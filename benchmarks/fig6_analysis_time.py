@@ -29,6 +29,7 @@ import numpy as np
 from repro.apps import matmul as mm
 from repro.core import (Eligibility, Explorer, a9_smp_seconds, explore,
                         zynq_system)
+from repro.core.xlacache import enable_persistent_cache
 from repro.kernels.block_matmul import block_matmul
 
 # Last run's machine-readable numbers — benchmarks/run.py --json serialises
@@ -801,6 +802,7 @@ if __name__ == "__main__":
     args = ap.parse_args()
     if args.smoke:
         args.n, args.sweep = min(args.n, 128), min(args.sweep, 24)
+    enable_persistent_cache()
     for name, us, derived in run(n=args.n, sweep=args.sweep,
                                  smoke=args.smoke):
         print(f"{name},{us:.1f},{derived}")

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """§Perf hillclimbing driver.
 
 For a chosen cell, re-lowers the two roofline probes under a candidate
@@ -21,6 +18,7 @@ Usage:
   python -m benchmarks.hillclimb zynq-codesign      # hillclimb D (paper §VI)
 """
 import json
+import os
 import sys
 from pathlib import Path
 
@@ -248,6 +246,10 @@ def zynq_codesign():
 
 
 def main() -> int:
+    # the dry-run probes lower on 512 virtual host devices: set before
+    # jax picks a backend; importing this module leaves the environment
+    # alone
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which in ("gemma2-prefill", "all"):
         gemma2_prefill()

@@ -151,6 +151,8 @@ def main(argv=None) -> int:
                     "(default %(default)s)")
     args = ap.parse_args(argv)
 
+    from repro.core.xlacache import enable_persistent_cache
+    enable_persistent_cache()
     from benchmarks import (fig3_dma_overlap, fig5_matmul,
                             fig6_analysis_time, fig9_cholesky,
                             serve_load, step_estimator)

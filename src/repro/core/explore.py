@@ -832,6 +832,16 @@ def _process_eval_chunk(ghash: str, fg: Optional[FrozenGraph],
             stats.as_dict())
 
 
+def _timed_eval_chunk(*args) -> Tuple[Optional[Tuple], float]:
+    """:func:`_process_eval_chunk` and the worker's own wall seconds for
+    it.  The parent's wait on a chunk future starts only when it reaches
+    that chunk, so a chunk that ran while the parent was busy elsewhere
+    can come back done but over budget; its own time decides."""
+    t0 = time.perf_counter()
+    got = _process_eval_chunk(*args)
+    return got, time.perf_counter() - t0
+
+
 #: Valid ``Explorer(engine=...)`` names, in fidelity order.  ``reference``
 #: is the object engine, ``fast``/``batch`` the exact array engines, and
 #: ``jax`` the rtol-tier compiled scan (see ``repro.core.replay``).
@@ -954,8 +964,8 @@ class Explorer:
         ``max_retries`` caps chunk re-submissions after a worker crash
         (capped exponential backoff between pool respawns) before the
         chunk is broken apart to isolate the poisoned candidate.  Engine
-        faults (jax import/compile failure, a lockstep engine error)
-        demote the engine down the
+        faults (a jax compile failure, a lockstep engine error) demote
+        the engine down the
         :data:`~repro.core.replay.ENGINE_FALLBACK` chain — one warning
         per step, counted on ``stats.engine_demotions`` — instead of
         raising.
@@ -1029,7 +1039,6 @@ class Explorer:
         self.jax_megabatch = (engine == "jax") if jax_megabatch is None \
             else bool(jax_megabatch)
         self._sim_tier = "jax" if engine == "jax" else "exact"
-        pending_demotion: Optional[BaseException] = None
         if engine == "jax":
             if self.processes:
                 raise ValueError(
@@ -1038,11 +1047,9 @@ class Explorer:
                     "fan-out would still pay per-worker executable loads "
                     "and device transfers); use engine='batch' with "
                     "processes=N for process-parallel sweeps")
-            from .jaxsim import require_jax
-            try:
-                require_jax()
-            except Exception as exc:    # noqa: BLE001 — a missing/broken
-                pending_demotion = exc  # jax backend degrades, never raises
+            # jax is a declared dependency: a broken install raises here
+            from .jaxsim import _jax
+            _jax()
         if not fast:
             if self.batch:
                 raise ValueError("batch=True requires the fast engine "
@@ -1137,8 +1144,6 @@ class Explorer:
         # cache_dir / the process-pool registry (the sweep server's shape)
         self._explore_lock = threading.RLock()
         self._disk_q_seen = 0       # DiskCache.quarantined already folded
-        if pending_demotion is not None:
-            self._demote(pending_demotion)
 
     # --------------------------------------------------------- disk keys
     def _trace_fingerprint(self) -> str:
@@ -1251,7 +1256,9 @@ class Explorer:
         at or below ``batch`` is exact, so the demoted sweep's results
         stay bit-identical to a healthy exact-engine run."""
         nxt = ENGINE_FALLBACK.get(self.engine)
-        if nxt is None:
+        if nxt is None or isinstance(exc, (ImportError, AttributeError)):
+            # a missing module or API is a bug of the code or the install,
+            # not an engine fault: demoting would hide it behind exit 0
             raise exc
         warnings.warn(f"engine {self.engine!r} degraded to {nxt!r} for the "
                       f"rest of the sweep: {exc!r}", UserWarning,
@@ -1902,11 +1909,14 @@ class Explorer:
           candidates isolated in-parent: only candidates that *keep*
           failing are quarantined, so innocents caught in a crashing
           chunk always get their (bit-identical) results.
-        * **timeout**: counted on ``chunk_timeouts``, the future is
-          cancelled (a no-op once running — the straggling worker keeps
-          its slot and its eventual result is discarded) and the unit
-          goes straight to in-parent isolation: one serial retry per
-          candidate, quarantine on a second offence.
+        * **timeout** — the future is not done within the unit budget,
+          or it is done but the worker's own time for it exceeded the
+          per-candidate budget (:func:`_timed_eval_chunk`): counted on
+          ``chunk_timeouts``, the future is cancelled (a no-op once
+          running — the straggling worker keeps its slot and its eventual
+          result is discarded) and the unit goes straight to in-parent
+          isolation: one serial retry per candidate, quarantine on a
+          second offence.
         * **in-worker exception**: an engine fault — demote once, guarded
           by the engine active at submit time so parallel same-tier
           failures demote a single step, then isolate the unit in-parent
@@ -1944,8 +1954,14 @@ class Explorer:
                                              u["items"], results)
                 break
             try:
-                got = unit["fut"].result(
+                got, worker_s = unit["fut"].result(
                     timeout=self._unit_timeout(len(unit["items"])))
+                budget = None if self.candidate_timeout is None else \
+                    self.candidate_timeout * max(1, len(unit["items"]))
+                if budget is not None and worker_s > budget:
+                    raise FuturesTimeout(
+                        f"chunk took {worker_s:.3f}s in its worker > "
+                        f"{budget}s")
             except (FuturesTimeout, CancelledError):
                 self.stats.chunk_timeouts += 1
                 unit["fut"].cancel()
@@ -2019,7 +2035,7 @@ class Explorer:
                 self._family_caps(fam))
         unit["engine"] = self.engine
         unit["t0"] = time.perf_counter()
-        unit["fut"] = ppool.submit(_process_eval_chunk, ghash, fg_arg, work,
+        unit["fut"] = ppool.submit(_timed_eval_chunk, ghash, fg_arg, work,
                                    self.policy, self.batch, orders_arg,
                                    self.max_rescue_rounds, prune_arg)
 

@@ -24,9 +24,11 @@ Invariants (shared with the numpy backend unless stated):
   makespans and busy sums within :data:`repro.core.replay.JAX_RTOL`
   (relative) of the reference, placements/pool layouts discrete-identical,
   and rankings stable under the documented tie-break (sub-tolerance
-  makespan ties break by candidate submission order).  The scan runs in
-  float64 (``jax.experimental.enable_x64``) to keep the residual far below
-  the tier.
+  makespan ties break by candidate submission order).  The scan runs
+  under ``jax.enable_x64``, and its ``lax`` step does exact IEEE float64
+  time arithmetic on int64 bit patterns (:func:`_bits_add`), so on every
+  backend — the TPU, whose own float64 is inexact, included — its
+  makespans match the reference bit for bit.
 * **Divergence falls back to the exact path.**  The same per-step heap-key
   monotonicity check as batchsim runs *inside* the scan (carried
   ``prev_key`` per lane); lanes whose popped ``(ready_t, tie_break)`` keys
@@ -59,15 +61,12 @@ economics:
   order library.
 
 The step body's commit (pool select + slot argmin + clock/busy/seen
-update) is pluggable (``step_impl``): the default pure-``lax`` form, or
-the fused pallas kernel :func:`repro.kernels.lockstep_step.step_commit`
-(TPU-native on TPU backends; ``"pallas-interpret"`` runs the same kernel
-body under the interpreter so CPU CI exercises it at the ``JAX_RTOL``
-tier).
-
-The jax dependency is gated: importing this module without jax installed
-works, and :func:`simulate_jax` raises a clear ``RuntimeError`` pointing at
-the exact engines instead.
+update) is the pure-``lax`` form on every backend, the TPU included
+(``step_impl="auto"`` resolves to ``"lax"``).  The fused pallas kernel
+:func:`repro.kernels.lockstep_step.step_commit` stays out of the scan: the
+scan carries 64-bit state, and XLA passes no 64-bit operand into a Mosaic
+kernel, so the kernel cannot compile inside it until time fits 32 bits
+(integer ticks).  ``step_impl="pallas"`` raises with that reason.
 """
 from __future__ import annotations
 
@@ -87,14 +86,11 @@ from .replay import (BatchStats, JAX_RTOL, Layout,  # noqa: F401
                      simulate_grouped, simulate_many)
 from .simulator import SimResult
 from .xlacache import CompileCache
-from ..testing import faults
 
 # The jax import is deferred until the engine is actually used: importing
 # repro.core (which re-exports simulate_jax) must stay cheap and must not
 # load a multithreaded runtime before the exploration engine's process
-# pools pick a start method.  _jax() performs and caches the gated import.
-_JAX_MODULES: Optional[Tuple] = None
-_JAX_ERROR: Optional[BaseException] = None
+# pools pick a start method.  _jax() performs the import.
 
 #: Lanes per compiled scan chunk (the bucket cap) on the per-graph path.
 #: Chunks are padded up to power-of-two widths so the jit cache is keyed
@@ -115,10 +111,10 @@ MEGABATCH_CHUNK = 256
 #: f64): wide lanes for narrow slot axes, narrow lanes for wide ones.
 TARGET_SLICE_ELEMS = 8192
 
-#: Valid ``step_impl`` names: ``auto`` picks the pallas kernel on TPU
-#: backends and pure lax elsewhere; ``pallas-interpret`` forces the pallas
-#: kernel body under the interpreter (slow — CI equivalence runs only).
-STEP_IMPLS = ("auto", "lax", "pallas", "pallas-interpret")
+#: Valid ``step_impl`` names: ``auto`` resolves to ``lax`` on every
+#: backend (the scan's 64-bit state cannot enter the Mosaic kernel — see
+#: :func:`resolve_step_impl`).
+STEP_IMPLS = ("auto", "lax")
 
 #: Fallback in-memory compile cache for bare ``simulate_jax`` calls with no
 #: Explorer-owned cache: still deduplicates compiles within the process.
@@ -126,40 +122,10 @@ _MEM_COMPILE_CACHE = CompileCache()
 
 
 def _jax():
-    """``(jax, jnp, enable_x64)``, importing on first use (gated)."""
-    global _JAX_MODULES, _JAX_ERROR
-    if _JAX_MODULES is None and _JAX_ERROR is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
-            _JAX_MODULES = (jax, jnp, enable_x64)
-        except Exception as e:          # noqa: BLE001 — any import failure
-            _JAX_ERROR = e
-    if _JAX_MODULES is None:
-        raise RuntimeError(
-            "the jax candidate-axis engine requires jax, which failed to "
-            f"import here ({_JAX_ERROR!r}); use Explorer(engine='batch') — "
-            "the exact numpy lockstep engine — instead") from _JAX_ERROR
-    return _JAX_MODULES
-
-
-def have_jax() -> bool:
-    """Whether the jax backend is importable in this environment."""
-    try:
-        _jax()
-        return True
-    except RuntimeError:
-        return False
-
-
-def require_jax() -> None:
-    # The fault site lives HERE and not inside _jax(): _jax() caches its
-    # failure in _JAX_ERROR forever, so injecting there would poison jax
-    # for the rest of the process instead of failing one activation.
-    if faults.fire("fail_jax_import"):
-        raise RuntimeError("injected fault: fail_jax_import")
-    _jax()
+    """``(jax, jnp)``, imported on first use."""
+    import jax
+    import jax.numpy as jnp
+    return jax, jnp
 
 
 def _bucket(n: int, cap: int) -> int:
@@ -179,14 +145,111 @@ def _bucket(n: int, cap: int) -> int:
     return b
 
 
-def _resolve_step_impl(step_impl: str) -> str:
+# ---------------------------------------------------------------------------
+# Exact float64 time, carried as int64 bit patterns
+# ---------------------------------------------------------------------------
+#
+# A TPU emulates float64 at reduced precision: on a v5e, 9,146 of 10,000
+# random sums of scan-sized times differed from IEEE float64 (max relative
+# error 6.1e-15), and even a round trip through the device rounds the
+# value.  The scan's in-flight heap-key check compares ready times for
+# exact ties, so with float64 state every lane of a tie-rich graph read
+# as diverged on the chip and went to the host fallback: rankings stayed
+# right, but the chip did no useful work.  The lax step therefore carries
+# each time as the int64 bit pattern of its float64 value, on every
+# backend.  Times are never negative, and for non-negative IEEE doubles
+# (+inf included) integer order *is* numeric order, so min / max / argmin
+# / compares / scatter-max work unchanged on the patterns; only ``+`` and
+# ``-`` need the round-to-nearest-even integer arithmetic below — exact,
+# hence bit-identical to float64 wherever it runs.
+
+_FRAC = 52                         # float64 fraction bits
+_GUARD = 8                         # extra low bits through add / subtract
+_LEAD = 1 << (_FRAC + _GUARD)      # a normalised significand's top bit
+_INF_BITS = 0x7FF0000000000000     # +inf; larger than every finite time
+
+
+def _unpack(x):
+    """``(exponent, significand << _GUARD)`` of non-negative float64 bit
+    patterns, with subnormals at the effective exponent 1."""
+    _, jnp = _jax()
+    e = x >> _FRAC
+    frac = x & ((1 << _FRAC) - 1)
+    m = jnp.where(e > 0, frac | (1 << _FRAC), frac)
+    return jnp.maximum(e, 1), m << _GUARD
+
+
+def _shift_jam(m, d):
+    """``m >> d`` with every bit shifted out OR-ed into bit 0."""
+    _, jnp = _jax()
+    d = jnp.minimum(d, 62)
+    kept = m >> d
+    return kept | ((kept << d) != m).astype(m.dtype)
+
+
+def _pack(e, v):
+    """Round ``v`` (top bit at ``_LEAD``, or below it only at exponent 1)
+    to nearest-even and encode; a carry out of the significand bumps the
+    exponent by itself, and overflow clamps to +inf."""
+    _, jnp = _jax()
+    m = v >> _GUARD
+    rem = v & ((1 << _GUARD) - 1)
+    half = 1 << (_GUARD - 1)
+    up = (rem > half) | ((rem == half) & ((m & 1) == 1))
+    return jnp.minimum(((e - 1) << _FRAC) + m + up.astype(v.dtype),
+                       _INF_BITS)
+
+
+def _bits_add(a, b):
+    """IEEE float64 ``a + b`` on non-negative bit patterns."""
+    _, jnp = _jax()
+    x, y = jnp.maximum(a, b), jnp.minimum(a, b)
+    ex, mx = _unpack(x)
+    ey, my = _unpack(y)
+    v = mx + _shift_jam(my, ex - ey)
+    carry = v >= (_LEAD << 1)
+    v = jnp.where(carry, (v >> 1) | (v & 1), v)
+    out = _pack(ex + carry.astype(ex.dtype), v)
+    return jnp.where(x >= _INF_BITS, _INF_BITS, out)
+
+
+def _bits_sub(x, y):
+    """IEEE float64 ``x - y`` on non-negative bit patterns, ``x >= y``."""
+    jax, jnp = _jax()
+    ex, mx = _unpack(x)
+    ey, my = _unpack(y)
+    v = mx - _shift_jam(my, ex - ey)
+    # normalise left until the top bit reaches _LEAD, stopping at the
+    # subnormal exponent; only cancellation (exponents within one, so no
+    # bit was jammed) shifts by more than one place
+    top = 63 - jax.lax.clz(v)
+    shift = jnp.clip(_FRAC + _GUARD - top, 0, ex - 1)
+    out = _pack(ex - shift, v << shift)
+    out = jnp.where(v == 0, 0, out)
+    return jnp.where(x >= _INF_BITS, _INF_BITS, out)
+
+
+def resolve_step_impl(step_impl: str) -> str:
+    """The step implementation the scan compiles with.
+
+    The scan state is 64-bit (the scan runs under ``jax.enable_x64``).
+    XLA refuses to pass 64-bit operands into a Mosaic (TPU pallas) kernel,
+    so the compiled kernel cannot run inside the scan on any backend:
+    ``auto`` resolves to ``lax``, and ``pallas`` raises — never a silent
+    fall back to the interpreter or to another engine."""
+    if step_impl == "pallas":
+        jax, _ = _jax()
+        backend = jax.default_backend()
+        why = (f"the backend is {backend!r}, and the compiled kernel runs "
+               f"only on a TPU" if backend != "tpu" else
+               "the scan carries 64-bit state, and a Mosaic kernel takes "
+               "no 64-bit operands")
+        raise ValueError(f"step_impl='pallas' cannot compile here: {why}; "
+                         f"use 'lax' (what 'auto' resolves to)")
     if step_impl not in STEP_IMPLS:
         raise ValueError(f"unknown step_impl {step_impl!r}: valid names are "
                          + ", ".join(repr(s) for s in STEP_IMPLS))
-    if step_impl == "auto":
-        jax, _, _ = _jax()
-        return "pallas" if jax.default_backend() == "tpu" else "lax"
-    return step_impl
+    return "lax"
 
 
 # ---------------------------------------------------------------------------
@@ -195,8 +258,12 @@ def _resolve_step_impl(step_impl: str) -> str:
 
 
 @functools.lru_cache(maxsize=None)
-def _runner(step_impl: str):
-    """The pure scan function for one resolved ``step_impl``.
+def _runner():
+    """The pure scan function.
+
+    The step carries every time (clocks, ready times, costs, busy,
+    makespan) as its float64 bit pattern in int64, with exact ``+``/``-``
+    (:func:`_bits_add`, :func:`_bits_sub`).
 
     All shapes are megabatch-form and **lane-aligned**: step inputs ``xs``
     carry the lane axis directly (``[T, B, ...]`` — each lane's cohort
@@ -210,26 +277,20 @@ def _runner(step_impl: str):
     ahead-of-time per shape signature via
     :class:`~repro.core.xlacache.CompileCache` (see :func:`_load_runner`).
     """
-    jax, jnp, _ = _jax()
-    use_pallas = step_impl in ("pallas", "pallas-interpret")
-    if use_pallas:
-        from ..kernels.lockstep_step import step_commit
-        interpret = (step_impl == "pallas-interpret"
-                     or jax.default_backend() != "tpu")
-        kernel_commit = functools.partial(step_commit, interpret=interpret)
+    jax, jnp = _jax()
 
     def commit(clocks, busy, seen, p, rt, base, live, aB):
         """Slot argmin + clock/busy/seen update — the step's dense tail."""
-        if use_pallas:
-            return kernel_commit(clocks, busy, seen, p, rt, base, live)
         cl = clocks[p, :, aB]                               # [B, S]
         s = jnp.argmin(cl, axis=1)          # first-minimum, like ref
         tmin = cl[aB, s]
         start = jnp.maximum(rt, tmin)
-        end = start + base
+        end = _bits_add(start, base)
         clocks = clocks.at[p, s, aB].set(
             jnp.where(live, end, clocks[p, s, aB]))
-        busy = busy.at[p, aB].add(jnp.where(live, end - start, 0.0))
+        b0 = busy[p, aB]
+        busy = busy.at[p, aB].set(
+            jnp.where(live, _bits_add(b0, _bits_sub(end, start)), b0))
         seen = seen.at[p, aB].set(seen[p, aB] | live)
         return clocks, busy, seen, end
 
@@ -239,6 +300,7 @@ def _runner(step_impl: str):
         aB = jnp.arange(B)
         K = xs["own_opts"].shape[2]
         skid = smp_kid                                      # [B]
+        zero = jnp.zeros((), dtype=clocks.dtype)
 
         def choose(opts, cost, rt, minc):
             """Vectorised reference `_choose_kind` over all lanes: options
@@ -251,7 +313,7 @@ def _runner(step_impl: str):
             own [B, S] min."""
             best_k = jnp.full((B,), -1, dtype=placement.dtype)
             bv = jnp.zeros((B,), dtype=minc.dtype)
-            bp = jnp.zeros((B,), dtype=minc.dtype)
+            bp = jnp.zeros((B,), dtype=jnp.int32)
             for j in range(K):                      # K is static and tiny
                 k = opts[:, j]
                 kk = jnp.maximum(k, 0)
@@ -260,8 +322,8 @@ def _runner(step_impl: str):
                 base = cost[aB, kk]
                 t = minc[jnp.maximum(pi, 0), aB]
                 start = jnp.maximum(rt, t)
-                keyv = start + jnp.where(eft, base, 0.0)
-                pref = jnp.where(k == skid, 1.0, 0.0)
+                keyv = _bits_add(start, jnp.where(eft, base, zero))
+                pref = (k == skid).astype(jnp.int32)
                 better = valid & ((best_k < 0) | (keyv < bv)
                                   | ((keyv == bv) & (pref < bp)))
                 bv = jnp.where(better, keyv, bv)
@@ -319,7 +381,7 @@ def _runner(step_impl: str):
             base = x["own_cost"][aB, kk]                        # [B]
             clocks, busy, seen, end = commit(clocks, busy, seen, p, rt,
                                              base, live, aB)
-            end_eff = jnp.where(live, end, jnp.where(valid, rt, 0.0))
+            end_eff = jnp.where(live, end, jnp.where(valid, rt, zero))
             makespan = jnp.maximum(makespan, end_eff)
             ready = ready.at[x["succ"], aB[:, None]].max(
                 end_eff[:, None])
@@ -329,7 +391,8 @@ def _runner(step_impl: str):
                     prev_rt, prev_tb, div), None
 
         makespan = jnp.zeros((B,), dtype=clocks.dtype)
-        prev_rt = jnp.full((B,), -jnp.inf, dtype=clocks.dtype)
+        # a negative bit pattern sorts below every (non-negative) time
+        prev_rt = jnp.full((B,), -1, dtype=clocks.dtype)
         prev_tb = jnp.full((B,), -1, dtype=xs["tb"].dtype)
         div = jnp.zeros((B,), dtype=bool)
         init = (clocks, ready, placement, busy, seen, makespan, prev_rt,
@@ -343,38 +406,33 @@ def _runner(step_impl: str):
 
 @functools.lru_cache(maxsize=None)
 def _code_fingerprint() -> str:
-    """Hash of the scan/kernel source files, part of every compile-cache
-    key: a persisted executable compiled from an older version of the step
+    """Hash of the scan's source file, part of every compile-cache key: a
+    persisted executable compiled from an older version of the step
     semantics must miss, never silently serve stale results."""
-    from repro.kernels import lockstep_step
-    h = hashlib.sha256()
-    for mod_file in (__file__, lockstep_step.__file__):
-        try:
-            with open(mod_file, "rb") as f:
-                h.update(f.read())
-        except OSError:                 # zipped/frozen install: sources
-            return "unhashable"         # unreadable, env key still applies
-    return h.hexdigest()[:16]
+    try:
+        with open(__file__, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:                     # zipped/frozen install: source
+        return "unhashable"             # unreadable, env key still applies
 
 
-def _signature(step_impl: str, args: Tuple) -> Tuple:
+def _signature(args: Tuple) -> Tuple:
     """Shape/dtype signature of one runner invocation — the compile-cache
     key body (the environment half lives in CompileCache)."""
     def one(a):
         return (tuple(a.shape), str(a.dtype))
     xs = args[0]
-    return (_code_fingerprint(), step_impl,
+    return (_code_fingerprint(),
             tuple((k, one(v)) for k, v in sorted(xs.items())),
             tuple(one(a) for a in args[1:]))
 
 
-def _load_runner(cc: CompileCache, step_impl: str, args: Tuple):
+def _load_runner(cc: CompileCache, args: Tuple):
     """The AOT-compiled executable for this signature: in-memory hit, disk
     deserialize, or fresh ``lower().compile()`` (then persisted)."""
-    jax, _, _ = _jax()
+    jax, _ = _jax()
     return cc.load_or_compile(
-        _signature(step_impl, args),
-        lambda: jax.jit(_runner(step_impl)).lower(*args))
+        _signature(args), lambda: jax.jit(_runner()).lower(*args))
 
 
 # ---------------------------------------------------------------------------
@@ -592,8 +650,14 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
     :data:`repro.core.replay.LockstepFn` contract, positions indexing the
     cohort's own ``layouts``; ``retired`` maps position to its bound.
     """
-    _, jnp, enable_x64 = _jax()
-    impl = _resolve_step_impl(step_impl)
+    jax, jnp = _jax()
+    resolve_step_impl(step_impl)
+
+    def _times(a: np.ndarray) -> np.ndarray:
+        """Host float64 times as the scan's int64 bit patterns (see
+        :func:`_bits_add`); other arrays unchanged."""
+        return a.view(np.int64) if a.dtype == np.float64 else a
+
     cc = compile_cache if compile_cache is not None else _MEM_COMPILE_CACHE
     eft = policy == "eft"
 
@@ -722,7 +786,7 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
         slices = [(lanes_flat[lo:lo + step], S)
                   for lo in range(0, len(lanes_flat), step)]
 
-    with enable_x64():
+    with jax.enable_x64(True):
         # lane-aligned step inputs per distinct cohort-index vector: the
         # host gathers [T, G, ...] -> [T, B, ...] once per slice shape so
         # the compiled body carries no gather ops (and no G in its shape
@@ -735,7 +799,8 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
             hit = _DEV_XS_CACHE.get(key)
             if hit is None:
                 mega = _mega()
-                hit = ({k: jnp.asarray(np.ascontiguousarray(v[:, g_np]))
+                hit = ({k: jnp.asarray(_times(np.ascontiguousarray(
+                            v[:, g_np])))
                         for k, v in mega.items()},
                        jnp.asarray(kind_pool_m[g_np]),
                        jnp.asarray(smp_kid_m[g_np]))
@@ -758,16 +823,17 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 for p, cnt in enumerate(per[gi]["lane_counts"][pos]):
                     clocks[p, :cnt, li] = 0.0
             xs_j, kp_j, sk_j = _lane_aligned(g_np)
-            args = (xs_j, jnp.asarray(clocks),
-                    jnp.zeros((n_max + 1, B)),                  # ready
+            args = (xs_j, jnp.asarray(_times(clocks)),
+                    jnp.zeros((n_max + 1, B), dtype=jnp.int64),  # ready
                     jnp.full((n_max + 1, B), -1, dtype=jnp.int32),
-                    jnp.zeros((P_max, B)),                      # busy
+                    jnp.zeros((P_max, B), dtype=jnp.int64),     # busy
                     jnp.zeros((P_max, B), dtype=bool),          # seen
                     kp_j, sk_j, jnp.asarray(eft))
-            exe = _load_runner(cc, impl, args)
+            exe = _load_runner(cc, args)
             makespan, busy, seen, placement, div = exe(*args)
             div_np = np.asarray(div)
-            mk_np, busy_np = np.asarray(makespan), np.asarray(busy)
+            mk_np = np.asarray(makespan).view(np.float64)
+            busy_np = np.asarray(busy).view(np.float64)
             seen_np, place_np = np.asarray(seen), np.asarray(placement)
             for li, (gi, pos) in enumerate(sl):
                 if div_np[li]:
@@ -858,10 +924,9 @@ def simulate_jax(fg: FrozenGraph, systems: Sequence[SystemConfig],
     :data:`~repro.core.replay.JAX_RTOL` tolerance so sub-rtol ties never
     retire.
     """
-    require_jax()
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk!r}")
-    _resolve_step_impl(step_impl)               # fail fast on bad names
+    resolve_step_impl(step_impl)               # fail fast on bad names
 
     def lockstep(fg, order, layouts, policy, cutoffs=None):
         return _scan_group(fg, order, layouts, policy, cutoffs, chunk=chunk,
@@ -897,11 +962,10 @@ def simulate_jax_many(items: Sequence[Tuple[FrozenGraph,
     compiles and no per-graph remainder chunks.  ``chunk`` defaults to
     the wider :data:`MEGABATCH_CHUNK`.
     """
-    require_jax()
     chunk = MEGABATCH_CHUNK if chunk is None else chunk
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk!r}")
-    _resolve_step_impl(step_impl)               # fail fast on bad names
+    resolve_step_impl(step_impl)               # fail fast on bad names
 
     def lockstep_many(cohorts):
         return _scan_cohorts(cohorts, policy, chunk=chunk,

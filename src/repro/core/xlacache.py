@@ -26,14 +26,22 @@ Safety properties, mirroring the order library's:
   role ``functools.lru_cache`` used to play); the disk tier serves future
   processes.  ``disk=None`` keeps the in-memory tier only.
 
-The module deliberately imports jax lazily (inside methods), so importing
-it — e.g. via :mod:`repro.core.explore` — stays cheap and jax-free.
+Beside that user-named store, :func:`enable_persistent_cache` turns on
+JAX's own persistent compilation cache for a whole process.  Entry points
+(``python -m repro.explore``, ``chip_smoke.py``, the benchmark scripts)
+call it; importing the library never does.
+
+The module deliberately imports jax lazily (inside functions), so
+importing it — e.g. via :mod:`repro.core.explore` — stays cheap and
+jax-free.
 """
 from __future__ import annotations
 
 import collections
 import json
+import os
 import threading
+from pathlib import Path
 from typing import Any, Callable, Dict, Optional
 
 from .diskcache import DiskCache
@@ -43,6 +51,27 @@ from ..testing import faults
 #: at most and a sweep touches a handful of shapes, so this is a backstop
 #: against pathological shape churn, not a working-set tuning knob.
 MEM_CAP = 64
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed directory inside the checkout.  The path is part of what
+#: lets a later process find an entry again, so it is never built from a
+#: temporary name, a pid or the time.
+DEFAULT_JAX_CACHE_DIR = str(Path(__file__).resolve().parents[3]
+                            / ".jax_cache")
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory:
+    ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else
+    :data:`DEFAULT_JAX_CACHE_DIR`.  Call once, before the first compile."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        DEFAULT_JAX_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    # keep every compile: the scan's small shapes compile in under the
+    # default one-second floor, and a cold chip run pays for each of them
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 class CompileCache:

@@ -173,6 +173,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print(f"error: {exc}", file=sys.stderr)
         return 2
 
+    if args.engine == "jax":
+        from .core.xlacache import enable_persistent_cache
+        enable_persistent_cache()
     result = ex.explore(cands, top_k=args.top_k, prune=args.prune)
 
     doc = sweep_doc(args.trace, args.engine, ex, result, len(cands),

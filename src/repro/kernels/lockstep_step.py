@@ -17,13 +17,17 @@ grid over lane blocks, following the BlockSpec idiom of
 is also why the fusion wins: the lax path materialises gather/scatter
 index ops per step, the kernel is pure elementwise/reduce traffic.
 
-Dispatch policy mirrors :func:`repro.kernels.ops.default_interpret`: on a
-TPU backend the kernel compiles natively (f32 state — TPUs have no f64);
-everywhere else ``interpret=True`` evaluates the same kernel body in
-Python, which is *slower* than the lax path but exercises the kernel
-end-to-end, so CPU CI validates it at the documented ``JAX_RTOL`` tier
-(`step_impl="pallas-interpret"` in jaxsim).  Booleans cross the kernel
-boundary as int32 masks (TPU VMEM has no bool tiles).
+Where it compiles: with ``interpret=False`` the kernel compiles for a TPU
+(Mosaic) with float32 state and x64 *off* — ``tests/test_tpu_compile.py``
+compiles it for a described v5e.  It does not compile under x64 in any
+dtype: XLA passes no float64 operand into a Mosaic kernel, and with
+float32 state the int64 index arithmetic that x64 brings fails to
+legalize.  The jax scan runs under x64 with 64-bit state, so it always
+uses the lax commit and does not call this kernel; the kernel returns to
+the scan once time fits 32 bits (integer ticks).  ``interpret=True``
+evaluates the same kernel body in Python on any backend and dtype, which
+is how CPU CI checks it against a numpy oracle.  Booleans cross the
+kernel boundary as int32 masks (TPU VMEM has no bool tiles).
 """
 from __future__ import annotations
 

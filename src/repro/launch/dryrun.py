@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent without
 real hardware.
 
@@ -22,6 +19,7 @@ All cells:      python -m repro.launch.dryrun --all [--multi-pod]
 """
 import argparse
 import json
+import os
 import re
 import time
 from pathlib import Path
@@ -273,6 +271,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> int:
+    # 512 virtual host devices for the mesh, set before jax picks a
+    # backend; importing this module leaves the environment alone
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -23,30 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                              # newer jax: public API
-    _shard_map = jax.shard_map
-except AttributeError:            # jax ≤ 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-# independently of the public promotion — detect it, don't infer it
-try:
-    import inspect
-    _CHECK_KW = ("check_vma" if "check_vma"
-                 in inspect.signature(_shard_map).parameters else "check_rep")
-except (TypeError, ValueError):   # signature not introspectable
-    _CHECK_KW = "check_rep"
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static mapped-axis size; ``jax.lax.axis_size`` is newer than 0.4.x.
-    ``psum(1, axis)`` constant-folds to a Python int under shard_map."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
 def reference_allgather_matmul(x: jax.Array, w_shard: jax.Array,
                                axis_name: str) -> jax.Array:
     """Oracle: gather the full weight, then one big matmul."""
@@ -58,7 +34,7 @@ def ring_allgather_matmul(x: jax.Array, w_shard: jax.Array,
                           axis_name: str) -> jax.Array:
     """x: (..., d) replicated over the ring axis; w_shard: (d/n, f) — this
     device's shard of the d-sharded weight.  Returns x @ W (full)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     shard_rows = w_shard.shape[0]
 
@@ -81,9 +57,9 @@ def ring_allgather_matmul(x: jax.Array, w_shard: jax.Array,
 
 def make_overlapped_matmul(mesh: Mesh, axis: str = "data"):
     """shard_map-wrapped ring matmul: weights d-sharded over ``axis``."""
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(), P(axis, None)), out_specs=P(),
-             **{_CHECK_KW: False})
+             check_vma=False)
     def f(x, w):
         return ring_allgather_matmul(x, w, axis)
     return f

@@ -65,17 +65,9 @@ def plan_for(cfg: ModelConfig) -> ParallelPlan:
 
 def abstract_mesh(axis_sizes: Sequence[int],
                   axis_names: Sequence[str]):
-    """Version-tolerant ``jax.sharding.AbstractMesh`` constructor.
-
-    jax ≤ 0.4.37 takes one ``((name, size), ...)`` shape tuple; newer jax
-    takes ``(axis_sizes, axis_names)``.  Sharding rules only consume
-    ``axis_names`` / ``shape``, which both layouts expose identically.
-    """
+    """A device-free ``jax.sharding.AbstractMesh`` for sharding rules."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

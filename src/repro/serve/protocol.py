@@ -366,8 +366,7 @@ def sweep_doc(trace_label: str, engine_requested: str, ex,
         # lanes retired mid-sweep by the branch-and-bound cutoff; the
         # counts stay 0 on unpruned sweeps)
         "retire": {k: int(result.cache.get(k, 0)) for k in RETIRE_KEYS},
-        # lifetime fault counters (includes construction-time demotions,
-        # which per-sweep result.cache deltas cannot see)
+        # lifetime fault counters of this Explorer
         "faults": {k: v for k, v in ex.stats.as_dict().items()
                    if k in FAULT_KEYS},
     }

@@ -58,6 +58,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 from ..core.diskcache import DiskCache
 from ..core.explore import ENGINE_NAMES, Explorer, orders_disk_text
 from ..core.replay import ReplayLibrary
+from ..core.xlacache import enable_persistent_cache
 from .coalesce import Coalescer, DEFAULT_WINDOW_S
 from .protocol import (FAULT_KEYS, POLICIES, ProtocolError, RETIRE_KEYS,
                        SweepRequest,
@@ -77,8 +78,8 @@ class CircuitBreaker:
 
     The Explorer already demotes *within* a request
     (:data:`~repro.core.replay.ENGINE_FALLBACK`), but a fresh Explorer
-    per request re-pays the whole failing chain — jax import timeout,
-    compile failure, demotion — on every query while a backend is down.
+    per request re-pays the whole failing chain — compile failure,
+    demotion — on every query while a backend is down.
     The breaker watches demotions *across* requests: after ``threshold``
     consecutive demoted sweeps it opens and grants every request the
     pinned (already-degraded, known-good) engine directly; after
@@ -563,6 +564,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     # a threaded parent must never fork: pools must come up via
     # forkserver even after some request imported jax
     os.environ.setdefault("REPRO_POOL_START", "forkserver")
+    # jax requests compile in this process
+    enable_persistent_cache()
 
     service = SweepService(
         cache_dir=args.cache_dir, processes=args.processes,

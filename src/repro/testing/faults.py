@@ -1,8 +1,8 @@
 """Deterministic fault injection for the exploration stack.
 
 Every failure path the fault-tolerant sweep machinery claims to handle —
-worker crashes, stuck chunks, corrupted cache entries, a broken jax
-backend — must be reproducible on demand or it is untested by definition.
+worker crashes, stuck chunks, corrupted cache entries, a failed XLA
+compile — must be reproducible on demand or it is untested by definition.
 This module is the single switchboard: production code calls
 :func:`fire` at named *sites* (cheap no-ops unless a fault plan is
 active), and tests/CI activate a plan through :func:`install` or the
@@ -52,7 +52,6 @@ kill_candidate worker, per candidate; arg = name substring    os._exit
 delay_chunk    worker, chunk entry; arg = seconds (def. 0.5)  sleep
 corrupt_cache  DiskCache.put; payload written corrupted       bad entry
 delay_put      DiskCache.put, pre-rename; arg = seconds       sleep
-fail_jax_import jaxsim.require_jax                            raise
 fail_compile   xlacache.CompileCache.load_or_compile          raise
 fail_lockstep  batchsim._run_lockstep entry                   raise
 ============== ============================================== ==========
@@ -78,7 +77,7 @@ ENV_TOKEN = "REPRO_FAULTS_TOKEN"
 
 #: Site names production code may fire; unknown sites in a spec fail fast.
 SITES = ("kill_worker", "kill_candidate", "delay_chunk", "corrupt_cache",
-         "delay_put", "fail_jax_import", "fail_compile", "fail_lockstep")
+         "delay_put", "fail_compile", "fail_lockstep")
 
 
 class _Rule:

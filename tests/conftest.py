@@ -9,8 +9,6 @@
   ``repro.testing.minihypothesis`` registers a deterministic, shrink-free
   stand-in for the API surface the suite uses, so the property tests still
   *run* instead of hard-erroring at collection.
-* **version-tolerant jax helpers** — see ``repro.parallel.sharding
-  .abstract_mesh`` for the AbstractMesh signature drift.
 """
 import pathlib
 import sys

@@ -115,3 +115,37 @@ def test_module_entrypoint_subprocess(tmp_path):
     assert proc.returncode == 0, proc.stderr
     doc = json.load(open(out_path))
     assert doc["candidates"] == 4 and doc["best"]
+
+
+def test_jax_engine_compile_cache_goes_where_the_env_says(tmp_path):
+    cache = tmp_path / "jax-cache"
+    out_path = str(tmp_path / "out.json")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.explore", "synth:16", "--accs", "1-6",
+         "--engine", "jax", "--json", out_path],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    assert json.load(open(out_path))["engine_final"] == "jax"
+    # the scan runner's executable is among the persisted entries
+    assert any(p.name.startswith("jit_run") for p in cache.iterdir())
+
+
+def test_persistent_cache_defaults_to_the_checkout(monkeypatch):
+    import jax
+    from repro.core.xlacache import (DEFAULT_JAX_CACHE_DIR,
+                                     enable_persistent_cache)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_JAX_CACHE_DIR == os.path.join(root, ".jax_cache")
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        assert enable_persistent_cache() == DEFAULT_JAX_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == DEFAULT_JAX_CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          saved[1])

@@ -21,18 +21,15 @@ import random
 
 import hypothesis
 import hypothesis.strategies as st
-import pytest
 
 from repro.core.explore import Explorer
 from repro.core.hwspec import SpecLibrary
-from repro.core.jaxsim import have_jax
 from repro.core.replay import (JAX_RTOL, frontiers_equivalent,
                                rankings_equivalent)
 from repro.core.trace import Trace, TraceEvent
 from repro.testing.synth import (synth_candidates, synth_report,
                                  synth_reports)
 
-needs_jax = pytest.mark.skipif(not have_jax(), reason="jax not installed")
 
 EXACT_ENGINES = ("reference", "fast", "batch")
 
@@ -166,7 +163,6 @@ def test_pruned_column_matches_unpruned(seed):
             assert _table(got) == _table(ref), engine
 
 
-@needs_jax
 @hypothesis.given(st.integers(0, 10_000))
 @hypothesis.settings(max_examples=2, deadline=None)
 def test_pruned_column_rtol_stable_on_jax(seed):
@@ -176,8 +172,7 @@ def test_pruned_column_rtol_stable_on_jax(seed):
     ref_spans = {o.name: o.makespan_s for o in ref.ranked}
     for megabatch in (True, False):
         ex, got = _run("jax", world, prune=True, jax_megabatch=megabatch)
-        if ex.engine != "jax":
-            pytest.skip(f"jax demoted to {ex.engine}: backend unusable")
+        assert ex.engine == "jax"
         names = [o.name for o in got.ranked]
         assert rankings_equivalent(names[:3], ref_names[:3], ref_spans,
                                    JAX_RTOL)
@@ -194,7 +189,6 @@ def test_pruned_column_rtol_stable_on_jax(seed):
 # ---------------------------------------------------------------------------
 
 
-@needs_jax
 @hypothesis.given(st.integers(0, 10_000))
 @hypothesis.settings(max_examples=3, deadline=None)
 def test_jax_tier_ranking_and_frontier_stable(seed):
@@ -206,8 +200,7 @@ def test_jax_tier_ranking_and_frontier_stable(seed):
     axes = ref.objectives or ["makespan_s"]
     for megabatch in (True, False):
         ex, got = _run("jax", world, jax_megabatch=megabatch)
-        if ex.engine != "jax":
-            pytest.skip(f"jax demoted to {ex.engine}: backend unusable")
+        assert ex.engine == "jax"
         # same candidates survived (power/area budgets are static, so
         # feasibility can never be tier-dependent here)
         assert sorted(o.name for o in got.ranked) == sorted(ref_names)

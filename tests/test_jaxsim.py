@@ -11,6 +11,9 @@ or otherwise).
 """
 import hypothesis
 import hypothesis.strategies as st
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import Explorer, zynq_system
@@ -18,7 +21,7 @@ from repro.core.batchsim import simulate_batch
 from repro.core.devices import DevicePool, SharedResource, SystemConfig
 from repro.core.explore import ENGINE_NAMES, CacheStats
 from repro.core.fastsim import FrozenGraph, simulate_fast
-from repro.core.jaxsim import have_jax, simulate_jax
+from repro.core.jaxsim import _bits_add, _bits_sub, simulate_jax
 from repro.core.replay import (BatchStats, ENGINE_TOLERANCE, JAX_RTOL,
                                makespans_close, rankings_equivalent,
                                sims_equivalent)
@@ -27,8 +30,6 @@ from repro.core.taskgraph import Task, TaskGraph
 from repro.core.trace import Trace, TraceEvent
 from repro.testing.synth import (frozen_for, synth_candidates, synth_report,
                                  synth_reports, synth_trace)
-
-needs_jax = pytest.mark.skipif(not have_jax(), reason="jax not installed")
 
 
 def assert_jax_tier(fg, systems, policy, **kw):
@@ -69,7 +70,6 @@ def random_trace(draw):
     return Trace(events=events, wall_seconds=1.0)
 
 
-@needs_jax
 @hypothesis.given(random_trace(), st.booleans(),
                   st.sampled_from(["availability", "eft"]),
                   st.lists(st.integers(1, 12), min_size=2, max_size=8))
@@ -84,7 +84,6 @@ def test_jax_tier_on_augmented_graphs(tr, smp, policy, slot_counts):
     assert_jax_tier(fg, systems, policy, min_lockstep=2)
 
 
-@needs_jax
 @hypothesis.given(st.integers(2, 20), st.integers(1, 3), st.integers(1, 3),
                   st.sampled_from(["availability", "eft"]))
 @hypothesis.settings(deadline=None, max_examples=8)
@@ -111,7 +110,6 @@ def test_jax_tier_on_bare_dags_with_two_pools(n, ca, cb, policy):
     assert_jax_tier(fg, systems, policy, min_lockstep=2)
 
 
-@needs_jax
 def test_jax_divergent_lanes_fall_back_exactly():
     """A wide slot ramp forces event-order divergence; diverged lanes must
     be flagged by the in-scan monotonicity check and re-simulated through
@@ -135,7 +133,6 @@ def test_jax_divergent_lanes_fall_back_exactly():
         pytest.fail(f"{system.name}: fallback lane not bit-identical")
 
 
-@needs_jax
 def test_jax_chunking_is_invariant():
     """Chunk width is a perf knob, never a semantics knob: every chunking
     of the lane axis yields the same results."""
@@ -149,14 +146,12 @@ def test_jax_chunking_is_invariant():
         assert [s.placements for s in got] == [s.placements for s in base]
 
 
-@needs_jax
 def test_jax_rejects_unknown_policy():
     fg, _ = frozen_for(synth_trace(4), smp=False)
     with pytest.raises(ValueError, match="policy"):
         simulate_jax(fg, [zynq_system("s", {"fpga:k": 1})], "heft")
 
 
-@needs_jax
 def test_jax_pure_smp_lanes_skip_inactive_dma_rows():
     """A pool template with no accelerator (and no DMA resources) forces
     every compute task onto the SMP, so every DMA row is conditionally
@@ -175,7 +170,6 @@ def test_jax_pure_smp_lanes_skip_inactive_dma_rows():
         assert sim.placements == ref.placements
 
 
-@needs_jax
 def test_jax_raises_reference_error_on_live_bad_dispatch():
     """A row the reference engine raises on (no compatible pool) must
     surface the same error from the scan — via the exact fallback."""
@@ -280,7 +274,6 @@ def test_exact_engines_still_bit_identical():
                 assert fast.placements == ref.placements == sim.placements
 
 
-@needs_jax
 def test_jax_tier_never_leaks_into_exact_sim_cache(tmp_path):
     """A jax-tier result persisted to the shared on-disk store must never
     satisfy an exact engine's lookup: the sim-cache key is namespaced by
@@ -305,7 +298,6 @@ def test_jax_tier_never_leaks_into_exact_sim_cache(tmp_path):
                                [o.name for o in ref.ranked], spans, JAX_RTOL)
 
 
-@needs_jax
 def test_exact_sim_cache_serves_jax_reads(tmp_path):
     """Tier blocking is one-directional: a warm *exact* store must serve a
     jax re-rank (bit-exact trivially satisfies rtol) — only rtol entries
@@ -350,7 +342,6 @@ def test_engine_names_and_validation():
     assert Explorer(tr, reports).engine == "batch"
 
 
-@needs_jax
 def test_explorer_jax_matches_batch_and_replays_topk():
     reports, rep = synth_reports(), synth_report()
     tr = synth_trace(30)
@@ -367,13 +358,11 @@ def test_explorer_jax_matches_batch_and_replays_topk():
         assert bool(est.sim.schedule) == (name in winners)
 
 
-@needs_jax
 def test_explorer_jax_rejects_processes():
     with pytest.raises(ValueError, match="jax"):
         Explorer(synth_trace(4), synth_reports(), engine="jax", processes=2)
 
 
-@needs_jax
 def test_bad_chunk_values_fail_fast():
     """Non-positive chunk widths get a clear ValueError at the API
     boundary — never an opaque range() crash or None-poisoned caches."""
@@ -391,7 +380,6 @@ def test_bad_chunk_values_fail_fast():
                  jax_chunk=16)
 
 
-@needs_jax
 def test_scan_inputs_memoised_on_frozen_graph():
     """Repeat sweeps over the same payload reuse the per-step scan inputs
     (and pickling drops them, like `_rt`)."""
@@ -416,3 +404,51 @@ def test_cache_stats_repr_has_disk_counters():
     assert "disk 5h/4m" in r and "graph 3h/1m" in r and "eval 7h/2m" in r
     # the fallback telemetry (diverged/rescued/serial-fallback) is visible
     assert "lanes 6d/2r/1f" in r
+
+
+# ---------------------------------------------------------------------------
+# exact float64 time on int64 bit patterns (the lax step's representation)
+# ---------------------------------------------------------------------------
+
+
+def test_bits_add_and_sub_are_ieee_float64():
+    """``_bits_add`` / ``_bits_sub`` on float64 bit patterns equal IEEE
+    float64 arithmetic bit for bit: scan-sized times, wide exponent gaps,
+    subnormals, huge values, exact ties, one-ulp neighbours, 0 and +inf."""
+    rng = np.random.default_rng(11)
+
+    def draw(n):
+        return np.concatenate([
+            rng.random(n) * 1e-1, rng.random(n) * 1e-4, rng.random(n) * 1e3,
+            np.exp(rng.uniform(-745.0, 709.0, n)),
+            [0.0, 5e-324, 1e-310, 2.2250738585072014e-308, 1.0, 1e308,
+             np.inf]])
+
+    a, b = draw(4000), draw(4000)
+    rng.shuffle(b)
+    b[:50] = a[:50]                                     # exact ties
+    b[50:100] = np.nextafter(a[50:100], np.inf)         # one ulp apart
+    hi, lo = np.maximum(a, b), np.minimum(a, b)
+    with jax.enable_x64(True):
+        def bits(x):
+            return jnp.asarray(x.view(np.int64))
+        s = np.asarray(jax.jit(_bits_add)(bits(a), bits(b)))
+        d = np.asarray(jax.jit(_bits_sub)(bits(hi), bits(lo)))
+    assert np.array_equal(s.view(np.float64), a + b)
+    fin = np.isfinite(hi)
+    assert np.array_equal(d.view(np.float64)[fin], (hi - lo)[fin])
+    assert not np.signbit(d.view(np.float64)).any()     # never -0.0
+
+
+@pytest.mark.parametrize("policy", ["availability", "eft"])
+def test_lax_scan_times_are_bit_exact(policy):
+    """The lax step's bit-pattern arithmetic is exact IEEE float64, so its
+    lockstep lanes match the reference engine at tolerance 0 — the jax
+    tier's rtol is headroom, not error, on every backend."""
+    fg, _ = frozen_for(synth_trace(24), smp=True)
+    systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 9)]
+    stats = BatchStats()
+    sims = simulate_jax(fg, systems, policy, min_lockstep=2, stats=stats)
+    assert stats.lockstep_lanes > 0
+    for sim, system in zip(sims, systems):
+        assert sims_equivalent(sim, simulate_fast(fg, system, policy), 0.0)

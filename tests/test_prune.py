@@ -22,7 +22,6 @@ from repro.core import Candidate, Eligibility, Explorer, zynq_system
 from repro.core.batchsim import BatchStats, simulate_batch
 from repro.core.fastsim import LanePruned, simulate_fast
 from repro.core.hlsreport import KernelReport
-from repro.core.jaxsim import have_jax
 from repro.core.replay import (JAX_RTOL, Incumbent, PruneContext, Retired,
                                ReplayLibrary, bound_aux, rankings_equivalent,
                                serial_tails)
@@ -30,7 +29,6 @@ from repro.core.trace import Trace, TraceEvent
 from repro.serve.protocol import ProtocolError, SweepRequest
 from repro.testing.synth import frozen_for, synth_trace
 
-needs_jax = pytest.mark.skipif(not have_jax(), reason="jax not installed")
 
 EXACT_ENGINES = ("reference", "fast", "batch")
 
@@ -127,7 +125,6 @@ def test_pruned_equals_unpruned_per_engine(seed):
             assert o.makespan_s == spans[o.name], engine
 
 
-@needs_jax
 @hypothesis.given(st.integers(0, 10_000))
 @hypothesis.settings(max_examples=3, deadline=None)
 def test_pruned_topk_rtol_stable_on_jax(seed):
@@ -139,8 +136,7 @@ def test_pruned_topk_rtol_stable_on_jax(seed):
     kth = spans[ref_names[min(k, len(ref_names)) - 1]]
     for megabatch in (True, False):
         ex, got = _run("jax", world, prune=True, jax_megabatch=megabatch)
-        if ex.engine != "jax":
-            pytest.skip(f"jax demoted to {ex.engine}: backend unusable")
+        assert ex.engine == "jax"
         names = [o.name for o in got.ranked]
         assert rankings_equivalent(names[:k], ref_names[:k], spans,
                                    JAX_RTOL)

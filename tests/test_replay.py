@@ -23,14 +23,12 @@ from repro.core.batchsim import BatchStats, simulate_batch
 from repro.core.diskcache import DiskCache
 from repro.core.explore import _process_eval_chunk
 from repro.core.fastsim import FrozenGraph, simulate_fast
-from repro.core.jaxsim import have_jax, simulate_jax
+from repro.core.jaxsim import simulate_jax
 from repro.core.replay import (JAX_RTOL, ReplayLibrary, order_valid,
                                sims_equivalent, simulate_grouped)
 from repro.core.trace import Trace, TraceEvent
 from repro.testing.synth import (frozen_for, synth_candidates, synth_report,
                                  synth_reports, synth_trace)
-
-needs_jax = pytest.mark.skipif(not have_jax(), reason="jax not installed")
 
 
 def assert_bit_identical(fg, systems, policy, sims):
@@ -323,7 +321,6 @@ def test_batch_bit_identical_with_warm_library(tr, smp, policy, slot_counts):
         assert_bit_identical(fg, systems, policy, sims)
 
 
-@needs_jax
 @hypothesis.given(random_trace(), st.booleans(),
                   st.lists(st.integers(1, 10), min_size=2, max_size=8))
 @hypothesis.settings(deadline=None, max_examples=6)
@@ -341,7 +338,6 @@ def test_jax_tier_holds_with_warm_library(tr, smp, slot_counts):
             assert sim.placements == ref.placements
 
 
-@needs_jax
 def test_library_is_shared_across_engines():
     """Orders are engine-agnostic: a batch-warmed library serves the jax
     scan (and vice versa) — recorded by the exact path, re-validated per

@@ -1,0 +1,112 @@
+"""Compile the device path for a described TPU v5e, with no chip attached.
+
+The TPU compiler is installed beside jax, and it compiles for a chip that
+is described, not attached: what it refuses here it would refuse on the
+chip.  The topology is described inside a module fixture — never while a
+module is imported — because only one process at a time may load the TPU
+library, and every test worker imports every test file.
+
+* The jax engine's scan runner (the ``lax`` step: times as int64 float64
+  bit patterns, under x64) compiles at the slice shapes ``chip_smoke.py``
+  compiles: phase A's
+  Cholesky ``n=2048, bs=128`` graph (4,912 task rows after DMA
+  augmentation, 5 pools), and phase B's ``synth:1024`` sweep over 1-128
+  accelerators (5,120 task rows, 4 pools, slot axes of 32, 64 and 128).
+* The pallas step-commit kernel compiles to a Mosaic custom call with
+  float32 state and x64 off.  No 64-bit operand passes into a Mosaic
+  kernel, which is why ``step_impl="auto"`` resolves to ``lax`` inside
+  the scan, and ``"pallas"`` raises.
+"""
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.core.devices import zynq_system
+from repro.core.jaxsim import _runner, resolve_step_impl, simulate_jax
+from repro.kernels.lockstep_step import step_commit
+from repro.testing.synth import frozen_for, synth_trace
+
+#: Scan slices of chip_smoke.py: task rows (T = n), pools, slots, lanes,
+#: per-row device options, device kinds and successor-list width.
+SLICES = {"phaseA": (4912, 5, 8, 32, 2, 5, 30),
+          "phaseB-S32": (5120, 4, 32, 64, 2, 4, 3),
+          "phaseB-S64": (5120, 4, 64, 32, 2, 4, 3),
+          "phaseB-S128": (5120, 4, 128, 16, 2, 4, 3)}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else libtpu logs under /tmp
+        # the TPU compiler ships with the installed jax: a topology that
+        # cannot be described is a bring-up failure, so it fails the tests
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sd(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("shape", SLICES.values(), ids=SLICES.keys())
+def test_scan_runner_compiles_for_v5e(one_chip, shape):
+    T, P, S, B, K, NK, SC = shape
+    sd = functools.partial(_sd, one_chip)
+    t64 = jnp.int64                     # times travel as float64 bits
+    with jax.enable_x64(True):
+        xs = {"valid": sd((T, B), jnp.bool_), "r": sd((T, B), jnp.int32),
+              "tb": sd((T, B), jnp.int64), "c": sd((T, B), jnp.int32),
+              "is_comp": sd((T, B), jnp.bool_),
+              "k_first": sd((T, B), jnp.int32),
+              "own_opts": sd((T, B, K), jnp.int32),
+              "own_cost": sd((T, B, NK), t64),
+              "par_opts": sd((T, B, K), jnp.int32),
+              "par_cost": sd((T, B, NK), t64),
+              "act": sd((T, B, NK), jnp.bool_),
+              "bad_row": sd((T, B), jnp.bool_),
+              "succ": sd((T, B, SC), jnp.int32)}
+        args = (xs, sd((P, S, B), t64),                  # clocks
+                sd((T + 1, B), t64),                     # ready
+                sd((T + 1, B), jnp.int32),               # placement
+                sd((P, B), t64), sd((P, B), jnp.bool_),  # busy, seen
+                sd((B, NK), jnp.int32), sd((B,), jnp.int32),
+                sd((), jnp.bool_))
+        compiled = jax.jit(_runner()).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    # the per-slice step inputs dominate: ~43 MB at 64 lanes, far inside
+    # the chip's 16 GB
+    assert 0 < mem.argument_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("P,S,B", [(4, 32, 64), (5, 8, 256)])
+def test_step_commit_kernel_compiles_for_v5e(one_chip, P, S, B):
+    sd = functools.partial(_sd, one_chip)
+    args = (sd((P, S, B), jnp.float32), sd((P, B), jnp.float32),
+            sd((P, B), jnp.bool_), sd((B,), jnp.int32),
+            sd((B,), jnp.float32), sd((B,), jnp.float32),
+            sd((B,), jnp.bool_))
+    with jax.enable_x64(False):
+        compiled = jax.jit(functools.partial(step_commit, interpret=False)) \
+            .lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_step_impl_raises_never_interprets(monkeypatch):
+    assert resolve_step_impl("auto") == "lax"
+    with pytest.raises(ValueError, match="backend is 'cpu'"):
+        resolve_step_impl("pallas")
+    fg, _ = frozen_for(synth_trace(8), smp=False)
+    systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 7)]
+    with pytest.raises(ValueError, match="step_impl='pallas'"):
+        simulate_jax(fg, systems, step_impl="pallas")
+    # on a TPU backend the scan's 64-bit state is the reason
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_step_impl("auto") == "lax"
+    with pytest.raises(ValueError, match="64-bit state"):
+        resolve_step_impl("pallas")

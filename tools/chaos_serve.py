@@ -51,6 +51,9 @@ REQUESTS_PER_CLIENT = 6
 FAULT_SPEC = "kill_worker:2,corrupt_cache:1"
 
 #: 50 candidates per request: accs 1-25 with the SMP variant doubles up.
+#: The engine stays "batch": the server child and the one-shot CLI child
+#: run at the same time, and with the jax engine both would contend for
+#: the one chip (a chip belongs to one process at a time).
 SWEEP_DOC = {"trace": "synth:32", "engine": "batch", "accs": "1-25",
              "top_k": 5, "budget_s": 300.0}
 
