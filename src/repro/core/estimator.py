@@ -37,7 +37,9 @@ class PerfEstimate:
     sim: SimResult
     graph_stats: Dict[str, object]
     critical_path_s: float
-    analysis_seconds: float          # how long the estimation itself took
+    # how long estimate() took; 0.0 in the estimates an Explorer makes,
+    # which time their layers with profiler spans instead
+    analysis_seconds: float = 0.0
 
     @property
     def speedup_vs(self) -> Callable[["PerfEstimate"], float]:

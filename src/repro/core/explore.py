@@ -92,6 +92,7 @@ from .replay import (ENGINE_FALLBACK, ENGINE_TOLERANCE, Incumbent,
                      MAX_RESCUE_ROUNDS, PruneContext, ReplayLibrary, Retired)
 from .hlsreport import KernelReport, ReportMap, ZYNQ_7045_BUDGET, fits
 from .simulator import SimResult, simulate
+from .spans import span
 from .taskgraph import TaskGraph
 from .trace import Trace
 from ..testing import faults
@@ -419,7 +420,6 @@ class CandidateOutcome:
     makespan_s: Optional[float] = None
     critical_path_s: Optional[float] = None
     lower_bound_s: Optional[float] = None
-    analysis_seconds: float = 0.0
     cached_graph: bool = False
     cached_eval: bool = False
     bottleneck: str = ""
@@ -599,6 +599,9 @@ class ExplorationResult:
         if d.get("version") != 2:
             raise ValueError(f"unsupported ExplorationResult version: "
                              f"{d.get('version')!r}")
+        for o in d["outcomes"]:
+            # written before the field was dropped: a wall-time share
+            o.pop("analysis_seconds", None)
         return ExplorationResult(
             outcomes=[CandidateOutcome(**o) for o in d["outcomes"]],
             wall_seconds=d["wall_seconds"], policy=d["policy"],
@@ -1311,27 +1314,25 @@ class Explorer:
             raise RuntimeError(
                 f"injected fault: kill during serial isolation of {name!r}")
 
-    def _failed_outcome(self, cand: Candidate, exc: BaseException,
-                        t0: float) -> Tuple[None, CandidateOutcome]:
+    def _failed_outcome(self, cand: Candidate, exc: BaseException
+                        ) -> Tuple[None, CandidateOutcome]:
         self.stats.quarantined += 1
-        return None, CandidateOutcome(
-            name=cand.name, status="failed",
-            analysis_seconds=time.perf_counter() - t0, error=repr(exc))
+        return None, CandidateOutcome(name=cand.name, status="failed",
+                                      error=repr(exc))
 
     def _safe_outcome(self, cand: Candidate) \
             -> Tuple[Optional[PerfEstimate], CandidateOutcome]:
         """The per-candidate (serial / thread-pool) path inside the fault
         envelope: expired sweep deadline and any evaluation exception
         quarantine the candidate instead of killing the sweep."""
-        tc = time.perf_counter()
         if self._deadline_left() == 0.0:
             return self._failed_outcome(
-                cand, FuturesTimeout("sweep deadline exceeded"), tc)
+                cand, FuturesTimeout("sweep deadline exceeded"))
         try:
             self._fire_inline_kills(cand.name)
             return self._evaluate_outcome(cand)
         except Exception as exc:            # noqa: BLE001 — quarantine
-            return self._failed_outcome(cand, exc, tc)
+            return self._failed_outcome(cand, exc)
 
     def _isolate_candidates(self, payload: object, ginfo: Tuple,
                             items: Sequence[Tuple], results: List) -> None:
@@ -1343,11 +1344,11 @@ class Explorer:
         deadline quarantines the remainder without evaluating."""
         _, stats, crit, lb = ginfo
         for pos, cand, key, text, ghit in items:
-            tc = time.perf_counter()
             if self._deadline_left() == 0.0:
                 results[pos] = self._failed_outcome(
-                    cand, FuturesTimeout("sweep deadline exceeded"), tc)
+                    cand, FuturesTimeout("sweep deadline exceeded"))
                 continue
+            tc = time.perf_counter()
             try:
                 self._fire_inline_kills(cand.name)
                 faults.sleep_if_injected("delay_chunk")
@@ -1362,12 +1363,11 @@ class Explorer:
                         f"serial retry took {dt:.3f}s > candidate_timeout="
                         f"{self.candidate_timeout}")
             except Exception as exc:        # noqa: BLE001 — quarantine
-                results[pos] = self._failed_outcome(cand, exc, tc)
+                results[pos] = self._failed_outcome(cand, exc)
                 continue
             self._sim_store(key, text, sim)
             results[pos] = self._outcome_from_sim(
-                cand, stats, crit, lb, ghit, False, sim,
-                time.perf_counter() - tc)
+                cand, stats, crit, lb, ghit, False, sim)
 
     # ------------------------------------------------------------------
     def _graph_for(self, cand: Candidate,
@@ -1381,34 +1381,37 @@ class Explorer:
                 self.stats.graph_hits += 1
                 return (*self._graphs[key], True)
             self.stats.graph_misses += 1
-        text = None
-        if self._disk is not None:
-            text = self._graph_disk_text(key)
-            fg = self._disk.get(text)
-            if isinstance(fg, FrozenGraph):
-                entry = (fg, fg.stats, fg.critical_path_s, fg.lower_bound_s)
+        with span("explore.graph"):
+            text = None
+            if self._disk is not None:
+                text = self._graph_disk_text(key)
+                fg = self._disk.get(text)
+                if isinstance(fg, FrozenGraph):
+                    entry = (fg, fg.stats, fg.critical_path_s,
+                             fg.lower_bound_s)
+                    with self._lock:
+                        self.stats.disk_hits += 1
+                        if self.cache_enabled:
+                            self._graphs[key] = entry
+                    return (*entry, True)
                 with self._lock:
-                    self.stats.disk_hits += 1
-                    if self.cache_enabled:
-                        self._graphs[key] = entry
-                return (*entry, True)
-            with self._lock:
-                self.stats.disk_misses += 1
-        g = build_graph(self.trace, cand.system, self.reports,
-                        cand.eligibility, smp_scale=self.smp_scale,
-                        smp_cost="mean", smp_seconds_fn=self.smp_seconds_fn)
-        if self.fast:
-            fg = FrozenGraph.freeze(g)
-            entry = (fg, fg.stats, fg.critical_path_s, fg.lower_bound_s)
-        else:
-            entry = (g, g.subgraph_stats(), g.critical_path(),
-                     lower_bound_seconds(g))
-        if text is not None:
-            self._disk.put(text, entry[0])
-        if self.cache_enabled:
-            with self._lock:
-                self._graphs[key] = entry
-        return (*entry, False)
+                    self.stats.disk_misses += 1
+            g = build_graph(self.trace, cand.system, self.reports,
+                            cand.eligibility, smp_scale=self.smp_scale,
+                            smp_cost="mean",
+                            smp_seconds_fn=self.smp_seconds_fn)
+            if self.fast:
+                fg = FrozenGraph.freeze(g)
+                entry = (fg, fg.stats, fg.critical_path_s, fg.lower_bound_s)
+            else:
+                entry = (g, g.subgraph_stats(), g.critical_path(),
+                         lower_bound_seconds(g))
+            if text is not None:
+                self._disk.put(text, entry[0])
+            if self.cache_enabled:
+                with self._lock:
+                    self._graphs[key] = entry
+            return (*entry, False)
 
     # ------------------------------------------------------------------
     def evaluate(self, cand: Candidate) -> PerfEstimate:
@@ -1433,12 +1436,10 @@ class Explorer:
         return simulate_fast(payload, cand.system, self.policy,
                              with_schedule=True)
 
-    def _infeasible_outcome(self, cand: Candidate,
-                            t0: float) -> Optional[CandidateOutcome]:
+    def _infeasible_outcome(self, cand: Candidate
+                            ) -> Optional[CandidateOutcome]:
         if cand.fabric and not cand.feasible(self.budget):
-            return CandidateOutcome(
-                name=cand.name, status="infeasible",
-                analysis_seconds=time.perf_counter() - t0)
+            return CandidateOutcome(name=cand.name, status="infeasible")
         if self.budgets is not None and (
                 self.budgets.area_mm2 is not None
                 or self.budgets.power_w is not None):
@@ -1450,25 +1451,22 @@ class Explorer:
                 {"area_mm2": ppa0.area_mm2, "power_w": ppa0.power_w})
             if reason is not None:
                 return CandidateOutcome(
-                    name=cand.name, status="infeasible", error=reason,
-                    analysis_seconds=time.perf_counter() - t0)
+                    name=cand.name, status="infeasible", error=reason)
         return None
 
     def _evaluate_outcome(self, cand: Candidate) \
             -> Tuple[Optional[PerfEstimate], CandidateOutcome]:
-        t0 = time.perf_counter()
-        infeasible = self._infeasible_outcome(cand, t0)
+        infeasible = self._infeasible_outcome(cand)
         if infeasible is not None:
             return None, infeasible
         payload, stats, crit, lb, ghit = self._graph_for(cand)
         sim, ehit = self._simulate(payload, cand)
-        dt = time.perf_counter() - t0
         return self._outcome_from_sim(cand, stats, crit, lb, ghit, ehit,
-                                      sim, dt)
+                                      sim)
 
     def _outcome_from_sim(self, cand: Candidate, stats: Dict[str, object],
                           crit: float, lb: float, ghit: bool, ehit: bool,
-                          sim: Union[SimResult, Retired], dt: float) \
+                          sim: Union[SimResult, Retired]) \
             -> Tuple[Optional[PerfEstimate], CandidateOutcome]:
         if isinstance(sim, Retired):
             # in-flight retirement: the engine proved the lane's final
@@ -1487,8 +1485,8 @@ class Explorer:
                            f"budget {self._prune_energy_cap:.6g}")
             return None, CandidateOutcome(
                 name=cand.name, status=status, critical_path_s=crit,
-                lower_bound_s=bound, analysis_seconds=dt,
-                cached_graph=ghit, cached_eval=ehit, error=err)
+                lower_bound_s=bound, cached_graph=ghit, cached_eval=ehit,
+                error=err)
         objs = ppa_doc = None
         if self.objectives is not None:
             # the single seam every engine path funnels through: annotate
@@ -1507,8 +1505,7 @@ class Explorer:
                     return None, CandidateOutcome(
                         name=cand.name, status="infeasible",
                         makespan_s=sim.makespan, critical_path_s=crit,
-                        lower_bound_s=lb, analysis_seconds=dt,
-                        cached_graph=ghit, cached_eval=ehit,
+                        lower_bound_s=lb, cached_graph=ghit, cached_eval=ehit,
                         bottleneck=sim.bottleneck(), error=reason,
                         objectives=objs, ppa=ppa_doc)
         if self._incumbent is not None:
@@ -1517,11 +1514,10 @@ class Explorer:
             # the engine already folded in is a no-op
             self._incumbent.offer(cand.name, sim.makespan)
         est = PerfEstimate(candidate=cand.name, makespan_s=sim.makespan,
-                           sim=sim, graph_stats=stats, critical_path_s=crit,
-                           analysis_seconds=dt)
+                           sim=sim, graph_stats=stats, critical_path_s=crit)
         return est, CandidateOutcome(
             name=cand.name, status="ok", makespan_s=sim.makespan,
-            critical_path_s=crit, lower_bound_s=lb, analysis_seconds=dt,
+            critical_path_s=crit, lower_bound_s=lb,
             cached_graph=ghit, cached_eval=ehit,
             bottleneck=sim.bottleneck(), objectives=objs, ppa=ppa_doc)
 
@@ -1612,7 +1608,7 @@ class Explorer:
         """
         if deadline_s is not None and deadline_s <= 0:
             raise ValueError(f"deadline_s must be > 0, got {deadline_s!r}")
-        with self._explore_lock:
+        with self._explore_lock, span("explore.sweep"):
             return self._explore(candidates, top_k=top_k, prune=prune,
                                  deadline_s=deadline_s)
 
@@ -1667,8 +1663,7 @@ class Explorer:
                 batch: List[Tuple[int, Candidate]] = []
                 for i in range(base, min(base + chunk, len(cands))):
                     cand = cands[i]
-                    tc = time.perf_counter()
-                    infeasible = self._infeasible_outcome(cand, tc)
+                    infeasible = self._infeasible_outcome(cand)
                     if infeasible is not None:
                         outcomes[i] = infeasible
                         continue
@@ -1687,8 +1682,7 @@ class Explorer:
                                 critical_path_s=crit, lower_bound_s=lb,
                                 cached_graph=ghit,
                                 error=f"energy_j lower bound {floor:.6g} "
-                                      f"exceeds budget {energy_cap:.6g}",
-                                analysis_seconds=time.perf_counter() - tc)
+                                      f"exceeds budget {energy_cap:.6g}")
                             continue
                     cut = threshold()
                     if cut is not None:
@@ -1698,8 +1692,7 @@ class Explorer:
                             outcomes[i] = CandidateOutcome(
                                 name=cand.name, status="pruned",
                                 critical_path_s=crit, lower_bound_s=lb,
-                                cached_graph=ghit,
-                                analysis_seconds=time.perf_counter() - tc)
+                                cached_graph=ghit)
                             continue
                     batch.append((i, cand))
                 # engine demotion may have dropped self.fast / self.batch
@@ -1766,18 +1759,19 @@ class Explorer:
         # sweep must account for its own batch only
         cache = {k: v - stats_before[k]
                  for k, v in self.stats.as_dict().items()}
-        result = ExplorationResult(
-            outcomes=done, wall_seconds=time.perf_counter() - t0,
-            policy=self.policy, n_workers=n_workers, top_k=top_k,
-            cache=cache, estimates=estimates,
-            objectives=list(self.objectives)
-            if self.objectives is not None else None,
-            budgets=self.budgets.as_dict()
-            if self.budgets is not None else None)
-        for rank, o in enumerate(result.ranked):
-            o.rank = rank
-        self._materialise_schedules(result, cands, estimates, kk)
-        self._save_orders()
+        with span("explore.rank"):
+            result = ExplorationResult(
+                outcomes=done, wall_seconds=time.perf_counter() - t0,
+                policy=self.policy, n_workers=n_workers, top_k=top_k,
+                cache=cache, estimates=estimates,
+                objectives=list(self.objectives)
+                if self.objectives is not None else None,
+                budgets=self.budgets.as_dict()
+                if self.budgets is not None else None)
+            for rank, o in enumerate(result.ranked):
+                o.rank = rank
+            self._materialise_schedules(result, cands, estimates, kk)
+            self._save_orders()
         return result
 
     def _chunk_size(self, n_cands: int, prune: bool, procs: int,
@@ -1843,14 +1837,12 @@ class Explorer:
         pending: Dict[Tuple, List[Tuple]] = {}
         graph_info: Dict[Tuple, Tuple] = {}
         for pos, (_, cand) in enumerate(batch):
-            tc = time.perf_counter()
             gkey = _graph_key(cand.system, cand.eligibility)
             payload, stats, crit, lb, ghit = self._graph_for(cand, gkey)
             key, text, hit = self._sim_lookup(cand, gkey)
             if hit is not None:
                 results[pos] = self._outcome_from_sim(
-                    cand, stats, crit, lb, ghit, True, hit,
-                    time.perf_counter() - tc)
+                    cand, stats, crit, lb, ghit, True, hit)
                 continue
             graph_info[gkey] = (payload, stats, crit, lb)
             pending.setdefault(gkey, []).append((pos, cand, key, text, ghit))
@@ -1868,7 +1860,6 @@ class Explorer:
                     self._isolate_candidates(payload, graph_info[gkey],
                                              items, results)
                     continue
-                t0 = time.perf_counter()
                 fam = [cand for _, cand, _, _, _ in items]
                 try:
                     sims = self._lockstep_family(payload, fam,
@@ -1878,14 +1869,13 @@ class Explorer:
                     self._isolate_candidates(payload, graph_info[gkey],
                                              items, results)
                     continue
-                share = (time.perf_counter() - t0) / max(len(items), 1)
                 for (pos, cand, key, text, ghit), sim in zip(items, sims):
                     if not isinstance(sim, Retired):
                         # a retirement marker is not a result: it must
                         # never satisfy a later (possibly unpruned) lookup
                         self._sim_store(key, text, sim)
                     results[pos] = self._outcome_from_sim(
-                        cand, stats, crit, lb, ghit, False, sim, share)
+                        cand, stats, crit, lb, ghit, False, sim)
             return results
         return self._evaluate_process_chunks(pending, graph_info, results)
 
@@ -1997,8 +1987,8 @@ class Explorer:
 
     def _submit_unit(self, ppool: ProcessPoolExecutor, unit: Dict,
                      graph_info: Mapping[Tuple, Tuple]) -> None:
-        """(Re-)submit one unit; records the future, the submit time and
-        the engine active at submission (the demotion guard) on it."""
+        """(Re-)submit one unit; records the future and the engine active
+        at submission (the demotion guard) on it."""
         payload = graph_info[unit["gkey"]][0]
         orders_arg = None
         if self.batch:
@@ -2034,7 +2024,6 @@ class Explorer:
                 self._incumbent.k if self._incumbent is not None else 0,
                 self._family_caps(fam))
         unit["engine"] = self.engine
-        unit["t0"] = time.perf_counter()
         unit["fut"] = ppool.submit(_timed_eval_chunk, ghash, fg_arg, work,
                                    self.policy, self.batch, orders_arg,
                                    self.max_rescue_rounds, prune_arg)
@@ -2086,14 +2075,12 @@ class Explorer:
         if worker_stats:
             self.batch_stats.add_dict(worker_stats)
         sims = dict(pairs)
-        share = (time.perf_counter() - unit["t0"]) \
-            / max(len(unit["items"]), 1)
         for pos, cand, key, text, ghit in unit["items"]:
             sim = sims[pos]
             if not isinstance(sim, Retired):
                 self._sim_store(key, text, sim)
             results[pos] = self._outcome_from_sim(
-                cand, stats, crit, lb, ghit, False, sim, share)
+                cand, stats, crit, lb, ghit, False, sim)
 
     def _evaluate_megabatch(self, pending: Mapping[Tuple, Sequence[Tuple]],
                             graph_info: Mapping[Tuple, Tuple],
@@ -2114,7 +2101,6 @@ class Explorer:
             # one context per family, all sharing the live incumbent —
             # cross-family tightening happens inside the megabatch too
             prunes.append(self._family_prune(fam))
-        t0 = time.perf_counter()
         kw = {} if self.jax_chunk is None else {"chunk": self.jax_chunk}
         fam_sims = simulate_jax_many(
             fams, self.policy, stats=self.batch_stats,
@@ -2122,8 +2108,6 @@ class Explorer:
             compile_cache=self.compile_cache,
             prunes=prunes if any(p is not None for p in prunes) else None,
             **kw)
-        n_total = sum(len(v) for v in pending.values()) or 1
-        share = (time.perf_counter() - t0) / n_total
         for gkey, sims in zip(gkeys, fam_sims):
             _, stats, crit, lb = graph_info[gkey]
             for (pos, cand, key, text, ghit), sim in zip(pending[gkey],
@@ -2131,7 +2115,7 @@ class Explorer:
                 if not isinstance(sim, Retired):
                     self._sim_store(key, text, sim)
                 results[pos] = self._outcome_from_sim(
-                    cand, stats, crit, lb, ghit, False, sim, share)
+                    cand, stats, crit, lb, ghit, False, sim)
         return results
 
     def _family_caps(self, cands: Sequence[Candidate]) \

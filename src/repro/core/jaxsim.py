@@ -85,6 +85,7 @@ from .replay import (BatchStats, JAX_RTOL, Layout,  # noqa: F401
                      RESCUE_MIN, ReplayLibrary, graph_aux, lane_results,
                      simulate_grouped, simulate_many)
 from .simulator import SimResult
+from .spans import span
 from .xlacache import CompileCache
 
 # The jax import is deferred until the engine is actually used: importing
@@ -296,110 +297,113 @@ def _runner():
 
     def run(xs, clocks, ready, placement, busy, seen, kind_pool,
             smp_kid, eft):
-        B = clocks.shape[2]
-        aB = jnp.arange(B)
-        K = xs["own_opts"].shape[2]
-        skid = smp_kid                                      # [B]
-        zero = jnp.zeros((), dtype=clocks.dtype)
+        # the scan's operations carry a stable name in the HLO metadata
+        with jax.named_scope("lockstep_scan"):
+            B = clocks.shape[2]
+            aB = jnp.arange(B)
+            K = xs["own_opts"].shape[2]
+            skid = smp_kid                                      # [B]
+            zero = jnp.zeros((), dtype=clocks.dtype)
 
-        def choose(opts, cost, rt, minc):
-            """Vectorised reference `_choose_kind` over all lanes: options
-            visited in annotation order, strict < on (key, pref) — the
-            lowest-index winner, identical tie-breaks to the exact
-            engines.  ``opts [B, K]`` / ``cost [B, NK]`` are the lanes'
-            own cohorts' tables (lane-aligned by the host pre-gather);
-            ``minc [P, B]`` is the step's hoisted earliest-free-slot
-            reduction, so each option costs a [B] gather instead of its
-            own [B, S] min."""
-            best_k = jnp.full((B,), -1, dtype=placement.dtype)
-            bv = jnp.zeros((B,), dtype=minc.dtype)
-            bp = jnp.zeros((B,), dtype=jnp.int32)
-            for j in range(K):                      # K is static and tiny
-                k = opts[:, j]
+            def choose(opts, cost, rt, minc):
+                """Vectorised reference `_choose_kind` over all lanes: options
+                visited in annotation order, strict < on (key, pref) — the
+                lowest-index winner, identical tie-breaks to the exact
+                engines.  ``opts [B, K]`` / ``cost [B, NK]`` are the lanes'
+                own cohorts' tables (lane-aligned by the host pre-gather);
+                ``minc [P, B]`` is the step's hoisted earliest-free-slot
+                reduction, so each option costs a [B] gather instead of its
+                own [B, S] min."""
+                best_k = jnp.full((B,), -1, dtype=placement.dtype)
+                bv = jnp.zeros((B,), dtype=minc.dtype)
+                bp = jnp.zeros((B,), dtype=jnp.int32)
+                for j in range(K):                      # K is static and tiny
+                    k = opts[:, j]
+                    kk = jnp.maximum(k, 0)
+                    pi = kind_pool[aB, kk]                          # [B]
+                    valid = (k >= 0) & (pi >= 0)
+                    base = cost[aB, kk]
+                    t = minc[jnp.maximum(pi, 0), aB]
+                    start = jnp.maximum(rt, t)
+                    keyv = _bits_add(start, jnp.where(eft, base, zero))
+                    pref = (k == skid).astype(jnp.int32)
+                    better = valid & ((best_k < 0) | (keyv < bv)
+                                      | ((keyv == bv) & (pref < bp)))
+                    bv = jnp.where(better, keyv, bv)
+                    bp = jnp.where(better, pref, bp)
+                    best_k = jnp.where(better, k, best_k)
+                return best_k
+
+            def step(carry, x):
+                (clocks, ready, placement, busy, seen, makespan, prev_rt,
+                 prev_tb, div) = carry
+                valid = x["valid"]                                  # [B]
+                r = x["r"]                   # dummy row n_max on invalid steps
+                rt = ready[r, aB]                                   # [B]
+                tbv = x["tb"]
+                # heap-key monotonicity: a lane whose popped (ready_t, tb)
+                # key ever fails to strictly increase is not executing its
+                # own heap order — flag it for the exact fallback.  Invalid
+                # (padding) steps read the dummy ready row, so every check
+                # and write below is gated on `valid`.
+                div = div | (valid & ((rt < prev_rt)
+                                      | ((rt == prev_rt) & (tbv <= prev_tb))))
+                # (div also absorbs bad dispatches below: any lane that
+                # *live*-executes a row the reference would raise on takes
+                # the exact fallback, which re-raises — or completes when
+                # the lane never actually reaches the row under its own
+                # order)
+
+                # earliest-free slot per (pool, lane), shared by both choose
+                # passes (clocks are only committed after them)
+                minc = jnp.min(clocks, axis=1)                      # [P, B]
+
+                # ---- conditional pass-through (per-lane mask) ---------------
+                c = x["c"]
+                has_cond = (c >= 0) & valid
+                cmax = jnp.maximum(c, 0)
+                pk = placement[cmax, aB]                            # [B]
+                chosen_p = choose(x["par_opts"], x["par_cost"], rt, minc)
+                pk = jnp.where(pk < 0, chosen_p, pk)
+                placement = placement.at[cmax, aB].set(
+                    jnp.where(has_cond, pk, placement[cmax, aB]))
+                live = jnp.where(has_cond, x["act"][aB, jnp.maximum(pk, 0)],
+                                 True) & valid
+
+                # ---- dispatch + commit for the lanes executing the row ------
+                k_own = placement[r, aB]
+                und = k_own < 0
+                chosen_o = choose(x["own_opts"], x["own_cost"], rt, minc)
+                is_comp = x["is_comp"]
+                k = jnp.where(is_comp, jnp.where(und, chosen_o, k_own),
+                              x["k_first"])
+                placement = placement.at[r, aB].set(
+                    jnp.where(is_comp & live & und, k, placement[r, aB]))
+                div = div | (live & (x["bad_row"] | (k < 0)))
                 kk = jnp.maximum(k, 0)
-                pi = kind_pool[aB, kk]                          # [B]
-                valid = (k >= 0) & (pi >= 0)
-                base = cost[aB, kk]
-                t = minc[jnp.maximum(pi, 0), aB]
-                start = jnp.maximum(rt, t)
-                keyv = _bits_add(start, jnp.where(eft, base, zero))
-                pref = (k == skid).astype(jnp.int32)
-                better = valid & ((best_k < 0) | (keyv < bv)
-                                  | ((keyv == bv) & (pref < bp)))
-                bv = jnp.where(better, keyv, bv)
-                bp = jnp.where(better, pref, bp)
-                best_k = jnp.where(better, k, best_k)
-            return best_k
+                p = jnp.maximum(kind_pool[aB, kk], 0)               # [B]
+                base = x["own_cost"][aB, kk]                        # [B]
+                clocks, busy, seen, end = commit(clocks, busy, seen, p, rt,
+                                                 base, live, aB)
+                end_eff = jnp.where(live, end, jnp.where(valid, rt, zero))
+                makespan = jnp.maximum(makespan, end_eff)
+                ready = ready.at[x["succ"], aB[:, None]].max(
+                    end_eff[:, None])
+                prev_rt = jnp.where(valid, rt, prev_rt)
+                prev_tb = jnp.where(valid, tbv, prev_tb)
+                return (clocks, ready, placement, busy, seen, makespan,
+                        prev_rt, prev_tb, div), None
 
-        def step(carry, x):
-            (clocks, ready, placement, busy, seen, makespan, prev_rt,
-             prev_tb, div) = carry
-            valid = x["valid"]                                  # [B]
-            r = x["r"]                   # dummy row n_max on invalid steps
-            rt = ready[r, aB]                                   # [B]
-            tbv = x["tb"]
-            # heap-key monotonicity: a lane whose popped (ready_t, tb) key
-            # ever fails to strictly increase is not executing its own heap
-            # order — flag it for the exact fallback.  Invalid (padding)
-            # steps read the dummy ready row, so every check and write
-            # below is gated on `valid`.
-            div = div | (valid & ((rt < prev_rt)
-                                  | ((rt == prev_rt) & (tbv <= prev_tb))))
-            # (div also absorbs bad dispatches below: any lane that *live*
-            # -executes a row the reference would raise on takes the exact
-            # fallback, which re-raises — or completes when the lane never
-            # actually reaches the row under its own order)
-
-            # earliest-free slot per (pool, lane), shared by both choose
-            # passes (clocks are only committed after them)
-            minc = jnp.min(clocks, axis=1)                      # [P, B]
-
-            # ---- conditional pass-through (per-lane mask) ---------------
-            c = x["c"]
-            has_cond = (c >= 0) & valid
-            cmax = jnp.maximum(c, 0)
-            pk = placement[cmax, aB]                            # [B]
-            chosen_p = choose(x["par_opts"], x["par_cost"], rt, minc)
-            pk = jnp.where(pk < 0, chosen_p, pk)
-            placement = placement.at[cmax, aB].set(
-                jnp.where(has_cond, pk, placement[cmax, aB]))
-            live = jnp.where(has_cond, x["act"][aB, jnp.maximum(pk, 0)],
-                             True) & valid
-
-            # ---- dispatch + commit for the lanes executing the row ------
-            k_own = placement[r, aB]
-            und = k_own < 0
-            chosen_o = choose(x["own_opts"], x["own_cost"], rt, minc)
-            is_comp = x["is_comp"]
-            k = jnp.where(is_comp, jnp.where(und, chosen_o, k_own),
-                          x["k_first"])
-            placement = placement.at[r, aB].set(
-                jnp.where(is_comp & live & und, k, placement[r, aB]))
-            div = div | (live & (x["bad_row"] | (k < 0)))
-            kk = jnp.maximum(k, 0)
-            p = jnp.maximum(kind_pool[aB, kk], 0)               # [B]
-            base = x["own_cost"][aB, kk]                        # [B]
-            clocks, busy, seen, end = commit(clocks, busy, seen, p, rt,
-                                             base, live, aB)
-            end_eff = jnp.where(live, end, jnp.where(valid, rt, zero))
-            makespan = jnp.maximum(makespan, end_eff)
-            ready = ready.at[x["succ"], aB[:, None]].max(
-                end_eff[:, None])
-            prev_rt = jnp.where(valid, rt, prev_rt)
-            prev_tb = jnp.where(valid, tbv, prev_tb)
-            return (clocks, ready, placement, busy, seen, makespan,
-                    prev_rt, prev_tb, div), None
-
-        makespan = jnp.zeros((B,), dtype=clocks.dtype)
-        # a negative bit pattern sorts below every (non-negative) time
-        prev_rt = jnp.full((B,), -1, dtype=clocks.dtype)
-        prev_tb = jnp.full((B,), -1, dtype=xs["tb"].dtype)
-        div = jnp.zeros((B,), dtype=bool)
-        init = (clocks, ready, placement, busy, seen, makespan, prev_rt,
-                prev_tb, div)
-        (clocks, ready, placement, busy, seen, makespan, _rt, _tb,
-         div), _ = jax.lax.scan(step, init, xs)
-        return makespan, busy, seen, placement, div
+            makespan = jnp.zeros((B,), dtype=clocks.dtype)
+            # a negative bit pattern sorts below every (non-negative) time
+            prev_rt = jnp.full((B,), -1, dtype=clocks.dtype)
+            prev_tb = jnp.full((B,), -1, dtype=xs["tb"].dtype)
+            div = jnp.zeros((B,), dtype=bool)
+            init = (clocks, ready, placement, busy, seen, makespan, prev_rt,
+                    prev_tb, div)
+            (clocks, ready, placement, busy, seen, makespan, _rt, _tb,
+             div), _ = jax.lax.scan(step, init, xs)
+            return makespan, busy, seen, placement, div
 
     return run
 
@@ -604,7 +608,8 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                   policy: str, *, chunk: int,
                   compile_cache: Optional[CompileCache] = None,
                   step_impl: str = "auto",
-                  slot_bucketed: bool = False
+                  slot_bucketed: bool = False,
+                  stats: Optional[BatchStats] = None
                   ) -> List[Tuple[Dict[int, SimResult], List[int],
                                   Dict[int, float]]]:
     """Drive every lane of every ``(fg, order, layouts, cutoffs)`` cohort
@@ -649,6 +654,8 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
     Returns one ``(done, diverged, retired)`` triple per cohort in the
     :data:`repro.core.replay.LockstepFn` contract, positions indexing the
     cohort's own ``layouts``; ``retired`` maps position to its bound.
+    ``stats`` receives the scan counters (``scan_lane_steps``,
+    ``scan_kept_lane_steps``, ``h2d_bytes``).
     """
     jax, jnp = _jax()
     resolve_step_impl(step_impl)
@@ -661,132 +668,140 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
     cc = compile_cache if compile_cache is not None else _MEM_COMPILE_CACHE
     eft = policy == "eft"
 
-    per = []
-    for fg, order, layouts, cuts in cohorts:
-        pool_names, _, kind_pool = layouts[0]           # template-shared
-        kinds = fg.kinds
-        caps = _pool_caps(fg, order, kind_pool, len(pool_names))
-        lane_counts = [lay[1] for lay in layouts]
-        per.append({
-            "fg": fg, "xs": _group_xs(fg, order, kind_pool), "cuts": cuts,
-            "pool_names": pool_names, "kind_pool": list(kind_pool),
-            "smp_kid": kinds.index("smp") if "smp" in kinds else -1,
-            "lane_counts": lane_counts,
-            # slot-axis need per lane: pool slot counts clamped to the
-            # dispatch caps (exact — see _pool_caps)
-            "needs": [max(1, max((min(int(c), int(caps[p]))
-                                  for p, c in enumerate(cnt)), default=1))
-                      for cnt in lane_counts],
-            "n": fg.n, "P": len(pool_names),
-        })
-    G = len(per)
-    n_max = max(c["n"] for c in per)
-    P_max = max(c["P"] for c in per)
-    T_max = max(len(c["xs"]["r"]) for c in per)
-    K = max(c["xs"]["own_opts"].shape[1] for c in per)
-    NK = max(len(c["kind_pool"]) for c in per)
-    SC = max(c["xs"]["succ"].shape[1] for c in per)
-    S = _bucket(max(nd for c in per for nd in c["needs"]), cap=1 << 30)
+    with span("jaxsim.prep"):
+        per = []
+        for fg, order, layouts, cuts in cohorts:
+            pool_names, _, kind_pool = layouts[0]           # template-shared
+            kinds = fg.kinds
+            caps = _pool_caps(fg, order, kind_pool, len(pool_names))
+            lane_counts = [lay[1] for lay in layouts]
+            per.append({
+                "fg": fg, "xs": _group_xs(fg, order, kind_pool), "cuts": cuts,
+                "pool_names": pool_names, "kind_pool": list(kind_pool),
+                "smp_kid": kinds.index("smp") if "smp" in kinds else -1,
+                "lane_counts": lane_counts,
+                # slot-axis need per lane: pool slot counts clamped to the
+                # dispatch caps (exact — see _pool_caps)
+                "needs": [max(1, max((min(int(c), int(caps[p]))
+                                      for p, c in enumerate(cnt)), default=1))
+                          for cnt in lane_counts],
+                "n": fg.n, "P": len(pool_names),
+            })
+        G = len(per)
+        n_max = max(c["n"] for c in per)
+        P_max = max(c["P"] for c in per)
+        T_max = max(len(c["xs"]["r"]) for c in per)
+        K = max(c["xs"]["own_opts"].shape[1] for c in per)
+        NK = max(len(c["kind_pool"]) for c in per)
+        SC = max(c["xs"]["succ"].shape[1] for c in per)
+        S = _bucket(max(nd for c in per for nd in c["needs"]), cap=1 << 30)
 
-    kind_pool_m = np.full((G, NK), -1, dtype=np.int32)
-    smp_kid_m = np.full((G,), -1, dtype=np.int32)
-    for gi, c in enumerate(per):
-        nk = len(c["kind_pool"])
-        kind_pool_m[gi, :nk] = c["kind_pool"]
-        smp_kid_m[gi] = c["smp_kid"]
-
-    _mega_memo: List[Optional[Dict[str, np.ndarray]]] = [None]
-
-    def _mega() -> Dict[str, np.ndarray]:
-        """The ``[T_max, G, ...]`` task-axis-padded step-input stack —
-        built lazily: a warm repeat sweep whose slices all hit the device
-        cache never stacks it at all."""
-        if _mega_memo[0] is not None:
-            return _mega_memo[0]
-        mega = {
-            "valid": np.zeros((T_max, G), dtype=bool),
-            "r": np.full((T_max, G), n_max, dtype=np.int32),
-            "tb": np.zeros((T_max, G), dtype=np.int64),
-            "c": np.full((T_max, G), -1, dtype=np.int32),
-            "is_comp": np.zeros((T_max, G), dtype=bool),
-            "k_first": np.zeros((T_max, G), dtype=np.int32),
-            "own_opts": np.full((T_max, G, K), -1, dtype=np.int32),
-            "own_cost": np.zeros((T_max, G, NK), dtype=np.float64),
-            "par_opts": np.full((T_max, G, K), -1, dtype=np.int32),
-            "par_cost": np.zeros((T_max, G, NK), dtype=np.float64),
-            "act": np.zeros((T_max, G, NK), dtype=bool),
-            "bad_row": np.zeros((T_max, G), dtype=bool),
-            "succ": np.full((T_max, G, SC), n_max, dtype=np.int32),
-        }
+        kind_pool_m = np.full((G, NK), -1, dtype=np.int32)
+        smp_kid_m = np.full((G,), -1, dtype=np.int32)
         for gi, c in enumerate(per):
-            xs = c["xs"]
-            T, n = len(xs["r"]), c["n"]
-            kg, nk, sc = (xs["own_opts"].shape[1], xs["own_cost"].shape[1],
-                          xs["succ"].shape[1])
-            mega["valid"][:T, gi] = True
-            for f in ("r", "tb", "c", "is_comp", "k_first", "bad_row"):
-                mega[f][:T, gi] = xs[f]
-            mega["own_opts"][:T, gi, :kg] = xs["own_opts"]
-            mega["par_opts"][:T, gi, :kg] = xs["par_opts"]
-            mega["own_cost"][:T, gi, :nk] = xs["own_cost"]
-            mega["par_cost"][:T, gi, :nk] = xs["par_cost"]
-            mega["act"][:T, gi, :nk] = xs["act"]
-            # each cohort's own dummy successor row is its fg.n — remap to
-            # the megabatch-wide dummy ready row n_max
-            mega["succ"][:T, gi, :sc] = np.where(xs["succ"] == n, n_max,
-                                                 xs["succ"])
-        _mega_memo[0] = mega
-        return mega
+            nk = len(c["kind_pool"])
+            kind_pool_m[gi, :nk] = c["kind_pool"]
+            smp_kid_m[gi] = c["smp_kid"]
 
-    # cache key prefix for the lane-aligned device blocks: content-based
-    # (graph hash × order × pool template per cohort), so repeat sweeps
-    # hit it across fresh Explorers — a warm re-rank re-launches resident
-    # device blocks without re-stacking or re-transferring anything
-    base_key = (tuple((c["fg"].content_hash(), tuple(c["xs"]["r"]),
-                       tuple(c["kind_pool"])) for c in per),
-                (T_max, n_max, P_max, K, NK, SC),
-                kind_pool_m.tobytes(), smp_kid_m.tobytes())
+        _mega_memo: List[Optional[Dict[str, np.ndarray]]] = [None]
 
-    lanes_flat = [(gi, pos) for gi, c in enumerate(per)
-                  for pos in range(len(c["lane_counts"]))]
-    accs = [{"kept": [], "mk": [], "busy": [], "seen": [], "place": []}
-            for _ in per]
-    diverged: List[List[int]] = [[] for _ in per]
-    retired: List[Dict[int, float]] = [{} for _ in per]
-    step = _bucket(chunk, cap=chunk)    # effective power-of-two slice width
+        def _mega() -> Dict[str, np.ndarray]:
+            """The ``[T_max, G, ...]`` task-axis-padded step-input stack —
+            built lazily: a warm repeat sweep whose slices all hit the device
+            cache never stacks it at all."""
+            if _mega_memo[0] is not None:
+                return _mega_memo[0]
+            mega = {
+                "valid": np.zeros((T_max, G), dtype=bool),
+                "r": np.full((T_max, G), n_max, dtype=np.int32),
+                "tb": np.zeros((T_max, G), dtype=np.int64),
+                "c": np.full((T_max, G), -1, dtype=np.int32),
+                "is_comp": np.zeros((T_max, G), dtype=bool),
+                "k_first": np.zeros((T_max, G), dtype=np.int32),
+                "own_opts": np.full((T_max, G, K), -1, dtype=np.int32),
+                "own_cost": np.zeros((T_max, G, NK), dtype=np.float64),
+                "par_opts": np.full((T_max, G, K), -1, dtype=np.int32),
+                "par_cost": np.zeros((T_max, G, NK), dtype=np.float64),
+                "act": np.zeros((T_max, G, NK), dtype=bool),
+                "bad_row": np.zeros((T_max, G), dtype=bool),
+                "succ": np.full((T_max, G, SC), n_max, dtype=np.int32),
+            }
+            for gi, c in enumerate(per):
+                xs = c["xs"]
+                T, n = len(xs["r"]), c["n"]
+                kg, nk, sc = (xs["own_opts"].shape[1], xs["own_cost"].shape[1],
+                              xs["succ"].shape[1])
+                mega["valid"][:T, gi] = True
+                for f in ("r", "tb", "c", "is_comp", "k_first", "bad_row"):
+                    mega[f][:T, gi] = xs[f]
+                mega["own_opts"][:T, gi, :kg] = xs["own_opts"]
+                mega["par_opts"][:T, gi, :kg] = xs["par_opts"]
+                mega["own_cost"][:T, gi, :nk] = xs["own_cost"]
+                mega["par_cost"][:T, gi, :nk] = xs["par_cost"]
+                mega["act"][:T, gi, :nk] = xs["act"]
+                # each cohort's own dummy successor row is its fg.n —
+                # remap to the megabatch-wide dummy ready row n_max
+                mega["succ"][:T, gi, :sc] = np.where(xs["succ"] == n, n_max,
+                                                     xs["succ"])
+            _mega_memo[0] = mega
+            return mega
 
-    def _need(lane):
-        gi, pos = lane
-        return per[gi]["needs"][pos]
+        # cache key prefix for the lane-aligned device blocks: content-based
+        # (graph hash × order × pool template per cohort), so repeat sweeps
+        # hit it across fresh Explorers — a warm re-rank re-launches resident
+        # device blocks without re-stacking or re-transferring anything
+        base_key = (tuple((c["fg"].content_hash(), tuple(c["xs"]["r"]),
+                           tuple(c["kind_pool"])) for c in per),
+                    (T_max, n_max, P_max, K, NK, SC),
+                    kind_pool_m.tobytes(), smp_kid_m.tobytes())
 
-    def _width(S_sl: int) -> int:
-        """Lane width keeping the slice's clock block near the cache
-        target: ``P_max × S_sl × width ≈ TARGET_SLICE_ELEMS``, floored at
-        16 lanes and capped by ``chunk``."""
-        return max(16, min(step,
-                           _bucket(TARGET_SLICE_ELEMS // (P_max * S_sl),
-                                   cap=1 << 30)))
+        lanes_flat = [(gi, pos) for gi, c in enumerate(per)
+                      for pos in range(len(c["lane_counts"]))]
+        accs = [{"kept": [], "mk": [], "busy": [], "seen": [], "place": []}
+                for _ in per]
+        diverged: List[List[int]] = [[] for _ in per]
+        retired: List[Dict[int, float]] = [{} for _ in per]
+        # the effective power-of-two slice width
+        step = _bucket(chunk, cap=chunk)
 
-    slices: List[Tuple[List[Tuple[int, int]], int]] = []
-    if slot_bucketed:
-        by_slots = sorted(lanes_flat, key=lambda t: (_need(t), t))
-        cur: List[Tuple[int, int]] = []
-        cur_S = 1
-        for lane in by_slots:
-            nb = max(cur_S, _bucket(_need(lane), cap=1 << 30))
-            if cur and len(cur) >= _width(nb):
+        def _need(lane):
+            gi, pos = lane
+            return per[gi]["needs"][pos]
+
+        def _width(S_sl: int) -> int:
+            """Lane width keeping the slice's clock block near the cache
+            target: ``P_max × S_sl × width ≈ TARGET_SLICE_ELEMS``, floored
+            at 16 lanes and capped by ``chunk``."""
+            return max(16, min(step,
+                               _bucket(TARGET_SLICE_ELEMS // (P_max * S_sl),
+                                       cap=1 << 30)))
+
+        slices: List[Tuple[List[Tuple[int, int]], int]] = []
+        if slot_bucketed:
+            by_slots = sorted(lanes_flat, key=lambda t: (_need(t), t))
+            cur: List[Tuple[int, int]] = []
+            cur_S = 1
+            for lane in by_slots:
+                nb = max(cur_S, _bucket(_need(lane), cap=1 << 30))
+                if cur and len(cur) >= _width(nb):
+                    slices.append((cur, cur_S))
+                    cur, cur_S = [], 1
+                    nb = _bucket(_need(lane), cap=1 << 30)
+                cur.append(lane)
+                cur_S = nb
+            if cur:
                 slices.append((cur, cur_S))
-                cur, cur_S = [], 1
-                nb = _bucket(_need(lane), cap=1 << 30)
-            cur.append(lane)
-            cur_S = nb
-        if cur:
-            slices.append((cur, cur_S))
-    else:
-        slices = [(lanes_flat[lo:lo + step], S)
-                  for lo in range(0, len(lanes_flat), step)]
+        else:
+            slices = [(lanes_flat[lo:lo + step], S)
+                      for lo in range(0, len(lanes_flat), step)]
 
     with jax.enable_x64(True):
+        def _put(a: np.ndarray):
+            """One host-to-device upload, counted in ``h2d_bytes``."""
+            if stats is not None:
+                stats.h2d_bytes += a.nbytes
+            return jnp.asarray(a)
+
         # lane-aligned step inputs per distinct cohort-index vector: the
         # host gathers [T, G, ...] -> [T, B, ...] once per slice shape so
         # the compiled body carries no gather ops (and no G in its shape
@@ -799,11 +814,11 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
             hit = _DEV_XS_CACHE.get(key)
             if hit is None:
                 mega = _mega()
-                hit = ({k: jnp.asarray(_times(np.ascontiguousarray(
-                            v[:, g_np])))
-                        for k, v in mega.items()},
-                       jnp.asarray(kind_pool_m[g_np]),
-                       jnp.asarray(smp_kid_m[g_np]))
+                host = {k: _times(np.ascontiguousarray(v[:, g_np]))
+                        for k, v in mega.items()}
+                with span("jaxsim.transfer"):
+                    hit = ({k: _put(v) for k, v in host.items()},
+                           _put(kind_pool_m[g_np]), _put(smp_kid_m[g_np]))
                 if len(_DEV_XS_CACHE) >= _DEV_XS_CACHE_CAP:
                     _DEV_XS_CACHE.popitem(last=False)
                 _DEV_XS_CACHE[key] = hit
@@ -811,30 +826,41 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 _DEV_XS_CACHE.move_to_end(key)
             return hit
 
+        launched = []
         for sl, S_sl in slices:
-            B = _bucket(len(sl), cap=chunk)
-            # pad lanes replicate the last real lane: finite, well-defined
-            # state whose results are simply dropped before assembly
-            padded = sl + [sl[-1]] * (B - len(sl))
-            g_np = np.fromiter((gi for gi, _ in padded), dtype=np.int32,
-                               count=B)
-            clocks = np.full((P_max, S_sl, B), np.inf)
-            for li, (gi, pos) in enumerate(padded):
-                for p, cnt in enumerate(per[gi]["lane_counts"][pos]):
-                    clocks[p, :cnt, li] = 0.0
-            xs_j, kp_j, sk_j = _lane_aligned(g_np)
-            args = (xs_j, jnp.asarray(_times(clocks)),
-                    jnp.zeros((n_max + 1, B), dtype=jnp.int64),  # ready
-                    jnp.full((n_max + 1, B), -1, dtype=jnp.int32),
-                    jnp.zeros((P_max, B), dtype=jnp.int64),     # busy
-                    jnp.zeros((P_max, B), dtype=bool),          # seen
-                    kp_j, sk_j, jnp.asarray(eft))
-            exe = _load_runner(cc, args)
-            makespan, busy, seen, placement, div = exe(*args)
-            div_np = np.asarray(div)
-            mk_np = np.asarray(makespan).view(np.float64)
-            busy_np = np.asarray(busy).view(np.float64)
-            seen_np, place_np = np.asarray(seen), np.asarray(placement)
+            with span("jaxsim.prep"):
+                B = _bucket(len(sl), cap=chunk)
+                # pad lanes replicate the last real lane: finite,
+                # well-defined state whose results are simply dropped
+                # before assembly
+                padded = sl + [sl[-1]] * (B - len(sl))
+                g_np = np.fromiter((gi for gi, _ in padded), dtype=np.int32,
+                                   count=B)
+                clocks = np.full((P_max, S_sl, B), np.inf)
+                for li, (gi, pos) in enumerate(padded):
+                    for p, cnt in enumerate(per[gi]["lane_counts"][pos]):
+                        clocks[p, :cnt, li] = 0.0
+                xs_j, kp_j, sk_j = _lane_aligned(g_np)
+                with span("jaxsim.transfer"):
+                    args = (xs_j, _put(_times(clocks)),
+                            # ready, placement, busy, seen: made on device
+                            jnp.zeros((n_max + 1, B), dtype=jnp.int64),
+                            jnp.full((n_max + 1, B), -1, dtype=jnp.int32),
+                            jnp.zeros((P_max, B), dtype=jnp.int64),
+                            jnp.zeros((P_max, B), dtype=bool),
+                            kp_j, sk_j, _put(np.asarray(eft)))
+                exe = _load_runner(cc, args)
+            if stats is not None:
+                stats.scan_lane_steps += B * T_max
+            with span("jaxsim.launch"):
+                makespan, busy, seen, placement, div = exe(*args)
+                launched.append((sl, np.asarray(div),
+                                 np.asarray(makespan).view(np.float64),
+                                 np.asarray(busy).view(np.float64),
+                                 np.asarray(seen), np.asarray(placement)))
+
+    with span("replay.assemble"):
+        for sl, div_np, mk_np, busy_np, seen_np, place_np in launched:
             for li, (gi, pos) in enumerate(sl):
                 if div_np[li]:
                     diverged[gi].append(pos)
@@ -851,20 +877,22 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 acc["busy"].append(busy_np[:c["P"], li:li + 1])
                 acc["seen"].append(seen_np[:c["P"], li:li + 1])
                 acc["place"].append(place_np[:c["n"], li:li + 1])
-
-    results: List[Tuple[Dict[int, SimResult], List[int],
-                        Dict[int, float]]] = []
-    for gi, c in enumerate(per):
-        acc = accs[gi]
-        done: Dict[int, SimResult] = {}
-        if acc["kept"]:
-            done = lane_results(
-                c["fg"], c["pool_names"], c["lane_counts"], acc["kept"],
-                policy, np.concatenate(acc["mk"]),
-                np.concatenate(acc["busy"], axis=1),
-                np.concatenate(acc["seen"], axis=1),
-                np.concatenate(acc["place"], axis=1).astype(np.int64))
-        results.append((done, diverged[gi], retired[gi]))
+        results: List[Tuple[Dict[int, SimResult], List[int],
+                            Dict[int, float]]] = []
+        for gi, c in enumerate(per):
+            acc = accs[gi]
+            if stats is not None:
+                stats.scan_kept_lane_steps += \
+                    len(acc["kept"]) * len(c["xs"]["r"])
+            done: Dict[int, SimResult] = {}
+            if acc["kept"]:
+                done = lane_results(
+                    c["fg"], c["pool_names"], c["lane_counts"], acc["kept"],
+                    policy, np.concatenate(acc["mk"]),
+                    np.concatenate(acc["busy"], axis=1),
+                    np.concatenate(acc["seen"], axis=1),
+                    np.concatenate(acc["place"], axis=1).astype(np.int64))
+            results.append((done, diverged[gi], retired[gi]))
     return results
 
 
@@ -873,14 +901,15 @@ def _scan_group(fg: FrozenGraph, order: Sequence[int],
                 cutoffs: Optional[np.ndarray] = None, *,
                 chunk: int = DEFAULT_CHUNK,
                 compile_cache: Optional[CompileCache] = None,
-                step_impl: str = "auto"
+                step_impl: str = "auto",
+                stats: Optional[BatchStats] = None
                 ) -> Tuple[Dict[int, SimResult], List[int],
                            Dict[int, float]]:
     """One-cohort form of :func:`_scan_cohorts` — the per-graph
     :data:`repro.core.replay.LockstepFn`."""
     (triple,) = _scan_cohorts([(fg, order, layouts, cutoffs)], policy,
                               chunk=chunk, compile_cache=compile_cache,
-                              step_impl=step_impl)
+                              step_impl=step_impl, stats=stats)
     return triple
 
 
@@ -930,7 +959,8 @@ def simulate_jax(fg: FrozenGraph, systems: Sequence[SystemConfig],
 
     def lockstep(fg, order, layouts, policy, cutoffs=None):
         return _scan_group(fg, order, layouts, policy, cutoffs, chunk=chunk,
-                           compile_cache=compile_cache, step_impl=step_impl)
+                           compile_cache=compile_cache, step_impl=step_impl,
+                           stats=stats)
 
     return simulate_grouped(fg, systems, policy, min_lockstep=min_lockstep,
                             stats=stats, library=library,
@@ -970,7 +1000,8 @@ def simulate_jax_many(items: Sequence[Tuple[FrozenGraph,
     def lockstep_many(cohorts):
         return _scan_cohorts(cohorts, policy, chunk=chunk,
                              compile_cache=compile_cache,
-                             step_impl=step_impl, slot_bucketed=True)
+                             step_impl=step_impl, slot_bucketed=True,
+                             stats=stats)
 
     return simulate_many(items, policy, lockstep_many_fn=lockstep_many,
                          min_lockstep=min_lockstep, stats=stats,

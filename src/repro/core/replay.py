@@ -67,6 +67,7 @@ import numpy as np
 from .devices import SystemConfig
 from .fastsim import FrozenGraph, LanePruned, pool_layout, simulate_fast
 from .simulator import SimResult
+from .spans import span
 
 # Below this many lanes per group the per-step dispatch overhead outweighs
 # the vectorisation win and simulate_fast per lane is faster.
@@ -171,6 +172,15 @@ class BatchStats:
     ``retire_sweeps`` counts lockstep sweeps that retired at least one
     lane; ``incumbent_updates`` counts cutoff tightenings folded in from
     :class:`Incumbent` trackers (local and worker-side).
+
+    Scan counters (the jax engine only; zero elsewhere):
+    ``scan_lane_steps`` sums lanes × steps over every scan launch, the
+    work the device was asked for, padding lanes and padding steps
+    included; ``scan_kept_lane_steps`` sums, over the lanes whose scan
+    result was kept (neither diverged nor retired), the steps of their own
+    cohort's order; ``h2d_bytes`` counts the bytes uploaded host→device
+    for the scan (step-input blocks on a device-cache miss, and the
+    arguments each launch builds on the host).
     """
 
     groups: int = 0
@@ -185,6 +195,9 @@ class BatchStats:
     retired_lanes: int = 0
     retire_sweeps: int = 0
     incumbent_updates: int = 0
+    scan_lane_steps: int = 0
+    scan_kept_lane_steps: int = 0
+    h2d_bytes: int = 0
 
     def as_dict(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -1078,8 +1091,9 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                ) -> Union[SimResult, Retired]:
         fg, systems = items[gi]
         pr = pr_of(gi)
-        res = _serial_sim(fg, systems[i], policy, pr, i,
-                          with_schedule=with_schedule, order_out=out)
+        with span("replay.exact"):
+            res = _serial_sim(fg, systems[i], policy, pr, i,
+                              with_schedule=with_schedule, order_out=out)
         if isinstance(res, Retired):
             if stats is not None:
                 stats.retired_lanes += 1
@@ -1088,96 +1102,72 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
         return res
 
     # ---- plan: route every group's lanes to (order, cohort) ------------
-    cohorts: List[Dict] = []
-    for gi, (fg, systems) in enumerate(items):
-        layouts = [pool_layout(fg.kinds, s) for s in systems]
-        fams: Dict[Tuple, List[int]] = {}
-        for i, lay in enumerate(layouts):
-            fams.setdefault((tuple(lay[0]), tuple(lay[2])), []).append(i)
-        for lanes in fams.values():
-            if stats is not None:
-                stats.groups += 1
-            if len(lanes) < min_lockstep:
-                for i in lanes:
-                    results[gi][i] = serial(gi, i)
+    with span("replay.plan"):
+        cohorts: List[Dict] = []
+        for gi, (fg, systems) in enumerate(items):
+            layouts = [pool_layout(fg.kinds, s) for s in systems]
+            fams: Dict[Tuple, List[int]] = {}
+            for i, lay in enumerate(layouts):
+                fams.setdefault((tuple(lay[0]), tuple(lay[2])), []).append(i)
+            for lanes in fams.values():
                 if stats is not None:
-                    stats.small_group_lanes += len(lanes)
-                continue
-            key = lib.key(fg, layouts[lanes[0]], policy)
-            pr = pr_of(gi)
-            if pr is not None and pr.deficit():
-                # phase-0 incumbent seeding, as in replay_group: the most-
-                # parallel lanes run serially (orders recorded) so the
-                # megabatch launches with a finite cutoff
-                seeds = sorted(lanes, key=lambda i: (-sum(layouts[i][1]),
-                                                     i))[:pr.deficit()]
-                for i in seeds:
-                    out0: List[int] = []
-                    results[gi][i] = serial(gi, i, out0)
-                    if isinstance(results[gi][i], Retired):
-                        continue            # partial order: never recorded
-                    pos0 = lib.record(key, out0, tuple(layouts[i][1]))
-                    if stats is not None:
-                        if pos0 is None:
-                            stats.serial_fallback_lanes += 1
-                        else:
-                            stats.reference_lanes += 1
-                taken = set(seeds)
-                lanes = [i for i in lanes if i not in taken]
-                if not lanes:
-                    continue
-            orders, sig_map, pins = lib.lookup(key)
-            grp = {"gi": gi, "fg": fg, "key": key, "layouts": layouts,
-                   "n_cached": len(orders), "discoveries": 0}
-            order_by_pos: Dict[int, Tuple[int, ...]] = dict(enumerate(orders))
-            routed: Dict[int, List[int]] = {}
-            unrouted: List[int] = []
-            for i in lanes:
-                sig = tuple(layouts[i][1])
-                if sig in pins:
-                    results[gi][i] = serial(gi, i)
-                    if stats is not None and \
-                            not isinstance(results[gi][i], Retired):
-                        stats.order_pinned_lanes += 1
-                        stats.order_hits += 1
-                    continue
-                pos = sig_map.get(sig)
-                if pos is not None and 0 <= pos < len(orders):
-                    routed.setdefault(pos, []).append(i)
-                else:
-                    unrouted.append(i)
-            if unrouted and not orders:
-                # cold group: one serial reference discovery (the
-                # most-parallel lane), everyone else rides its fresh order
-                # in the megabatch — replay_group's reference sweep folded
-                # into the main dispatch
-                if max_rounds <= 0:
-                    for i in unrouted:
+                    stats.groups += 1
+                if len(lanes) < min_lockstep:
+                    for i in lanes:
                         results[gi][i] = serial(gi, i)
-                        if stats is not None and \
-                                not isinstance(results[gi][i], Retired):
-                            stats.serial_fallback_lanes += 1
-                    unrouted = []
-                else:
-                    j = max(unrouted,
-                            key=lambda i: (sum(layouts[i][1]), i))
-                    unrouted.remove(j)
-                    out: List[int] = []
-                    results[gi][j] = serial(gi, j, out)
-                    grp["discoveries"] += 1
-                    if isinstance(results[gi][j], Retired):
-                        # the group's likeliest winner is already beaten:
-                        # no order to ride — the rest go serial, where the
-                        # same cutoff aborts them just as fast
-                        pos = None
-                    else:
-                        pos = lib.record(key, out, tuple(layouts[j][1]))
+                    if stats is not None:
+                        stats.small_group_lanes += len(lanes)
+                    continue
+                key = lib.key(fg, layouts[lanes[0]], policy)
+                pr = pr_of(gi)
+                if pr is not None and pr.deficit():
+                    # phase-0 incumbent seeding, as in replay_group: the most-
+                    # parallel lanes run serially (orders recorded) so the
+                    # megabatch launches with a finite cutoff
+                    seeds = sorted(lanes, key=lambda i: (-sum(layouts[i][1]),
+                                                         i))[:pr.deficit()]
+                    for i in seeds:
+                        out0: List[int] = []
+                        results[gi][i] = serial(gi, i, out0)
+                        if isinstance(results[gi][i], Retired):
+                            continue            # partial order: never recorded
+                        pos0 = lib.record(key, out0, tuple(layouts[i][1]))
                         if stats is not None:
-                            if pos is None:
+                            if pos0 is None:
                                 stats.serial_fallback_lanes += 1
                             else:
                                 stats.reference_lanes += 1
-                    if pos is None:         # key full (shared library)
+                    taken = set(seeds)
+                    lanes = [i for i in lanes if i not in taken]
+                    if not lanes:
+                        continue
+                orders, sig_map, pins = lib.lookup(key)
+                grp = {"gi": gi, "fg": fg, "key": key, "layouts": layouts,
+                       "n_cached": len(orders), "discoveries": 0}
+                order_by_pos: Dict[int, Tuple[int, ...]] = \
+                    dict(enumerate(orders))
+                routed: Dict[int, List[int]] = {}
+                unrouted: List[int] = []
+                for i in lanes:
+                    sig = tuple(layouts[i][1])
+                    if sig in pins:
+                        results[gi][i] = serial(gi, i)
+                        if stats is not None and \
+                                not isinstance(results[gi][i], Retired):
+                            stats.order_pinned_lanes += 1
+                            stats.order_hits += 1
+                        continue
+                    pos = sig_map.get(sig)
+                    if pos is not None and 0 <= pos < len(orders):
+                        routed.setdefault(pos, []).append(i)
+                    else:
+                        unrouted.append(i)
+                if unrouted and not orders:
+                    # cold group: one serial reference discovery (the
+                    # most-parallel lane), everyone else rides its fresh
+                    # order in the megabatch — replay_group's reference
+                    # sweep folded into the main dispatch
+                    if max_rounds <= 0:
                         for i in unrouted:
                             results[gi][i] = serial(gi, i)
                             if stats is not None and \
@@ -1185,32 +1175,59 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                                 stats.serial_fallback_lanes += 1
                         unrouted = []
                     else:
-                        order_by_pos[pos] = tuple(out)
-                        routed.setdefault(pos, []).extend(unrouted)
-                        unrouted = []
-            elif unrouted:
-                # untried signatures take the insertion-order first order
-                # (the original reference), like phase 2's first trial
-                routed.setdefault(0, []).extend(unrouted)
-            for pos, cl in routed.items():
-                cohorts.append({"grp": grp, "position": pos,
-                                "order": order_by_pos[pos], "lanes": cl})
+                        j = max(unrouted,
+                                key=lambda i: (sum(layouts[i][1]), i))
+                        unrouted.remove(j)
+                        out: List[int] = []
+                        results[gi][j] = serial(gi, j, out)
+                        grp["discoveries"] += 1
+                        if isinstance(results[gi][j], Retired):
+                            # the group's likeliest winner is already
+                            # beaten: no order to ride — the rest go
+                            # serial, where the same cutoff aborts them
+                            # just as fast
+                            pos = None
+                        else:
+                            pos = lib.record(key, out, tuple(layouts[j][1]))
+                            if stats is not None:
+                                if pos is None:
+                                    stats.serial_fallback_lanes += 1
+                                else:
+                                    stats.reference_lanes += 1
+                        if pos is None:         # key full (shared library)
+                            for i in unrouted:
+                                results[gi][i] = serial(gi, i)
+                                if stats is not None and not isinstance(
+                                        results[gi][i], Retired):
+                                    stats.serial_fallback_lanes += 1
+                            unrouted = []
+                        else:
+                            order_by_pos[pos] = tuple(out)
+                            routed.setdefault(pos, []).extend(unrouted)
+                            unrouted = []
+                elif unrouted:
+                    # untried signatures take the insertion-order first order
+                    # (the original reference), like phase 2's first trial
+                    routed.setdefault(0, []).extend(unrouted)
+                for pos, cl in routed.items():
+                    cohorts.append({"grp": grp, "position": pos,
+                                    "order": order_by_pos[pos], "lanes": cl})
 
-    # A megabatch below min_lockstep is a doomed sweep (the same economics
-    # as replay_group's thin routed cohorts): route its lanes straight to
-    # the exact serial path instead.
-    if cohorts and sum(len(c["lanes"]) for c in cohorts) < min_lockstep:
-        for c in cohorts:
-            grp = c["grp"]
-            gi = grp["gi"]
-            for i in c["lanes"]:
-                results[gi][i] = serial(gi, i)
-                if stats is not None and \
-                        not isinstance(results[gi][i], Retired):
-                    stats.order_pinned_lanes += 1
-                    if c["position"] < grp["n_cached"]:
-                        stats.order_hits += 1
-        cohorts = []
+        # A megabatch below min_lockstep is a doomed sweep (the same economics
+        # as replay_group's thin routed cohorts): route its lanes straight to
+        # the exact serial path instead.
+        if cohorts and sum(len(c["lanes"]) for c in cohorts) < min_lockstep:
+            for c in cohorts:
+                grp = c["grp"]
+                gi = grp["gi"]
+                for i in c["lanes"]:
+                    results[gi][i] = serial(gi, i)
+                    if stats is not None and \
+                            not isinstance(results[gi][i], Retired):
+                        stats.order_pinned_lanes += 1
+                        if c["position"] < grp["n_cached"]:
+                            stats.order_hits += 1
+            cohorts = []
 
     # ---- one megabatch dispatch for every cohort of every family -------
     if cohorts:
@@ -1220,57 +1237,58 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
               None if pr_of(c["grp"]["gi"]) is None
               else pr_of(c["grp"]["gi"]).cutoffs(c["lanes"]))
              for c in cohorts])
-        for c, (done, diverged, retired) in zip(cohorts, outs):
-            grp = c["grp"]
-            gi, key, layouts = grp["gi"], grp["key"], grp["layouts"]
-            systems = items[gi][1]
-            pr = pr_of(gi)
-            from_cache = c["position"] < grp["n_cached"]
-            for pos_l, bound in retired.items():
-                results[gi][c["lanes"][pos_l]] = Retired(float(bound))
-            if stats is not None and retired:
-                stats.retired_lanes += len(retired)
-                stats.retire_sweeps += 1
-            for pos_l, sim in done.items():
-                i = c["lanes"][pos_l]
-                results[gi][i] = dataclasses.replace(
-                    sim, system=systems[i].name)
-                lib.map_sig(key, tuple(layouts[i][1]), c["position"])
-                if pr is not None:
-                    pr.offer(systems[i].name, sim.makespan)
-                if stats is not None:
-                    stats.lockstep_lanes += 1
-                    if from_cache:
-                        stats.order_hits += 1
-            for pos_l in diverged:
-                i = c["lanes"][pos_l]
-                sig = tuple(layouts[i][1])
-                if stats is not None:
-                    stats.diverged_lanes += 1
-                if grp["discoveries"] >= max_rounds:
-                    results[gi][i] = serial(gi, i)
-                    if stats is not None and \
-                            not isinstance(results[gi][i], Retired):
-                        stats.serial_fallback_lanes += 1
-                    continue
-                # serial discovery: the lane's own order is recorded so
-                # the next sweep routes it (no rescue re-batch here)
-                out2: List[int] = []
-                results[gi][i] = serial(gi, i, out2)
-                grp["discoveries"] += 1
-                if isinstance(results[gi][i], Retired):
-                    continue                # partial order: never recorded
-                pos2 = lib.record(key, out2, sig)
-                if pos2 is None:
+        with span("replay.assemble"):
+            for c, (done, diverged, retired) in zip(cohorts, outs):
+                grp = c["grp"]
+                gi, key, layouts = grp["gi"], grp["key"], grp["layouts"]
+                systems = items[gi][1]
+                pr = pr_of(gi)
+                from_cache = c["position"] < grp["n_cached"]
+                for pos_l, bound in retired.items():
+                    results[gi][c["lanes"][pos_l]] = Retired(float(bound))
+                if stats is not None and retired:
+                    stats.retired_lanes += len(retired)
+                    stats.retire_sweeps += 1
+                for pos_l, sim in done.items():
+                    i = c["lanes"][pos_l]
+                    results[gi][i] = dataclasses.replace(
+                        sim, system=systems[i].name)
+                    lib.map_sig(key, tuple(layouts[i][1]), c["position"])
+                    if pr is not None:
+                        pr.offer(systems[i].name, sim.makespan)
                     if stats is not None:
-                        stats.serial_fallback_lanes += 1
-                    continue
-                if pos2 == c["position"]:
-                    # its own recorded order is the one it just failed:
-                    # provably a conservative false positive — pin it
-                    lib.pin_sig(key, sig)
-                if stats is not None:
-                    stats.reference_lanes += 1
+                        stats.lockstep_lanes += 1
+                        if from_cache:
+                            stats.order_hits += 1
+                for pos_l in diverged:
+                    i = c["lanes"][pos_l]
+                    sig = tuple(layouts[i][1])
+                    if stats is not None:
+                        stats.diverged_lanes += 1
+                    if grp["discoveries"] >= max_rounds:
+                        results[gi][i] = serial(gi, i)
+                        if stats is not None and \
+                                not isinstance(results[gi][i], Retired):
+                            stats.serial_fallback_lanes += 1
+                        continue
+                    # serial discovery: the lane's own order is recorded so
+                    # the next sweep routes it (no rescue re-batch here)
+                    out2: List[int] = []
+                    results[gi][i] = serial(gi, i, out2)
+                    grp["discoveries"] += 1
+                    if isinstance(results[gi][i], Retired):
+                        continue                # partial order: never recorded
+                    pos2 = lib.record(key, out2, sig)
+                    if pos2 is None:
+                        if stats is not None:
+                            stats.serial_fallback_lanes += 1
+                        continue
+                    if pos2 == c["position"]:
+                        # its own recorded order is the one it just failed:
+                        # provably a conservative false positive — pin it
+                        lib.pin_sig(key, sig)
+                    if stats is not None:
+                        stats.reference_lanes += 1
     return results  # type: ignore[return-value]
 
 

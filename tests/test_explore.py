@@ -265,6 +265,22 @@ def test_json_roundtrip(trace, reports_and_rep):
         ExplorationResult.from_json('{"version": 1}')
 
 
+def test_json_loads_documents_with_the_dropped_time_share(trace,
+                                                         reports_and_rep):
+    """Outcomes no longer carry ``analysis_seconds`` (a sweep's wall time
+    split evenly over its candidates); a version-2 document written while
+    they did still loads, and the key is dropped."""
+    reports, rep = reports_and_rep
+    res = explore(trace, synth_candidates(rep, accs=(1, 2)), reports)
+    doc = json.loads(res.to_json())
+    assert all("analysis_seconds" not in o for o in doc["outcomes"])
+    for o in doc["outcomes"]:
+        o["analysis_seconds"] = 0.0125
+    back = ExplorationResult.from_json(json.dumps(doc))
+    assert [vars(o) for o in back.outcomes] == [vars(o) for o in res.outcomes]
+    assert back.to_json() == res.to_json()
+
+
 def test_json_roundtrip_ppa_fields(trace, reports_and_rep):
     """The PPA additions (objectives/budgets on the result, per-outcome
     objective values + component breakdowns, the derived frontier) and

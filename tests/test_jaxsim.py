@@ -396,6 +396,30 @@ def test_scan_inputs_memoised_on_frozen_graph():
     assert not hasattr(pickle.loads(pickle.dumps(fg)), "_jax_xs")
 
 
+def test_scan_module_and_scope_names_are_stable(monkeypatch):
+    """The trace reads the scan by name: its module is ``jit_run`` and its
+    operations carry the ``lockstep_scan`` scope in their metadata."""
+    from repro.core import jaxsim
+    launched = []
+    real = jaxsim._load_runner
+
+    def load(cc, args):
+        launched.append(args)
+        return real(cc, args)
+
+    monkeypatch.setattr(jaxsim, "_load_runner", load)
+    fg, _ = frozen_for(synth_trace(8), smp=False)
+    systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 7)]
+    simulate_jax(fg, systems, "availability", min_lockstep=2)
+    with jax.enable_x64(True):
+        lowered = jax.jit(jaxsim._runner()).lower(*launched[0])
+    text = lowered.as_text(debug_info=True)
+    assert "module @jit_run" in text
+    assert "lockstep_scan/" in text
+    hlo = lowered.compile().as_text()
+    assert 'op_name="jit(run)/lockstep_scan/while' in hlo
+
+
 def test_cache_stats_repr_has_disk_counters():
     s = CacheStats(graph_hits=3, graph_misses=1, eval_hits=7, eval_misses=2,
                    disk_hits=5, disk_misses=4, diverged_lanes=6,

@@ -13,6 +13,7 @@ serves a fresh process with zero compiles — plus the two satellite
 bugfixes: ``_bucket`` can never exceed its cap, and process pools never
 fork a jax-loaded parent.
 """
+import collections
 import json
 import pickle
 import subprocess
@@ -365,6 +366,128 @@ def _src_env():
     env["PYTHONPATH"] = os.path.dirname(list(repro.__path__)[0])
     env["JAX_PLATFORMS"] = jax.default_backend()
     return env
+
+
+# ---------------------------------------------------------------------------
+# scan counters (BatchStats.scan_lane_steps / scan_kept_lane_steps /
+# h2d_bytes)
+# ---------------------------------------------------------------------------
+
+
+def _ramp_families():
+    """Two families whose slot-count ramps make some lanes diverge, so the
+    kept lanes are fewer than the launched ones."""
+    fg1, _ = frozen_for(synth_trace(24), smp=True)
+    fg2, _ = frozen_for(synth_trace(14), smp=False)
+    return [(fg1, [zynq_system(f"a{n}", {"fpga:k": n})
+                   for n in range(1, 13)]),
+            (fg2, [zynq_system(f"b{n}", {"fpga:k": n})
+                   for n in range(1, 9)])]
+
+
+def _record_scans(monkeypatch):
+    """Record, beside the counters, what the scan really did: the
+    ``(T, B)`` step block of every launch, the bytes of every
+    ``jnp.asarray`` upload and of each launch's host-made arguments, and
+    each cohort's kept lanes × its own steps."""
+    import jax.numpy as jnp
+    from repro.core import jaxsim
+    rec = {"launches": [], "uploads": [], "launch_args": [], "kept": []}
+    real_load, real_put = jaxsim._load_runner, jnp.asarray
+    real_scan = jaxsim._scan_cohorts
+
+    def load(cc, args):
+        rec["launches"].append(args[0]["r"].shape)
+        rec["launch_args"].append(np.asarray(args[1]).nbytes
+                                  + np.asarray(args[8]).nbytes)
+        return real_load(cc, args)
+
+    def put(a, *rest, **kw):
+        rec["uploads"].append(np.asarray(a).nbytes)
+        return real_put(a, *rest, **kw)
+
+    def scan(cohorts, *rest, **kw):
+        outs = real_scan(cohorts, *rest, **kw)
+        for (_fg, order, _lay, _cut), (done, _div, _ret) in zip(cohorts,
+                                                                outs):
+            rec["kept"].append(len(done) * len(order))
+        return outs
+
+    monkeypatch.setattr(jaxsim, "_DEV_XS_CACHE",
+                        collections.OrderedDict())
+    monkeypatch.setattr(jaxsim, "_load_runner", load)
+    monkeypatch.setattr(jaxsim, "_scan_cohorts", scan)
+    monkeypatch.setattr(jnp, "asarray", put)
+    return rec
+
+
+def test_scan_counters_match_the_launches_and_uploads(monkeypatch):
+    """``scan_lane_steps`` is Σ B × T_max over the launches made,
+    ``scan_kept_lane_steps`` Σ kept lanes × their cohort's steps, and
+    ``h2d_bytes`` the bytes actually handed to ``jnp.asarray``; a warm
+    repeat (every step block a device-cache hit) uploads only each
+    launch's own arguments."""
+    items = _ramp_families()
+    lib = ReplayLibrary()
+    for _ in range(2):              # settle the routing, as a warm sweep
+        simulate_jax_many(items, "availability", min_lockstep=2,
+                          library=lib)
+    rec = _record_scans(monkeypatch)
+    cold = BatchStats()
+    simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
+                      stats=cold)
+    launches = list(rec["launches"])
+    assert launches, "the families must reach the scan"
+    assert cold.scan_lane_steps == sum(t * b for t, b in launches)
+    assert cold.scan_kept_lane_steps == sum(rec["kept"])
+    assert 0 < cold.scan_kept_lane_steps < cold.scan_lane_steps
+    assert cold.h2d_bytes == sum(rec["uploads"])
+    assert cold.h2d_bytes > sum(rec["launch_args"])
+
+    for k in rec:
+        rec[k].clear()
+    warm = BatchStats()
+    simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
+                      stats=warm)
+    assert rec["launches"] == launches
+    assert warm.h2d_bytes == sum(rec["uploads"]) == sum(rec["launch_args"])
+    assert warm.scan_lane_steps == cold.scan_lane_steps
+    assert warm.scan_kept_lane_steps == cold.scan_kept_lane_steps
+
+
+def test_span_needs_no_jax_and_marks_the_trace_with_it():
+    """Without jax loaded a span is one shared no-op and imports nothing;
+    with jax loaded it is a profiler annotation."""
+    script = """
+import sys
+from repro.core.spans import span
+with span("explore.sweep"):
+    pass
+assert span("a") is span("b") and "jax" not in sys.modules
+import jax
+assert isinstance(span("a"), jax.profiler.TraceAnnotation)
+print("SPANS-OK")
+"""
+    out = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                         text=True, timeout=120, env=_src_env())
+    assert out.returncode == 0, out.stderr
+    assert "SPANS-OK" in out.stdout
+
+
+def test_scan_counters_on_the_per_graph_path_and_the_batch_engine():
+    """The per-graph scan counts as the megabatch does; the numpy batch
+    engine launches no scan and leaves all three counters at 0."""
+    fg, systems = _ramp_families()[1]
+    stats = BatchStats()
+    simulate_jax(fg, systems, "availability", min_lockstep=2, stats=stats)
+    assert 0 < stats.scan_kept_lane_steps <= stats.scan_lane_steps
+    assert stats.h2d_bytes > 0
+    from repro.core.batchsim import simulate_batch
+    stats = BatchStats()
+    simulate_batch(fg, systems, "availability", min_lockstep=2, stats=stats)
+    assert stats.lockstep_lanes > 0
+    assert (stats.scan_lane_steps, stats.scan_kept_lane_steps,
+            stats.h2d_bytes) == (0, 0, 0)
 
 
 # ---------------------------------------------------------------------------
