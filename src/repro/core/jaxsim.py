@@ -13,11 +13,11 @@ a device-resident candidate axis.
 Invariants (shared with the numpy backend unless stated):
 
 * **Lane-last axis convention.**  Per-candidate state is stacked with the
-  lane axis *last* — pool free-slot clocks ``[P, S, B]``, task ready times
-  ``[n, B]``, placement ids ``[n, B]`` — exactly the batchsim layout, so
-  the two backends' state arrays are interchangeable in tests and the
-  shared assembly helper (:func:`repro.core.replay.lane_results`) serves
-  both.
+  lane axis *last* — pool free-slot clocks ``[P, S, B]``, busy ``[P, B]``,
+  and the step-indexed ends ``[T+1, B]`` and placements ``[2T+1, B]``
+  (:func:`_runner`), which the host turns back into the batchsim layout's
+  placement ids ``[n, B]``, so the shared assembly helper
+  (:func:`repro.core.replay.lane_results`) serves both backends.
 * **rtol tier, not bit-identity.**  The exact engines replicate the
   reference engine's float ops in the reference order; XLA owns its own op
   scheduling, so this engine is pinned at the relaxed tier instead:
@@ -160,7 +160,7 @@ def _bucket(n: int, cap: int) -> int:
 # each time as the int64 bit pattern of its float64 value, on every
 # backend.  Times are never negative, and for non-negative IEEE doubles
 # (+inf included) integer order *is* numeric order, so min / max / argmin
-# / compares / scatter-max work unchanged on the patterns; only ``+`` and
+# / compares work unchanged on the patterns; only ``+`` and
 # ``-`` need the round-to-nearest-even integer arithmetic below — exact,
 # hence bit-identical to float64 wherever it runs.
 
@@ -262,9 +262,24 @@ def resolve_step_impl(step_impl: str) -> str:
 def _runner():
     """The pure scan function.
 
-    The step carries every time (clocks, ready times, costs, busy,
-    makespan) as its float64 bit pattern in int64, with exact ``+``/``-``
+    The step carries every time (clocks, ends, costs, busy, makespan) as
+    its float64 bit pattern in int64, with exact ``+``/``-``
     (:func:`_bits_add`, :func:`_bits_sub`).
+
+    **Step-indexed state.**  Nothing the step writes is indexed by task
+    row.  ``ends [T+1, B]`` holds, at row ``t``, the end time step ``t``
+    left (a skipped conditional row's ready time passes through); a row's
+    ready time is *pulled* as the max over its predecessors' step
+    positions (``xs["pred"]``, padded to the slot ``T`` that stays 0).
+    ``placed [2T+1, B]`` holds, at ``2t``, the placement of step ``t``'s
+    row after the step and, at ``2t + 1``, that of its conditional parent
+    (whose device a child may decide before the parent's own step); a
+    placement is read at its latest earlier touch (``xs["at_own"]``,
+    ``xs["at_par"]``, padded to the slot ``2T`` that stays -1).  Each step
+    writes both arrays once, in place, at the shared step counter: on a
+    TPU a per-step scatter into row-indexed ``[n+1, B]`` state moves the
+    whole array through vector memory every step.  :func:`_scan_cohorts`
+    turns ``placed`` back into row placements on the host.
 
     All shapes are megabatch-form and **lane-aligned**: step inputs ``xs``
     carry the lane axis directly (``[T, B, ...]`` — each lane's cohort
@@ -295,8 +310,7 @@ def _runner():
         seen = seen.at[p, aB].set(seen[p, aB] | live)
         return clocks, busy, seen, end
 
-    def run(xs, clocks, ready, placement, busy, seen, kind_pool,
-            smp_kid, eft):
+    def run(xs, clocks, ends, placed, busy, seen, kind_pool, smp_kid, eft):
         # the scan's operations carry a stable name in the HLO metadata
         with jax.named_scope("lockstep_scan"):
             B = clocks.shape[2]
@@ -314,7 +328,7 @@ def _runner():
                 ``minc [P, B]`` is the step's hoisted earliest-free-slot
                 reduction, so each option costs a [B] gather instead of its
                 own [B, S] min."""
-                best_k = jnp.full((B,), -1, dtype=placement.dtype)
+                best_k = jnp.full((B,), -1, dtype=placed.dtype)
                 bv = jnp.zeros((B,), dtype=minc.dtype)
                 bp = jnp.zeros((B,), dtype=jnp.int32)
                 for j in range(K):                      # K is static and tiny
@@ -335,17 +349,19 @@ def _runner():
                 return best_k
 
             def step(carry, x):
-                (clocks, ready, placement, busy, seen, makespan, prev_rt,
+                (t, clocks, ends, placed, busy, seen, makespan, prev_rt,
                  prev_tb, div) = carry
                 valid = x["valid"]                                  # [B]
-                r = x["r"]                   # dummy row n_max on invalid steps
-                rt = ready[r, aB]                                   # [B]
+                # pull, not push: the row's ready time is the latest end of
+                # its predecessors, read at the steps that ran them (the
+                # pad slot T_max holds 0, the roots' ready time)
+                rt = jnp.max(ends[x["pred"], aB[:, None]], axis=1)  # [B]
                 tbv = x["tb"]
                 # heap-key monotonicity: a lane whose popped (ready_t, tb)
                 # key ever fails to strictly increase is not executing its
                 # own heap order — flag it for the exact fallback.  Invalid
-                # (padding) steps read the dummy ready row, so every check
-                # and write below is gated on `valid`.
+                # (padding) steps read only pad slots, so every check and
+                # write below is gated on `valid`.
                 div = div | (valid & ((rt < prev_rt)
                                       | ((rt == prev_rt) & (tbv <= prev_tb))))
                 # (div also absorbs bad dispatches below: any lane that
@@ -359,26 +375,23 @@ def _runner():
                 minc = jnp.min(clocks, axis=1)                      # [P, B]
 
                 # ---- conditional pass-through (per-lane mask) ---------------
-                c = x["c"]
-                has_cond = (c >= 0) & valid
-                cmax = jnp.maximum(c, 0)
-                pk = placement[cmax, aB]                            # [B]
+                # the parent's placement as its latest earlier touch left it
+                # (pad slot 2 T_max holds -1: not placed yet)
+                has_cond = (x["c"] >= 0) & valid
+                pk = placed[x["at_par"], aB]                        # [B]
                 chosen_p = choose(x["par_opts"], x["par_cost"], rt, minc)
                 pk = jnp.where(pk < 0, chosen_p, pk)
-                placement = placement.at[cmax, aB].set(
-                    jnp.where(has_cond, pk, placement[cmax, aB]))
                 live = jnp.where(has_cond, x["act"][aB, jnp.maximum(pk, 0)],
                                  True) & valid
 
                 # ---- dispatch + commit for the lanes executing the row ------
-                k_own = placement[r, aB]
+                k_own = placed[x["at_own"], aB]
                 und = k_own < 0
                 chosen_o = choose(x["own_opts"], x["own_cost"], rt, minc)
                 is_comp = x["is_comp"]
                 k = jnp.where(is_comp, jnp.where(und, chosen_o, k_own),
                               x["k_first"])
-                placement = placement.at[r, aB].set(
-                    jnp.where(is_comp & live & und, k, placement[r, aB]))
+                k_row = jnp.where(is_comp & live & und, k, k_own)
                 div = div | (live & (x["bad_row"] | (k < 0)))
                 kk = jnp.maximum(k, 0)
                 p = jnp.maximum(kind_pool[aB, kk], 0)               # [B]
@@ -387,11 +400,15 @@ def _runner():
                                                  base, live, aB)
                 end_eff = jnp.where(live, end, jnp.where(valid, rt, zero))
                 makespan = jnp.maximum(makespan, end_eff)
-                ready = ready.at[x["succ"], aB[:, None]].max(
-                    end_eff[:, None])
+                # one in-place row write each at the shared step counter:
+                # the row's end, its placement (slot 2t) and its conditional
+                # parent's (slot 2t + 1)
+                ends = jax.lax.dynamic_update_index_in_dim(ends, end_eff, t, 0)
+                placed = jax.lax.dynamic_update_slice_in_dim(
+                    placed, jnp.stack([k_row, pk]), 2 * t, 0)
                 prev_rt = jnp.where(valid, rt, prev_rt)
                 prev_tb = jnp.where(valid, tbv, prev_tb)
-                return (clocks, ready, placement, busy, seen, makespan,
+                return (t + 1, clocks, ends, placed, busy, seen, makespan,
                         prev_rt, prev_tb, div), None
 
             makespan = jnp.zeros((B,), dtype=clocks.dtype)
@@ -399,11 +416,11 @@ def _runner():
             prev_rt = jnp.full((B,), -1, dtype=clocks.dtype)
             prev_tb = jnp.full((B,), -1, dtype=xs["tb"].dtype)
             div = jnp.zeros((B,), dtype=bool)
-            init = (clocks, ready, placement, busy, seen, makespan, prev_rt,
-                    prev_tb, div)
-            (clocks, ready, placement, busy, seen, makespan, _rt, _tb,
+            init = (jnp.zeros((), dtype=jnp.int32), clocks, ends, placed,
+                    busy, seen, makespan, prev_rt, prev_tb, div)
+            (_t, clocks, ends, placed, busy, seen, makespan, _rt, _tb,
              div), _ = jax.lax.scan(step, init, xs)
-            return makespan, busy, seen, placement, div
+            return makespan, busy, seen, placed, div
 
     return run
 
@@ -533,9 +550,13 @@ def _group_xs(fg: FrozenGraph, order: Sequence[int],
     order: row ids, tie-break scalars, conditional parents, device options
     and cost rows for the row *and* its conditional parent (the parent's
     placement may be decided at this step), activation-mask rows,
-    bad-dispatch flags (:func:`_bad_rows`), and padded successor lists
-    (pad = ``n``, a dummy ready row — remapped to the megabatch dummy by
-    :func:`_scan_cohorts`).
+    bad-dispatch flags (:func:`_bad_rows`), and the step-indexed state's
+    read positions (see :func:`_runner`): ``pred``, the steps of each
+    row's predecessors (pad ``T``); ``at_own`` / ``at_par``, the
+    ``placed`` slot of the latest earlier touch of the row / of its
+    conditional parent (pad ``2T``); ``fin [n]``, each row's last touch
+    over the whole order, which the host reads the final placements at.
+    The pads are remapped to the megabatch's by :func:`_scan_cohorts`.
 
     Memoised on the FrozenGraph like :func:`~repro.core.replay.graph_aux`
     (repeat sweeps — re-ranks, hillclimbs — replay the same order over the
@@ -548,15 +569,26 @@ def _group_xs(fg: FrozenGraph, order: Sequence[int],
     cached = cache.get(ckey)
     if cached is not None:
         return cached
-    (uids, ci, cond, dev_first, dev_opts, asets, costs, succs,
+    (uids, ci, cond, dev_first, dev_opts, asets, costs, _succs,
      _npred, is_comp, rankmaps, *_rest) = fg._runtime()
     n = fg.n
     tb, act_mask = graph_aux(fg, ci, rankmaps[0], asets)
     cost_np = fg.cost
     T = len(order)
     K = max(1, max(len(dev_opts[i]) for i in range(n)) if n else 1)
-    S_max = max(1, max((len(succs[i]) for i in range(n)), default=1))
     n_kinds = len(fg.kinds)
+    # predecessor steps: every edge (src -> dst) as pos[src] in the row of
+    # pos[dst], in ascending column order (a replayed order is
+    # topological, so each predecessor's step comes first)
+    pos = np.empty(n, dtype=np.int64)
+    pos[np.asarray(order, dtype=np.int64)] = np.arange(T)
+    src = np.repeat(np.arange(n), np.diff(fg.succ_indptr))
+    e_dst, e_src = pos[fg.succ_rows], pos[src]
+    by = np.lexsort((e_src, e_dst))
+    e_dst, e_src = e_dst[by], e_src[by]
+    indeg = np.bincount(e_dst, minlength=T)
+    col = np.arange(len(e_dst)) - np.repeat(np.cumsum(indeg) - indeg, indeg)
+    PC = max(1, int(indeg.max(initial=0)))
 
     xs = {
         "r": np.empty(T, dtype=np.int32),
@@ -570,8 +602,12 @@ def _group_xs(fg: FrozenGraph, order: Sequence[int],
         "par_cost": np.zeros((T, n_kinds), dtype=np.float64),
         "act": np.zeros((T, n_kinds), dtype=bool),
         "bad_row": _bad_rows(fg, kind_pool)[list(order)],
-        "succ": np.full((T, S_max), n, dtype=np.int32),
+        "pred": np.full((T, PC), T, dtype=np.int32),
+        "at_own": np.empty(T, dtype=np.int32),
+        "at_par": np.full(T, 2 * T, dtype=np.int32),
     }
+    xs["pred"][e_dst, col] = e_src
+    last = [2 * T] * n                  # each row's latest `placed` slot
     for t, r in enumerate(order):
         xs["r"][t] = r
         xs["tb"][t] = tb[r]
@@ -581,12 +617,15 @@ def _group_xs(fg: FrozenGraph, order: Sequence[int],
         xs["k_first"][t] = dev_first[r]
         xs["own_opts"][t, :len(dev_opts[r])] = dev_opts[r]
         xs["own_cost"][t] = cost_np[r]
+        xs["at_own"][t] = last[r]
+        last[r] = 2 * t
         if c >= 0:
             xs["par_opts"][t, :len(dev_opts[c])] = dev_opts[c]
             xs["par_cost"][t] = cost_np[c]
             xs["act"][t] = act_mask[r]
-        if succs[r]:
-            xs["succ"][t, :len(succs[r])] = succs[r]
+            xs["at_par"][t] = last[c]
+            last[c] = 2 * t + 1
+    xs["fin"] = np.asarray(last, dtype=np.int32)
     # bad-row flags capture every NaN a live dispatch could reach; scrub
     # the rest so no masked-out lane arithmetic can produce a NaN
     np.nan_to_num(xs["own_cost"], copy=False)
@@ -600,6 +639,12 @@ def _group_xs(fg: FrozenGraph, order: Sequence[int],
 # ---------------------------------------------------------------------------
 # Cohort driver: task-axis padding, chunked lanes, shared compiled scan
 # ---------------------------------------------------------------------------
+
+
+def _repad(slots: np.ndarray, pad: int, to: int) -> np.ndarray:
+    """A cohort's state positions with its own pad slot moved to the
+    megabatch's."""
+    return np.where(slots == pad, to, slots)
 
 
 def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
@@ -617,10 +662,11 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
 
     Task-axis padding layout: per-cohort step inputs (:func:`_group_xs`)
     are stacked into ``[T_max, G, ...]`` blocks — steps beyond a cohort's
-    own length carry ``valid=False``, the dummy row id ``n_max`` and
-    all-dummy successor lists, so they update nothing; rows/pools/options
-    pad to the megabatch maxima with inert values (``-1`` options, dummy
-    successors, ``inf`` clocks beyond a lane's slot count).  Lanes from
+    own length carry ``valid=False`` and read only the pad slots of the
+    step-indexed state (predecessor step ``T_max``, placement slot
+    ``2 T_max``), so they update nothing a lane reads; rows/pools/options
+    pad to the megabatch maxima with inert values (``-1`` options, pad
+    predecessors, ``inf`` clocks beyond a lane's slot count).  Lanes from
     *all* cohorts share the bucketed lane axis (``chunk`` caps the bucket;
     padding lanes replicate the last real lane).
 
@@ -685,15 +731,17 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 "needs": [max(1, max((min(int(c), int(caps[p]))
                                       for p, c in enumerate(cnt)), default=1))
                           for cnt in lane_counts],
-                "n": fg.n, "P": len(pool_names),
+                "P": len(pool_names),
             })
         G = len(per)
-        n_max = max(c["n"] for c in per)
         P_max = max(c["P"] for c in per)
         T_max = max(len(c["xs"]["r"]) for c in per)
         K = max(c["xs"]["own_opts"].shape[1] for c in per)
         NK = max(len(c["kind_pool"]) for c in per)
-        SC = max(c["xs"]["succ"].shape[1] for c in per)
+        PC = max(c["xs"]["pred"].shape[1] for c in per)
+        for c in per:
+            c["fin"] = _repad(c["xs"]["fin"], 2 * len(c["xs"]["r"]),
+                              2 * T_max)
         S = _bucket(max(nd for c in per for nd in c["needs"]), cap=1 << 30)
 
         kind_pool_m = np.full((G, NK), -1, dtype=np.int32)
@@ -713,7 +761,6 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 return _mega_memo[0]
             mega = {
                 "valid": np.zeros((T_max, G), dtype=bool),
-                "r": np.full((T_max, G), n_max, dtype=np.int32),
                 "tb": np.zeros((T_max, G), dtype=np.int64),
                 "c": np.full((T_max, G), -1, dtype=np.int32),
                 "is_comp": np.zeros((T_max, G), dtype=bool),
@@ -724,25 +771,27 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 "par_cost": np.zeros((T_max, G, NK), dtype=np.float64),
                 "act": np.zeros((T_max, G, NK), dtype=bool),
                 "bad_row": np.zeros((T_max, G), dtype=bool),
-                "succ": np.full((T_max, G, SC), n_max, dtype=np.int32),
+                "pred": np.full((T_max, G, PC), T_max, dtype=np.int32),
+                "at_own": np.full((T_max, G), 2 * T_max, dtype=np.int32),
+                "at_par": np.full((T_max, G), 2 * T_max, dtype=np.int32),
             }
             for gi, c in enumerate(per):
                 xs = c["xs"]
-                T, n = len(xs["r"]), c["n"]
-                kg, nk, sc = (xs["own_opts"].shape[1], xs["own_cost"].shape[1],
-                              xs["succ"].shape[1])
+                T = len(xs["r"])
+                kg, nk, pc = (xs["own_opts"].shape[1], xs["own_cost"].shape[1],
+                              xs["pred"].shape[1])
                 mega["valid"][:T, gi] = True
-                for f in ("r", "tb", "c", "is_comp", "k_first", "bad_row"):
+                for f in ("tb", "c", "is_comp", "k_first", "bad_row"):
                     mega[f][:T, gi] = xs[f]
                 mega["own_opts"][:T, gi, :kg] = xs["own_opts"]
                 mega["par_opts"][:T, gi, :kg] = xs["par_opts"]
                 mega["own_cost"][:T, gi, :nk] = xs["own_cost"]
                 mega["par_cost"][:T, gi, :nk] = xs["par_cost"]
                 mega["act"][:T, gi, :nk] = xs["act"]
-                # each cohort's own dummy successor row is its fg.n —
-                # remap to the megabatch-wide dummy ready row n_max
-                mega["succ"][:T, gi, :sc] = np.where(xs["succ"] == n, n_max,
-                                                     xs["succ"])
+                # each cohort's own pad slots (T, 2T) -> the megabatch's
+                mega["pred"][:T, gi, :pc] = _repad(xs["pred"], T, T_max)
+                for f in ("at_own", "at_par"):
+                    mega[f][:T, gi] = _repad(xs[f], 2 * T, 2 * T_max)
             _mega_memo[0] = mega
             return mega
 
@@ -752,7 +801,7 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
         # device blocks without re-stacking or re-transferring anything
         base_key = (tuple((c["fg"].content_hash(), tuple(c["xs"]["r"]),
                            tuple(c["kind_pool"])) for c in per),
-                    (T_max, n_max, P_max, K, NK, SC),
+                    (T_max, P_max, K, NK, PC),
                     kind_pool_m.tobytes(), smp_kid_m.tobytes())
 
         lanes_flat = [(gi, pos) for gi, c in enumerate(per)
@@ -843,9 +892,9 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 xs_j, kp_j, sk_j = _lane_aligned(g_np)
                 with span("jaxsim.transfer"):
                     args = (xs_j, _put(_times(clocks)),
-                            # ready, placement, busy, seen: made on device
-                            jnp.zeros((n_max + 1, B), dtype=jnp.int64),
-                            jnp.full((n_max + 1, B), -1, dtype=jnp.int32),
+                            # ends, placed, busy, seen: made on device
+                            jnp.zeros((T_max + 1, B), dtype=jnp.int64),
+                            jnp.full((2 * T_max + 1, B), -1, dtype=jnp.int32),
                             jnp.zeros((P_max, B), dtype=jnp.int64),
                             jnp.zeros((P_max, B), dtype=bool),
                             kp_j, sk_j, _put(np.asarray(eft)))
@@ -853,11 +902,11 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
             if stats is not None:
                 stats.scan_lane_steps += B * T_max
             with span("jaxsim.launch"):
-                makespan, busy, seen, placement, div = exe(*args)
+                makespan, busy, seen, placed, div = exe(*args)
                 launched.append((sl, np.asarray(div),
                                  np.asarray(makespan).view(np.float64),
                                  np.asarray(busy).view(np.float64),
-                                 np.asarray(seen), np.asarray(placement)))
+                                 np.asarray(seen), np.asarray(placed)))
 
     with span("replay.assemble"):
         for sl, div_np, mk_np, busy_np, seen_np, place_np in launched:
@@ -876,7 +925,8 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 acc["mk"].append(mk_np[li:li + 1])
                 acc["busy"].append(busy_np[:c["P"], li:li + 1])
                 acc["seen"].append(seen_np[:c["P"], li:li + 1])
-                acc["place"].append(place_np[:c["n"], li:li + 1])
+                # each row's placement at its last touch (see _group_xs)
+                acc["place"].append(place_np[c["fin"], li:li + 1])
         results: List[Tuple[Dict[int, SimResult], List[int],
                             Dict[int, float]]] = []
         for gi, c in enumerate(per):

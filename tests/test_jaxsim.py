@@ -21,7 +21,8 @@ from repro.core.batchsim import simulate_batch
 from repro.core.devices import DevicePool, SharedResource, SystemConfig
 from repro.core.explore import ENGINE_NAMES, CacheStats
 from repro.core.fastsim import FrozenGraph, simulate_fast
-from repro.core.jaxsim import _bits_add, _bits_sub, simulate_jax
+from repro.core.jaxsim import (_bits_add, _bits_sub, simulate_jax,
+                               simulate_jax_many)
 from repro.core.replay import (BatchStats, ENGINE_TOLERANCE, JAX_RTOL,
                                makespans_close, rankings_equivalent,
                                sims_equivalent)
@@ -476,3 +477,210 @@ def test_lax_scan_times_are_bit_exact(policy):
     assert stats.lockstep_lanes > 0
     for sim, system in zip(sims, systems):
         assert sims_equivalent(sim, simulate_fast(fg, system, policy), 0.0)
+
+
+# ---------------------------------------------------------------------------
+# the step-indexed scan state: pulled ready times, in-place placement slots
+# ---------------------------------------------------------------------------
+
+
+def _dag(n, edges):
+    """A bare DAG over two device kinds with the given row edges."""
+    g = TaskGraph()
+    uids = []
+    for i in range(n):
+        kinds = ("a", "b") if i % 3 else ("b", "a")
+        t = Task(uid=g.new_uid(), name=f"t{i}", devices=kinds,
+                 costs={"a": 0.5 + (i % 5) * 0.25, "b": 1.0 + (i % 3) * 0.5},
+                 creation_index=i, meta={"role": "compute"})
+        g.add_task(t, infer_deps=False)
+        uids.append(t.uid)
+    for a, b in edges:
+        g.add_edge(uids[a], uids[b])
+    return FrozenGraph.freeze(g)
+
+
+def _two_pools(ca, cb):
+    return [SystemConfig(name=f"s{i}-{j}",
+                         pools=[DevicePool("pa", ("a",), i),
+                                DevicePool("pb", ("b",), j)],
+                         shared=[SharedResource("x", 1)])
+            for i in range(1, ca + 1) for j in range(1, cb + 1)]
+
+
+def _ramp(ns, prefix="a"):
+    return [zynq_system(f"{prefix}{n}", {"fpga:k": n}) for n in ns]
+
+
+def _skip_graph(units=3):
+    """A chain of units ``r -> d -> x -> z``: ``d`` is a transfer
+    conditional on the compute ``x``, active only when ``x`` runs on
+    ``a``.  Under ``eft`` the SMP wins ``x`` (0.5 against 2.0), so ``d``
+    decides ``x``'s device at its own step, is skipped, and passes its
+    ready time on to ``x``, which pops after it (a higher creation index)
+    with the heap key still increasing: the lanes stay in lockstep."""
+    g = TaskGraph()
+    prev = None
+
+    def add(name, devices, costs, ci, **meta):
+        t = Task(uid=g.new_uid(), name=name, devices=devices, costs=costs,
+                 creation_index=ci, meta=meta)
+        g.add_task(t, infer_deps=False)
+        return t.uid
+
+    for u in range(units):
+        r = add(f"r{u}", ("b",), {"b": 1.0}, 4 * u, role="compute")
+        x = add(f"x{u}", ("a", "smp"), {"a": 2.0, "smp": 0.5}, 4 * u + 2,
+                role="compute")
+        d = add(f"d{u}", ("d",), {"d": 0.1}, 4 * u + 1, role="submit_in",
+                conditional_on=x, active_kinds=("a",))
+        z = add(f"z{u}", ("b",), {"b": 1.0}, 4 * u + 3, role="compute")
+        if prev is not None:
+            g.add_edge(prev, r)
+        for a, b in ((r, d), (d, x), (x, z)):
+            g.add_edge(a, b)
+        prev = z
+    fg = FrozenGraph.freeze(g)
+    systems = [SystemConfig(name=f"s{i}{j}{k}",
+                            pools=[DevicePool("smp", ("smp",), k),
+                                   DevicePool("pa", ("a",), i),
+                                   DevicePool("pb", ("b",), j),
+                                   DevicePool("pd", ("d",), 1)],
+                            shared=[SharedResource("x", 1)])
+               for i in (1, 2) for j in (1, 2, 3) for k in (1, 2)]
+    return [(fg, systems)]
+
+
+#: Nine independent rows joined by one, which three rows follow.
+_FAN_IN = [(i, 9) for i in range(9)] + [(9, j) for j in (10, 11, 12)]
+
+#: case -> (policy, families, what the scan must have run for the case to
+#: count).
+STATE_CASES = {
+    # every row a root: all predecessor slots are the pad
+    "roots": ("availability", lambda: [(_dag(12, []), _two_pools(3, 3))],
+              lambda run: (run.xs[0]["pred"] == run.T[0]).all()),
+    # the join row fills the whole predecessor list
+    "widest_preds": (
+        "availability", lambda: [(_dag(13, _FAN_IN), _two_pools(3, 3))],
+        lambda run: run.xs[0]["pred"].shape[1] == 9
+        and (run.xs[0]["pred"] < run.T[0]).all(axis=1).any()),
+    # lanes the scan completed passed conditional rows through untouched
+    "skipped_conditional": (
+        "eft", _skip_graph,
+        lambda run: all(any(st.kind == "skipped" for st in simulate_fast(
+            fg, s, "eft", with_schedule=True).schedule)
+            for fg, s in run.kept)),
+    # a DMA-in child decided its compute parent's device before the
+    # parent's own step: the parent reads an odd (parent) slot
+    "parent_placed_early": (
+        "availability",
+        lambda: [(frozen_for(synth_trace(16), smp=True)[0], _ramp(range(2, 9)))],
+        lambda run: (run.xs[0]["at_own"] % 2 == 1).any()),
+    # one launch holds lanes of cohorts with different step counts
+    "mixed_lengths": (
+        "availability",
+        lambda: [(frozen_for(synth_trace(10), smp=True)[0], _ramp(range(1, 7))),
+                 (frozen_for(synth_trace(17), smp=False)[0],
+                  _ramp(range(1, 7), "b"))],
+        lambda run: any(len(set(v.sum(axis=0).tolist())) > 1
+                        for v in run.valid)),
+    # the launches are wider than the lanes they carry
+    "padding_lanes": (
+        "availability",
+        lambda: [(frozen_for(synth_trace(12), smp=False)[0], _ramp(range(1, 6)))],
+        lambda run: sum(v.shape[1] for v in run.valid) > sum(run.lanes)),
+}
+
+
+def _run_recorded(monkeypatch, items, policy):
+    """``simulate_jax_many`` over ``items``, recording each launch's
+    ``valid`` block and lane count, each cohort's step inputs, and the
+    ``(fg, system)`` of every lane the scan completed."""
+    import types
+    from repro.core import jaxsim
+    from repro.core.fastsim import pool_layout
+    run = types.SimpleNamespace(valid=[], lanes=[], kept=[])
+    real_load, real_scan = jaxsim._load_runner, jaxsim._scan_cohorts
+
+    def load(cc, args):
+        run.valid.append(np.asarray(args[0]["valid"]))
+        return real_load(cc, args)
+
+    def scan(cohorts, *rest, **kw):
+        outs = real_scan(cohorts, *rest, **kw)
+        run.lanes.append(sum(len(c[2]) for c in cohorts))
+        for (fg, _order, layouts, _cut), (done, _div, _ret) in zip(cohorts,
+                                                                   outs):
+            systems = next(s for f, s in items if f is fg)
+            for pos in done:
+                run.kept.extend((fg, s) for s in systems
+                                if pool_layout(fg.kinds, s) == layouts[pos])
+        return outs
+
+    monkeypatch.setattr(jaxsim, "_load_runner", load)
+    monkeypatch.setattr(jaxsim, "_scan_cohorts", scan)
+    stats = BatchStats()
+    run.sims = simulate_jax_many(items, policy, min_lockstep=2, stats=stats)
+    run.stats = stats
+    run.xs = [next(iter(fg._jax_xs.values())) for fg, _ in items]
+    run.T = [len(x["r"]) for x in run.xs]
+    return run
+
+
+@pytest.mark.parametrize("case", STATE_CASES)
+def test_step_indexed_state_is_bit_exact(monkeypatch, case):
+    """Ready times pulled from the predecessors' steps and placements read
+    at their latest touch give every lane ``simulate_fast``'s makespan,
+    busy times and placements bit for bit, on the megabatch and the
+    per-graph path, in each case the step-indexed state has to get right."""
+    policy, build, ran = STATE_CASES[case]
+    items = build()
+    run = _run_recorded(monkeypatch, items, policy)
+    assert run.stats.lockstep_lanes > 0 and run.kept, "no lane ran the scan"
+    assert ran(run), f"the scan never exercised {case}"
+    for (fg, systems), sims in zip(items, run.sims):
+        per_graph = simulate_jax(fg, systems, policy, min_lockstep=2)
+        for system, sim, pg in zip(systems, sims, per_graph):
+            ref = simulate_fast(fg, system, policy)
+            assert sims_equivalent(sim, ref, 0.0), (case, system.name)
+            assert sims_equivalent(pg, ref, 0.0), (case, system.name)
+
+
+def test_scan_step_scatters_into_no_task_indexed_state():
+    """The loop body writes its ``[T+1, B]`` ends and ``[2T+1, B]``
+    placements with one in-place dynamic-update-slice each; every scatter
+    left (clocks, busy, seen) is O(lanes)-sized, and none combines with a
+    64-bit ``max`` — the property the step's cost on the chip rests on."""
+    import re
+    from repro.core import jaxsim
+    T, P, S, B, K, NK, PC = 37, 3, 4, 8, 2, 3, 5
+    with jax.enable_x64(True):
+        def sd(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+        xs = {"valid": sd((T, B), jnp.bool_), "tb": sd((T, B), jnp.int64),
+              "c": sd((T, B), jnp.int32), "is_comp": sd((T, B), jnp.bool_),
+              "k_first": sd((T, B), jnp.int32),
+              "own_opts": sd((T, B, K), jnp.int32),
+              "own_cost": sd((T, B, NK), jnp.int64),
+              "par_opts": sd((T, B, K), jnp.int32),
+              "par_cost": sd((T, B, NK), jnp.int64),
+              "act": sd((T, B, NK), jnp.bool_),
+              "bad_row": sd((T, B), jnp.bool_),
+              "pred": sd((T, B, PC), jnp.int32),
+              "at_own": sd((T, B), jnp.int32),
+              "at_par": sd((T, B), jnp.int32)}
+        hlo = jax.jit(jaxsim._runner()).lower(
+            xs, sd((P, S, B), jnp.int64), sd((T + 1, B), jnp.int64),
+            sd((2 * T + 1, B), jnp.int32), sd((P, B), jnp.int64),
+            sd((P, B), jnp.bool_), sd((B, NK), jnp.int32),
+            sd((B,), jnp.int32), sd((), jnp.bool_)).as_text(dialect="hlo")
+    regions = dict(re.findall(r"\n(\S+) [^\n]*\{\n(.*?)\n\}", hlo, re.S))
+    scatters = re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(.*to_apply=([\w.]+)",
+                          hlo)
+    assert scatters, "clocks, busy and seen are scattered"
+    for dims, region in scatters:
+        assert max(int(d) for d in dims.split(",")) < T, dims
+        assert "maximum" not in regions[region], (dims, region)
+    updates = re.findall(r"= \w+\[([\d,]*)\]\S* dynamic-update-slice\(", hlo)
+    assert sorted(updates) == sorted([f"{T + 1},{B}", f"{2 * T + 1},{B}"])

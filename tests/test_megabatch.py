@@ -397,7 +397,7 @@ def _record_scans(monkeypatch):
     real_scan = jaxsim._scan_cohorts
 
     def load(cc, args):
-        rec["launches"].append(args[0]["r"].shape)
+        rec["launches"].append(args[0]["valid"].shape)
         rec["launch_args"].append(np.asarray(args[1]).nbytes
                                   + np.asarray(args[8]).nbytes)
         return real_load(cc, args)

@@ -30,11 +30,11 @@ from repro.kernels.lockstep_step import step_commit
 from repro.testing.synth import frozen_for, synth_trace
 
 #: Scan slices of chip_smoke.py: task rows (T = n), pools, slots, lanes,
-#: per-row device options, device kinds and successor-list width.
-SLICES = {"phaseA": (4912, 5, 8, 32, 2, 5, 30),
-          "phaseB-S32": (5120, 4, 32, 64, 2, 4, 3),
-          "phaseB-S64": (5120, 4, 64, 32, 2, 4, 3),
-          "phaseB-S128": (5120, 4, 128, 16, 2, 4, 3)}
+#: per-row device options, device kinds and predecessor-list width.
+SLICES = {"phaseA": (4912, 5, 8, 32, 2, 5, 10),
+          "phaseB-S32": (5120, 4, 32, 64, 2, 4, 4),
+          "phaseB-S64": (5120, 4, 64, 32, 2, 4, 4),
+          "phaseB-S128": (5120, 4, 128, 16, 2, 4, 4)}
 
 
 @pytest.fixture(scope="module")
@@ -56,11 +56,11 @@ def _sd(sharding, shape, dtype):
 
 @pytest.mark.parametrize("shape", SLICES.values(), ids=SLICES.keys())
 def test_scan_runner_compiles_for_v5e(one_chip, shape):
-    T, P, S, B, K, NK, SC = shape
+    T, P, S, B, K, NK, PC = shape
     sd = functools.partial(_sd, one_chip)
     t64 = jnp.int64                     # times travel as float64 bits
     with jax.enable_x64(True):
-        xs = {"valid": sd((T, B), jnp.bool_), "r": sd((T, B), jnp.int32),
+        xs = {"valid": sd((T, B), jnp.bool_),
               "tb": sd((T, B), jnp.int64), "c": sd((T, B), jnp.int32),
               "is_comp": sd((T, B), jnp.bool_),
               "k_first": sd((T, B), jnp.int32),
@@ -70,10 +70,12 @@ def test_scan_runner_compiles_for_v5e(one_chip, shape):
               "par_cost": sd((T, B, NK), t64),
               "act": sd((T, B, NK), jnp.bool_),
               "bad_row": sd((T, B), jnp.bool_),
-              "succ": sd((T, B, SC), jnp.int32)}
+              "pred": sd((T, B, PC), jnp.int32),
+              "at_own": sd((T, B), jnp.int32),
+              "at_par": sd((T, B), jnp.int32)}
         args = (xs, sd((P, S, B), t64),                  # clocks
-                sd((T + 1, B), t64),                     # ready
-                sd((T + 1, B), jnp.int32),               # placement
+                sd((T + 1, B), t64),                     # ends
+                sd((2 * T + 1, B), jnp.int32),           # placed
                 sd((P, B), t64), sd((P, B), jnp.bool_),  # busy, seen
                 sd((B, NK), jnp.int32), sd((B,), jnp.int32),
                 sd((), jnp.bool_))
