@@ -34,7 +34,10 @@ Invariants (shared with the numpy backend unless stated):
   ``prev_key`` per lane); lanes whose popped ``(ready_t, tie_break)`` keys
   ever violate it are flagged, their scan state is discarded, and they are
   re-simulated through :func:`~repro.core.fastsim.simulate_fast` — the
-  identical contract to the numpy backend.
+  identical contract to the numpy backend — or, on the megabatch, where a
+  group has enough of them, completed in the **heap scan**
+  (:func:`_heap_runner`), a second lockstep scan in which each lane pops
+  its own heap and so needs no order.
 * **Fixed-bucket lane chunking.**  Lanes are evaluated in chunks padded to
   power-of-two widths (``chunk`` caps the bucket — non-power-of-two caps
   round *down* to a power of two, so the compiled width never exceeds the
@@ -87,6 +90,7 @@ from .replay import (BatchStats, JAX_RTOL, Layout,  # noqa: F401
 from .simulator import SimResult
 from .spans import span
 from .xlacache import CompileCache
+from ..testing import faults
 
 # The jax import is deferred until the engine is actually used: importing
 # repro.core (which re-exports simulate_jax) must stay cheap and must not
@@ -111,6 +115,14 @@ MEGABATCH_CHUNK = 256
 #: therefore sized so ``P_max × S × B`` stays near this target (64 KiB of
 #: f64): wide lanes for narrow slot axes, narrow lanes for wide ones.
 TARGET_SLICE_ELEMS = 8192
+
+#: Heap-scan launch shapes (:func:`_heap_cohorts`): rows pad to a multiple
+#: of this, and lanes and cohorts bucket to powers of two no smaller than
+#: these floors, so a sweep whose count of heap-bound lanes or cohorts
+#: drifts with its costs meets the widths its warm-up compiled.
+HEAP_ROW_ALIGN = 128
+HEAP_MIN_LANES = 32
+HEAP_MIN_COHORTS = 4
 
 #: Valid ``step_impl`` names: ``auto`` resolves to ``lax`` on every
 #: backend (the scan's 64-bit state cannot enter the Mosaic kernel — see
@@ -258,6 +270,55 @@ def resolve_step_impl(step_impl: str) -> str:
 # ---------------------------------------------------------------------------
 
 
+def _choose(opts, cost, rt, minc, kind_pool, smp_kid, eft, aB):
+    """Vectorised reference `_choose_kind` over all lanes: options visited
+    in annotation order, strict < on (key, pref) — the lowest-index winner,
+    identical tie-breaks to the exact engines.  ``opts [B, K]`` / ``cost
+    [B, NK]`` are each lane's own row's tables; ``minc [P, B]`` is the
+    step's hoisted earliest-free-slot reduction, so each option costs a
+    [B] gather instead of its own [B, S] min.  Shared by both scans."""
+    _, jnp = _jax()
+    B = aB.shape[0]
+    zero = jnp.zeros((), dtype=minc.dtype)
+    best_k = jnp.full((B,), -1, dtype=jnp.int32)
+    bv = jnp.zeros((B,), dtype=minc.dtype)
+    bp = jnp.zeros((B,), dtype=jnp.int32)
+    for j in range(opts.shape[1]):              # K is static and tiny
+        k = opts[:, j]
+        kk = jnp.maximum(k, 0)
+        pi = kind_pool[aB, kk]                                  # [B]
+        valid = (k >= 0) & (pi >= 0)
+        base = cost[aB, kk]
+        t = minc[jnp.maximum(pi, 0), aB]
+        start = jnp.maximum(rt, t)
+        keyv = _bits_add(start, jnp.where(eft, base, zero))
+        pref = (k == smp_kid).astype(jnp.int32)
+        better = valid & ((best_k < 0) | (keyv < bv)
+                          | ((keyv == bv) & (pref < bp)))
+        bv = jnp.where(better, keyv, bv)
+        bp = jnp.where(better, pref, bp)
+        best_k = jnp.where(better, k, best_k)
+    return best_k
+
+
+def _commit(clocks, busy, seen, p, rt, base, live, aB):
+    """Slot argmin + clock/busy/seen update — the step's dense tail, shared
+    by both scans."""
+    _, jnp = _jax()
+    cl = clocks[p, :, aB]                                       # [B, S]
+    s = jnp.argmin(cl, axis=1)                  # first-minimum, like ref
+    tmin = cl[aB, s]
+    start = jnp.maximum(rt, tmin)
+    end = _bits_add(start, base)
+    clocks = clocks.at[p, s, aB].set(
+        jnp.where(live, end, clocks[p, s, aB]))
+    b0 = busy[p, aB]
+    busy = busy.at[p, aB].set(
+        jnp.where(live, _bits_add(b0, _bits_sub(end, start)), b0))
+    seen = seen.at[p, aB].set(seen[p, aB] | live)
+    return clocks, busy, seen, end
+
+
 @functools.lru_cache(maxsize=None)
 def _runner():
     """The pure scan function.
@@ -295,58 +356,12 @@ def _runner():
     """
     jax, jnp = _jax()
 
-    def commit(clocks, busy, seen, p, rt, base, live, aB):
-        """Slot argmin + clock/busy/seen update — the step's dense tail."""
-        cl = clocks[p, :, aB]                               # [B, S]
-        s = jnp.argmin(cl, axis=1)          # first-minimum, like ref
-        tmin = cl[aB, s]
-        start = jnp.maximum(rt, tmin)
-        end = _bits_add(start, base)
-        clocks = clocks.at[p, s, aB].set(
-            jnp.where(live, end, clocks[p, s, aB]))
-        b0 = busy[p, aB]
-        busy = busy.at[p, aB].set(
-            jnp.where(live, _bits_add(b0, _bits_sub(end, start)), b0))
-        seen = seen.at[p, aB].set(seen[p, aB] | live)
-        return clocks, busy, seen, end
-
     def run(xs, clocks, ends, placed, busy, seen, kind_pool, smp_kid, eft):
         # the scan's operations carry a stable name in the HLO metadata
         with jax.named_scope("lockstep_scan"):
             B = clocks.shape[2]
             aB = jnp.arange(B)
-            K = xs["own_opts"].shape[2]
-            skid = smp_kid                                      # [B]
             zero = jnp.zeros((), dtype=clocks.dtype)
-
-            def choose(opts, cost, rt, minc):
-                """Vectorised reference `_choose_kind` over all lanes: options
-                visited in annotation order, strict < on (key, pref) — the
-                lowest-index winner, identical tie-breaks to the exact
-                engines.  ``opts [B, K]`` / ``cost [B, NK]`` are the lanes'
-                own cohorts' tables (lane-aligned by the host pre-gather);
-                ``minc [P, B]`` is the step's hoisted earliest-free-slot
-                reduction, so each option costs a [B] gather instead of its
-                own [B, S] min."""
-                best_k = jnp.full((B,), -1, dtype=placed.dtype)
-                bv = jnp.zeros((B,), dtype=minc.dtype)
-                bp = jnp.zeros((B,), dtype=jnp.int32)
-                for j in range(K):                      # K is static and tiny
-                    k = opts[:, j]
-                    kk = jnp.maximum(k, 0)
-                    pi = kind_pool[aB, kk]                          # [B]
-                    valid = (k >= 0) & (pi >= 0)
-                    base = cost[aB, kk]
-                    t = minc[jnp.maximum(pi, 0), aB]
-                    start = jnp.maximum(rt, t)
-                    keyv = _bits_add(start, jnp.where(eft, base, zero))
-                    pref = (k == skid).astype(jnp.int32)
-                    better = valid & ((best_k < 0) | (keyv < bv)
-                                      | ((keyv == bv) & (pref < bp)))
-                    bv = jnp.where(better, keyv, bv)
-                    bp = jnp.where(better, pref, bp)
-                    best_k = jnp.where(better, k, best_k)
-                return best_k
 
             def step(carry, x):
                 (t, clocks, ends, placed, busy, seen, makespan, prev_rt,
@@ -379,7 +394,8 @@ def _runner():
                 # (pad slot 2 T_max holds -1: not placed yet)
                 has_cond = (x["c"] >= 0) & valid
                 pk = placed[x["at_par"], aB]                        # [B]
-                chosen_p = choose(x["par_opts"], x["par_cost"], rt, minc)
+                chosen_p = _choose(x["par_opts"], x["par_cost"], rt, minc,
+                                   kind_pool, smp_kid, eft, aB)
                 pk = jnp.where(pk < 0, chosen_p, pk)
                 live = jnp.where(has_cond, x["act"][aB, jnp.maximum(pk, 0)],
                                  True) & valid
@@ -387,7 +403,8 @@ def _runner():
                 # ---- dispatch + commit for the lanes executing the row ------
                 k_own = placed[x["at_own"], aB]
                 und = k_own < 0
-                chosen_o = choose(x["own_opts"], x["own_cost"], rt, minc)
+                chosen_o = _choose(x["own_opts"], x["own_cost"], rt, minc,
+                                   kind_pool, smp_kid, eft, aB)
                 is_comp = x["is_comp"]
                 k = jnp.where(is_comp, jnp.where(und, chosen_o, k_own),
                               x["k_first"])
@@ -396,8 +413,8 @@ def _runner():
                 kk = jnp.maximum(k, 0)
                 p = jnp.maximum(kind_pool[aB, kk], 0)               # [B]
                 base = x["own_cost"][aB, kk]                        # [B]
-                clocks, busy, seen, end = commit(clocks, busy, seen, p, rt,
-                                                 base, live, aB)
+                clocks, busy, seen, end = _commit(clocks, busy, seen, p, rt,
+                                                  base, live, aB)
                 end_eff = jnp.where(live, end, jnp.where(valid, rt, zero))
                 makespan = jnp.maximum(makespan, end_eff)
                 # one in-place row write each at the shared step counter:
@@ -423,6 +440,140 @@ def _runner():
             return makespan, busy, seen, placed, div
 
     return run
+
+
+#: ``cnt`` of a row a lane has popped: never 0 again, since a popped row
+#: is no later row's successor.
+_POPPED = -1
+#: ``cnt`` of a padding row: never released, so never ready.
+_PAD_PREDS = 1 << 30
+
+
+@functools.lru_cache(maxsize=None)
+def _heap_runner():
+    """The pure heap-scan function: every lane pops its *own* heap.
+
+    The reference pops its ready set by the key ``(ready_t,
+    creation_index, uid)``, a strict total order, and a lane's ready set
+    is the rows whose predecessors it has all popped.  So the per-lane
+    lexicographic argmin of ``(ready bits, tie-break rank)`` over the rows
+    that are ready and not popped is exactly the reference's next pop —
+    at zero-cost ties too — and no lane can diverge: this scan needs no
+    replayed order and no validation.  The host sorts each cohort's rows
+    by the tie-break (:func:`_heap_tables`), so the rank is the row
+    index and the first minimum of the ready bits is the pop.
+
+    Per-lane state over the cohort's ``R`` (padded) rows: ``ready [R, B]``
+    (int64 float64 bit patterns, the max of the popped predecessors'
+    ends), ``cnt [R, B]`` (predecessors still unpopped; ``_POPPED`` once
+    popped, ``_PAD_PREDS`` on padding rows) and ``placement [R, B]``,
+    beside the pool clocks, busy and seen of the replay scan.  Each step
+    pops, runs the replay step's conditional pass-through, :func:`_choose`
+    and :func:`_commit` on the popped row, then releases the row's
+    successors with its end time.  Every row-indexed write is a dense
+    ``where`` over ``[R, B]`` (the popped row's one-hot, and its successor
+    bitset row expanded to a mask): on a TPU a per-step scatter into
+    row-indexed ``[R, B]`` state moves the whole array through vector
+    memory every step.
+
+    Graph tables are per cohort, stacked row-major ``[R, G, ...]`` (so the
+    loop's operands share the leading dimension ``R``, its trip count);
+    each lane carries its cohort index ``g`` and its own row count
+    (``valid`` steps are ``t < nrows``).  A lane that pops a row the
+    reference would raise on, or finds nothing ready, comes back flagged
+    for the exact path.  Compiled ahead of time per shape signature
+    (:func:`_load_heap_runner`); the XLA module is ``jit_run_heap``.
+    """
+    jax, jnp = _jax()
+    lax = jax.lax
+
+    def run_heap(tabs, clocks, kind_pool, smp_kid, g, nrows, eft):
+        with jax.named_scope("heap_scan"):
+            R = tabs["npred"].shape[0]
+            P, _, B = clocks.shape
+            aB = jnp.arange(B)
+            zero = jnp.zeros((), dtype=clocks.dtype)
+            none = jnp.array(np.iinfo(np.int64).max, dtype=clocks.dtype)
+            rows = lax.broadcasted_iota(jnp.int32, (R, B), 0)
+            shifts = jnp.arange(32, dtype=jnp.uint32)
+
+            def first_min(a, b):
+                (ka, ia), (kb, ib) = a, b
+                pick = (ka < kb) | ((ka == kb) & (ia < ib))
+                return jnp.where(pick, ka, kb), jnp.where(pick, ia, ib)
+
+            def row(name, r):
+                """The lanes' own cohorts' table rows at ``r``."""
+                return tabs[name][r, g]
+
+            def step(carry, valid):
+                (clocks, ready, cnt, placement, busy, seen, makespan,
+                 bad) = carry
+                # ---- pop: each lane's (ready bits, rank) minimum ------------
+                kmin, r = lax.reduce(
+                    (jnp.where(cnt == 0, ready, none), rows),
+                    (none, jnp.int32(R)), first_min, (0,))
+                empty = kmin == none
+                bad = bad | (valid & empty)             # nothing ready
+                r = jnp.where(empty, 0, r)
+                rt = jnp.where(empty, zero, kmin)
+                minc = jnp.min(clocks, axis=1)                      # [P, B]
+
+                # ---- conditional pass-through (per-lane mask) ---------------
+                c = row("c", r)
+                has_cond = (c >= 0) & valid
+                cc = jnp.maximum(c, 0)
+                pk = placement[cc, aB]
+                chosen_p = _choose(row("opts", cc), row("cost", cc), rt, minc,
+                                   kind_pool, smp_kid, eft, aB)
+                pk = jnp.where(pk < 0, chosen_p, pk)
+                live = jnp.where(has_cond,
+                                 tabs["act"][r, g, jnp.maximum(pk, 0)],
+                                 True) & valid
+
+                # ---- dispatch + commit for the lanes executing the row ------
+                k_own = placement[r, aB]
+                und = k_own < 0
+                cost = row("cost", r)
+                chosen_o = _choose(row("opts", r), cost, rt, minc,
+                                   kind_pool, smp_kid, eft, aB)
+                is_comp = row("is_comp", r)
+                k = jnp.where(is_comp, jnp.where(und, chosen_o, k_own),
+                              row("k_first", r))
+                k_row = jnp.where(is_comp & live & und, k, k_own)
+                bad = bad | (live & (row("bad_row", r) | (k < 0)))
+                kk = jnp.maximum(k, 0)
+                p = jnp.maximum(kind_pool[aB, kk], 0)
+                clocks, busy, seen, end = _commit(clocks, busy, seen, p, rt,
+                                                  cost[aB, kk], live, aB)
+                end_eff = jnp.where(live, end, jnp.where(valid, rt, zero))
+                makespan = jnp.maximum(makespan, end_eff)
+
+                # ---- dense row writes: placements, pop, release -------------
+                at_r = (rows == r) & valid
+                placement = jnp.where(
+                    at_r, k_row, jnp.where((rows == cc) & has_cond, pk,
+                                           placement))
+                words = row("succ", r).T                            # [W, B]
+                succ = (((words[:, None, :] >> shifts[None, :, None]) & 1)
+                        .reshape(R, B) != 0) & valid
+                ready = jnp.where(succ, jnp.maximum(ready, end_eff), ready)
+                cnt = jnp.where(at_r, _POPPED, cnt - succ.astype(cnt.dtype))
+                return (clocks, ready, cnt, placement, busy, seen, makespan,
+                        bad), None
+
+            init = (clocks, jnp.zeros((R, B), dtype=clocks.dtype),
+                    tabs["npred"][:, g], jnp.full((R, B), -1, jnp.int32),
+                    jnp.zeros((P, B), dtype=clocks.dtype),
+                    jnp.zeros((P, B), dtype=bool),
+                    jnp.zeros((B,), dtype=clocks.dtype),
+                    jnp.zeros((B,), dtype=bool))
+            # the scan's step input: [R, B], one step per padded row
+            (_c, _rd, _cnt, placement, busy, seen, makespan, bad), _ = \
+                lax.scan(step, init, rows < nrows)
+            return makespan, busy, seen, placement, bad
+
+    return run_heap
 
 
 @functools.lru_cache(maxsize=None)
@@ -454,6 +605,14 @@ def _load_runner(cc: CompileCache, args: Tuple):
     jax, _ = _jax()
     return cc.load_or_compile(
         _signature(args), lambda: jax.jit(_runner()).lower(*args))
+
+
+def _load_heap_runner(cc: CompileCache, args: Tuple):
+    """:func:`_load_runner` for the heap scan, under its own signatures."""
+    jax, _ = _jax()
+    return cc.load_or_compile(
+        ("heap",) + _signature(args),
+        lambda: jax.jit(_heap_runner()).lower(*args))
 
 
 # ---------------------------------------------------------------------------
@@ -493,11 +652,11 @@ def _bad_rows(fg: FrozenGraph, kind_pool: Sequence[int]) -> np.ndarray:
     return bad
 
 
-def _pool_caps(fg: FrozenGraph, order: Sequence[int],
-               kind_pool: Sequence[int], P: int) -> np.ndarray:
-    """``int[P]``: how many rows of ``order`` could *ever* dispatch to each
-    pool — computes count toward every eligible pool, non-computes toward
-    their device's pool.
+def _pool_caps(fg: FrozenGraph, kind_pool: Sequence[int],
+               P: int) -> np.ndarray:
+    """``int[P]``: how many rows could *ever* dispatch to each pool —
+    computes count toward every eligible pool, non-computes toward their
+    device's pool.
 
     This bounds the slot axis exactly: slots are claimed in prefix order
     (the commit's first-minimum argmin always prefers the lowest-index
@@ -506,26 +665,17 @@ def _pool_caps(fg: FrozenGraph, order: Sequence[int],
     a lane's slot count to the cap changes nothing about its schedule.  A
     1000-slot candidate over a 64-task graph then costs a 64-wide slot
     axis, not 1024 (the canonical over-provisioned end of a co-design
-    ramp).  Memoised per (order, kind_pool) beside :func:`_group_xs`.
+    ramp).  Every replayed order holds every row, so the cap is the
+    graph's, whatever the order.
     """
-    cache = getattr(fg, "_jax_caps", None)
-    if cache is None:
-        cache = fg._jax_caps = {}
-    ckey = (tuple(order), tuple(kind_pool), P)
-    cached = cache.get(ckey)
-    if cached is not None:
-        return cached
-    (_uids, _ci, _cond, dev_first, dev_opts, _asets, _costs, _succs,
-     _npred, is_comp, *_rest) = fg._runtime()
+    lens = np.diff(fg.dev_indptr)
+    first = np.zeros(len(fg.dev_kids), dtype=bool)
+    first[fg.dev_indptr[:-1][lens > 0]] = True
+    take = fg.is_compute[np.repeat(np.arange(fg.n), lens)] | first
+    per_kind = np.bincount(fg.dev_kids[take], minlength=len(fg.kinds))
+    kp = np.asarray(kind_pool)
     cap = np.zeros(P, dtype=np.int64)
-    for r in order:
-        for k in (dev_opts[r] if is_comp[r] else (dev_first[r],)):
-            p = kind_pool[k]
-            if p >= 0:
-                cap[p] += 1
-    if len(cache) >= _XS_CACHE_CAP:
-        cache.pop(next(iter(cache)))
-    cache[ckey] = cap
+    np.add.at(cap, kp[kp >= 0], per_kind[kp >= 0])
     return cap
 
 
@@ -636,9 +786,70 @@ def _group_xs(fg: FrozenGraph, order: Sequence[int],
     return xs
 
 
+def _heap_tables(fg: FrozenGraph, kind_pool: Sequence[int]
+                 ) -> Dict[str, np.ndarray]:
+    """The heap scan's per-cohort graph tables (see :func:`_heap_runner`),
+    rows sorted by the heap's tie-break ``(creation_index, uid)`` (the
+    replay's ``tb``): ``npred``, ``c`` (the conditional parent's sorted
+    position, or -1), ``is_comp``, ``k_first``, ``opts [n, K]``, ``cost [n,
+    NK]``, ``act [n, NK]``, ``bad_row`` (:func:`_bad_rows`), ``succ [n,
+    W]`` (each row's successors as a bitset over sorted positions, ``W =
+    ceil(n / 32)`` words), and ``pos [n]``, each row's sorted position,
+    which the host reads the final placements at.  Memoised on the
+    FrozenGraph beside :func:`_group_xs`."""
+    cache = getattr(fg, "_jax_xs", None)
+    if cache is None:
+        cache = fg._jax_xs = {}
+    ckey = ("heap", tuple(kind_pool))
+    cached = cache.get(ckey)
+    if cached is not None:
+        return cached
+    (_uids, ci, cond, dev_first, dev_opts, asets, _costs, _succs,
+     _npred, _is_comp, rankmaps, *_rest) = fg._runtime()
+    n = fg.n
+    tb, act_mask = graph_aux(fg, ci, rankmaps[0], asets)
+    order = np.argsort(np.asarray(tb, dtype=np.int64), kind="stable")
+    pos = np.empty(n, dtype=np.int64)
+    pos[order] = np.arange(n)
+    K = max(1, max(len(dev_opts[i]) for i in range(n)) if n else 1)
+    opts = np.full((n, K), -1, dtype=np.int32)
+    for r in range(n):
+        opts[pos[r], :len(dev_opts[r])] = dev_opts[r]
+    cond_np = np.asarray(cond, dtype=np.int64)
+    src = np.repeat(np.arange(n), np.diff(fg.succ_indptr))
+    ps, pd = pos[src], pos[fg.succ_rows]
+    succ = np.zeros((n, (n + 31) // 32), dtype=np.uint32)
+    np.bitwise_or.at(succ, (ps, pd >> 5),
+                     np.left_shift(np.uint32(1), (pd & 31).astype(np.uint32)))
+    tabs = {
+        "npred": fg.n_pred[order].astype(np.int32),
+        "c": np.where(cond_np >= 0, pos[np.maximum(cond_np, 0)],
+                      -1)[order].astype(np.int32),
+        "is_comp": fg.is_compute[order],
+        "k_first": np.asarray(dev_first, dtype=np.int32)[order],
+        "opts": opts,
+        # NaN costs are flagged as bad rows; scrub them like _group_xs
+        "cost": np.nan_to_num(fg.cost[order]),
+        "act": act_mask[order],
+        "bad_row": _bad_rows(fg, kind_pool)[order],
+        "succ": succ,
+        "pos": pos,
+    }
+    if len(cache) >= _XS_CACHE_CAP:
+        cache.pop(next(iter(cache)))
+    cache[ckey] = tabs
+    return tabs
+
+
 # ---------------------------------------------------------------------------
 # Cohort driver: task-axis padding, chunked lanes, shared compiled scan
 # ---------------------------------------------------------------------------
+
+
+def _times(a: np.ndarray) -> np.ndarray:
+    """Host float64 times as the scans' int64 bit patterns (see
+    :func:`_bits_add`); other arrays unchanged."""
+    return a.view(np.int64) if a.dtype == np.float64 else a
 
 
 def _repad(slots: np.ndarray, pad: int, to: int) -> np.ndarray:
@@ -706,11 +917,6 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
     jax, jnp = _jax()
     resolve_step_impl(step_impl)
 
-    def _times(a: np.ndarray) -> np.ndarray:
-        """Host float64 times as the scan's int64 bit patterns (see
-        :func:`_bits_add`); other arrays unchanged."""
-        return a.view(np.int64) if a.dtype == np.float64 else a
-
     cc = compile_cache if compile_cache is not None else _MEM_COMPILE_CACHE
     eft = policy == "eft"
 
@@ -719,7 +925,7 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
         for fg, order, layouts, cuts in cohorts:
             pool_names, _, kind_pool = layouts[0]           # template-shared
             kinds = fg.kinds
-            caps = _pool_caps(fg, order, kind_pool, len(pool_names))
+            caps = _pool_caps(fg, kind_pool, len(pool_names))
             lane_counts = [lay[1] for lay in layouts]
             per.append({
                 "fg": fg, "xs": _group_xs(fg, order, kind_pool), "cuts": cuts,
@@ -963,6 +1169,180 @@ def _scan_group(fg: FrozenGraph, order: Sequence[int],
     return triple
 
 
+def _heap_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[Layout],
+                                          Optional[np.ndarray]]],
+                  policy: str, *, chunk: int,
+                  universe: Optional[Sequence[Tuple[FrozenGraph,
+                                                    Sequence[Layout]]]]
+                  = None,
+                  compile_cache: Optional[CompileCache] = None,
+                  stats: Optional[BatchStats] = None
+                  ) -> List[Tuple[Dict[int, SimResult], List[int],
+                                  Dict[int, float]]]:
+    """Complete every lane of every ``(fg, layouts, cutoffs)`` cohort in
+    the heap scan (:func:`_heap_runner`), which needs no order.
+
+    The launch's shapes come from ``universe``, every ``(fg, layouts)``
+    group of the sweep that could send lanes here (default: the cohorts
+    themselves): rows pad to the largest graph (a multiple of
+    :data:`HEAP_ROW_ALIGN`), pools, kinds and options to the largest, and
+    the slot axis to the widest lane's need (pool slot counts clamped to
+    the rows that could ever dispatch there, as :func:`_pool_caps`).  So
+    which lanes of a sweep diverge moves none of them; lanes and cohorts
+    bucket with floors (:data:`HEAP_MIN_LANES`, :data:`HEAP_MIN_COHORTS`).
+    Lanes of all cohorts share slices of at most ``chunk`` lanes.
+
+    Returns one ``(done, flagged, retired)`` triple per cohort, positions
+    indexing its ``layouts``: ``flagged`` lanes met a row the reference
+    raises on (or a deadlock) and take the exact path; retirement is
+    post-scan classification against ``cutoffs``, as in
+    :func:`_scan_cohorts`.  ``stats`` receives ``heap_lane_steps`` and
+    ``heap_h2d_bytes``.
+    """
+    if not cohorts:
+        return []
+    if faults.fire("fail_lockstep"):
+        raise RuntimeError("injected fault: fail_lockstep")
+    jax, jnp = _jax()
+    cc = compile_cache if compile_cache is not None else _MEM_COMPILE_CACHE
+    if universe is None:
+        universe = [(fg, layouts) for fg, layouts, _ in cohorts]
+
+    with span("jaxsim.heap_prep"):
+        R = -(-max(fg.n for fg, _ in universe) // HEAP_ROW_ALIGN) \
+            * HEAP_ROW_ALIGN
+        W = R // 32
+        P = max(len(lay[0]) for _, lays in universe for lay in lays)
+        NK = max(len(fg.kinds) for fg, _ in universe)
+        K = max(max(1, int(np.diff(fg.dev_indptr).max(initial=0)))
+                for fg, _ in universe)
+        need = 1
+        for fg, lays in universe:
+            names, _, kind_pool = lays[0]               # template-shared
+            caps = _pool_caps(fg, kind_pool, len(names)).tolist()
+            for _, counts, _ in lays:
+                need = max([need] + [min(c, cap)
+                                     for c, cap in zip(counts, caps)])
+        S = _bucket(need, cap=1 << 30)
+        G = 1 << (max(len(cohorts), HEAP_MIN_COHORTS) - 1).bit_length()
+
+        per = []
+        kind_pool_m = np.full((G, NK), -1, dtype=np.int32)
+        smp_kid_m = np.full((G,), -1, dtype=np.int32)
+        for gi, (fg, layouts, cuts) in enumerate(cohorts):
+            names, _, kind_pool = layouts[0]            # template-shared
+            per.append({"fg": fg, "tabs": _heap_tables(fg, kind_pool),
+                        "layouts": layouts, "cuts": cuts,
+                        "names": names, "P": len(names)})
+            kind_pool_m[gi, :len(kind_pool)] = kind_pool
+            smp_kid_m[gi] = fg.kinds.index("smp") if "smp" in fg.kinds \
+                else -1
+        key = (tuple((c["fg"].content_hash(), tuple(kind_pool_m[gi]))
+                     for gi, c in enumerate(per)), (R, G, K, NK))
+
+    with jax.enable_x64(True):
+        def _put(a: np.ndarray):
+            """One host-to-device upload, counted in ``heap_h2d_bytes``."""
+            if stats is not None:
+                stats.heap_h2d_bytes += a.nbytes
+            return jnp.asarray(a)
+
+        with span("jaxsim.heap_prep"):
+            tabs_j = _DEV_XS_CACHE.get(key)
+            if tabs_j is None:
+                stack = {
+                    "npred": np.full((R, G), _PAD_PREDS, dtype=np.int32),
+                    "c": np.full((R, G), -1, dtype=np.int32),
+                    "is_comp": np.zeros((R, G), dtype=bool),
+                    "k_first": np.zeros((R, G), dtype=np.int32),
+                    "opts": np.full((R, G, K), -1, dtype=np.int32),
+                    "cost": np.zeros((R, G, NK), dtype=np.float64),
+                    "act": np.zeros((R, G, NK), dtype=bool),
+                    "bad_row": np.zeros((R, G), dtype=bool),
+                    "succ": np.zeros((R, G, W), dtype=np.uint32),
+                }
+                for gi, c in enumerate(per):
+                    t = c["tabs"]
+                    n = len(t["npred"])
+                    for f, a in stack.items():
+                        if a.ndim == 2:
+                            a[:n, gi] = t[f]
+                        else:
+                            a[:n, gi, :t[f].shape[1]] = t[f]
+                with span("jaxsim.heap_transfer"):
+                    tabs_j = {f: _put(_times(a)) for f, a in stack.items()}
+                if len(_DEV_XS_CACHE) >= _DEV_XS_CACHE_CAP:
+                    _DEV_XS_CACHE.popitem(last=False)
+                _DEV_XS_CACHE[key] = tabs_j
+            else:
+                _DEV_XS_CACHE.move_to_end(key)
+
+        lanes = [(gi, pos) for gi, c in enumerate(per)
+                 for pos in range(len(c["layouts"]))]
+        width = _bucket(chunk, cap=chunk)
+        launched = []
+        for lo in range(0, len(lanes), width):
+            sl = lanes[lo:lo + width]
+            with span("jaxsim.heap_prep"):
+                B = _bucket(max(len(sl), HEAP_MIN_LANES), cap=chunk)
+                padded = sl + [sl[-1]] * (B - len(sl))
+                g_np = np.fromiter((gi for gi, _ in padded), dtype=np.int32,
+                                   count=B)
+                clocks = np.full((P, S, B), np.inf)
+                for li, (gi, pos) in enumerate(padded):
+                    for p, cnt in enumerate(per[gi]["layouts"][pos][1]):
+                        clocks[p, :cnt, li] = 0.0
+                nrows = np.fromiter((per[gi]["fg"].n for gi, _ in padded),
+                                    dtype=np.int32, count=B)
+                with span("jaxsim.heap_transfer"):
+                    args = (tabs_j, _put(_times(clocks)),
+                            _put(kind_pool_m[g_np]), _put(smp_kid_m[g_np]),
+                            _put(g_np), _put(nrows), _put(np.asarray(policy
+                                                                     == "eft")))
+                exe = _load_heap_runner(cc, args)
+            if stats is not None:
+                stats.heap_lane_steps += B * R
+            with span("jaxsim.heap_launch"):
+                makespan, busy, seen, placement, bad = exe(*args)
+                launched.append((sl, np.asarray(bad),
+                                 np.asarray(makespan).view(np.float64),
+                                 np.asarray(busy).view(np.float64),
+                                 np.asarray(seen), np.asarray(placement)))
+
+    with span("replay.assemble"):
+        accs = [{"kept": [], "mk": [], "busy": [], "seen": [], "place": []}
+                for _ in per]
+        flagged: List[List[int]] = [[] for _ in per]
+        retired: List[Dict[int, float]] = [{} for _ in per]
+        for sl, bad_np, mk_np, busy_np, seen_np, place_np in launched:
+            for li, (gi, pos) in enumerate(sl):
+                c, acc = per[gi], accs[gi]
+                if bad_np[li]:
+                    flagged[gi].append(pos)
+                elif c["cuts"] is not None and mk_np[li] > c["cuts"][pos]:
+                    retired[gi][pos] = float(mk_np[li])
+                else:
+                    acc["kept"].append(pos)
+                    acc["mk"].append(mk_np[li:li + 1])
+                    acc["busy"].append(busy_np[:c["P"], li:li + 1])
+                    acc["seen"].append(seen_np[:c["P"], li:li + 1])
+                    # each row's placement at its sorted position
+                    acc["place"].append(place_np[c["tabs"]["pos"], li:li + 1])
+        results = []
+        for gi, c in enumerate(per):
+            acc = accs[gi]
+            done: Dict[int, SimResult] = {}
+            if acc["kept"]:
+                done = lane_results(
+                    c["fg"], c["names"], [lay[1] for lay in c["layouts"]],
+                    acc["kept"], policy, np.concatenate(acc["mk"]),
+                    np.concatenate(acc["busy"], axis=1),
+                    np.concatenate(acc["seen"], axis=1),
+                    np.concatenate(acc["place"], axis=1).astype(np.int64))
+            results.append((done, flagged[gi], retired[gi]))
+    return results
+
+
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
@@ -1053,7 +1433,11 @@ def simulate_jax_many(items: Sequence[Tuple[FrozenGraph,
                              step_impl=step_impl, slot_bucketed=True,
                              stats=stats)
 
+    def heap_many(cohorts, universe):
+        return _heap_cohorts(cohorts, policy, chunk=chunk, universe=universe,
+                             compile_cache=compile_cache, stats=stats)
+
     return simulate_many(items, policy, lockstep_many_fn=lockstep_many,
-                         min_lockstep=min_lockstep, stats=stats,
-                         library=library, max_rounds=max_rounds,
+                         heap_many_fn=heap_many, min_lockstep=min_lockstep,
+                         stats=stats, library=library, max_rounds=max_rounds,
                          prunes=prunes)

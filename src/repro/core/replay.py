@@ -141,6 +141,17 @@ CohortSpec = Tuple[FrozenGraph, Tuple[int, ...], List[Layout],
 LockstepManyFn = Callable[[Sequence[CohortSpec]],
                           List[Tuple[Dict[int, SimResult], List[int],
                                      Dict[int, float]]]]
+# A backend's heap scan: every lane of every ``(fg, layouts, cutoffs)``
+# cohort completed with no order, in one call; the second argument is every
+# ``(fg, layouts)`` group of the sweep that could send lanes there (it fixes
+# the launch's shapes).  One (done, flagged, retired) triple per cohort:
+# ``flagged`` lanes met a row the reference raises on and take the exact
+# path.
+HeapManyFn = Callable[[Sequence[Tuple[FrozenGraph, List[Layout],
+                                      Optional[np.ndarray]]],
+                       Sequence[Tuple[FrozenGraph, List[Layout]]]],
+                      List[Tuple[Dict[int, SimResult], List[int],
+                                 Dict[int, float]]]]
 
 
 @dataclasses.dataclass
@@ -163,7 +174,10 @@ class BatchStats:
     ``rescued_lanes`` counts diverged lanes later completed in lockstep
     against another order; ``order_hits`` counts lanes completed against
     an order the library already held before the call (the warm-sweep
-    figure of merit).
+    figure of merit); ``heap_lanes`` counts lanes completed in the heap
+    scan, the device scan in which each lane pops its own heap, so that
+    no order has to serve it (counted in ``lockstep_lanes`` too, as
+    ``rescued_lanes`` are).
 
     Retirement counters (branch-and-bound pruning fused into the sweep):
     ``retired_lanes`` counts lanes retired mid-sweep because their
@@ -180,7 +194,10 @@ class BatchStats:
     result was kept (neither diverged nor retired), the steps of their own
     cohort's order; ``h2d_bytes`` counts the bytes uploaded host→device
     for the scan (step-input blocks on a device-cache miss, and the
-    arguments each launch builds on the host).
+    arguments each launch builds on the host).  ``heap_lane_steps`` and
+    ``heap_h2d_bytes`` are the same two sums for the heap scan's launches
+    (its stacked graph tables on a device-cache miss, and each launch's
+    lane arguments).
     """
 
     groups: int = 0
@@ -198,6 +215,9 @@ class BatchStats:
     scan_lane_steps: int = 0
     scan_kept_lane_steps: int = 0
     h2d_bytes: int = 0
+    heap_lanes: int = 0
+    heap_lane_steps: int = 0
+    heap_h2d_bytes: int = 0
 
     def as_dict(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -1034,6 +1054,7 @@ def replay_group(fg: FrozenGraph, systems: Sequence[SystemConfig],
 def simulate_many(items: Sequence[Tuple[FrozenGraph,
                                         Sequence[SystemConfig]]],
                   policy: str, *, lockstep_many_fn: LockstepManyFn,
+                  heap_many_fn: HeapManyFn,
                   min_lockstep: int = MIN_LOCKSTEP,
                   stats: Optional[BatchStats] = None,
                   library: Optional[ReplayLibrary] = None,
@@ -1061,16 +1082,28 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
       group to that fresh order *within the same megabatch* — phase 3's
       first re-batch, folded into the main sweep.
     * Unrouted lanes with cached orders try position 0 only (phase 2's
-      first trial); there is **no rescue re-batching** — a diverged lane
-      is discovered serially (order + signature recorded, bounded by
-      ``max_rounds`` per group) or falls back serially.  The library still
-      ends the call warm, so the *next* sweep routes those lanes straight
-      to their own orders; ``rescued_lanes`` is therefore never counted
-      here.
+      first trial); there is **no rescue re-batching**.
+    * The lanes that no order serves — diverged in the replay launch,
+      pinned signatures, and fallbacks past the discovery budget or a
+      full library key — go to **one** ``heap_many_fn`` call for the
+      whole sweep after the replay launch (``jaxsim._heap_cohorts``: a
+      lockstep scan in which each lane pops its own heap, so it needs no
+      order).  Heap lanes count as ``lockstep_lanes`` (and
+      ``heap_lanes``).  They write nothing to the library, which other
+      engines share: a pin there sends a signature to the exact serial
+      path of every engine.  ``heap_many_fn`` gets the heap cohorts
+      ``(fg, layouts, cutoffs)`` and every group of the sweep that could
+      send lanes there, which fixes the launch's shapes.
+    * A group with fewer than ``min_lockstep`` such lanes keeps the exact
+      serial path: a diverged lane is discovered serially (order +
+      signature recorded, bounded by ``max_rounds`` per group, so the
+      *next* sweep routes it) or falls back serially.
+      ``rescued_lanes`` is never counted here.
 
-    Every completion is still either a validated lockstep lane or an exact
-    serial run, so the engine tiers are preserved by construction.
-    Returns one result list per family, each in its ``systems`` order.
+    Every completion is still either a validated lockstep lane, a heap
+    lane (bit-identical by construction) or an exact serial run, so the
+    engine tiers are preserved.  Returns one result list per family, each
+    in its ``systems`` order.
 
     ``prunes`` carries one optional :class:`PruneContext` per family
     (sharing a live :class:`Incumbent` across them); cohorts then ship
@@ -1101,9 +1134,48 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
             pr.offer(systems[i].name, res.makespan)
         return res
 
+    def pinned_serial(gi: int, i: int, hit: bool) -> None:
+        results[gi][i] = serial(gi, i)
+        if stats is not None and not isinstance(results[gi][i], Retired):
+            stats.order_pinned_lanes += 1
+            if hit:
+                stats.order_hits += 1
+
+    def fallback_serial(gi: int, i: int) -> None:
+        results[gi][i] = serial(gi, i)
+        if stats is not None and not isinstance(results[gi][i], Retired):
+            stats.serial_fallback_lanes += 1
+
+    def discover(grp: Dict, i: int, position: int) -> None:
+        """A diverged lane's serial discovery: its own order is recorded
+        so the next sweep routes it (no rescue re-batch here)."""
+        gi, key = grp["gi"], grp["key"]
+        sig = tuple(grp["layouts"][i][1])
+        if grp["discoveries"] >= max_rounds:
+            fallback_serial(gi, i)
+            return
+        out2: List[int] = []
+        results[gi][i] = serial(gi, i, out2)
+        grp["discoveries"] += 1
+        if isinstance(results[gi][i], Retired):
+            return                              # partial order: never recorded
+        pos2 = lib.record(key, out2, sig)
+        if pos2 is None:
+            if stats is not None:
+                stats.serial_fallback_lanes += 1
+            return
+        if pos2 == position:
+            # its own recorded order is the one it just failed: provably a
+            # conservative false positive — pin it
+            lib.pin_sig(key, sig)
+        if stats is not None:
+            stats.reference_lanes += 1
+
     # ---- plan: route every group's lanes to (order, cohort) ------------
     with span("replay.plan"):
         cohorts: List[Dict] = []
+        groups: List[Dict] = []
+        universe: List[Tuple[FrozenGraph, List[Layout]]] = []
         for gi, (fg, systems) in enumerate(items):
             layouts = [pool_layout(fg.kinds, s) for s in systems]
             fams: Dict[Tuple, List[int]] = {}
@@ -1119,6 +1191,7 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                         stats.small_group_lanes += len(lanes)
                     continue
                 key = lib.key(fg, layouts[lanes[0]], policy)
+                universe.append((fg, [layouts[i] for i in lanes]))
                 pr = pr_of(gi)
                 if pr is not None and pr.deficit():
                     # phase-0 incumbent seeding, as in replay_group: the most-
@@ -1142,8 +1215,12 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                     if not lanes:
                         continue
                 orders, sig_map, pins = lib.lookup(key)
+                # "heap": (lane, why, replay position) of the lanes no
+                # order serves, for the heap launch
                 grp = {"gi": gi, "fg": fg, "key": key, "layouts": layouts,
-                       "n_cached": len(orders), "discoveries": 0}
+                       "n_cached": len(orders), "discoveries": 0,
+                       "heap": []}
+                groups.append(grp)
                 order_by_pos: Dict[int, Tuple[int, ...]] = \
                     dict(enumerate(orders))
                 routed: Dict[int, List[int]] = {}
@@ -1151,29 +1228,21 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                 for i in lanes:
                     sig = tuple(layouts[i][1])
                     if sig in pins:
-                        results[gi][i] = serial(gi, i)
-                        if stats is not None and \
-                                not isinstance(results[gi][i], Retired):
-                            stats.order_pinned_lanes += 1
-                            stats.order_hits += 1
+                        grp["heap"].append((i, "pinned", None))
                         continue
                     pos = sig_map.get(sig)
                     if pos is not None and 0 <= pos < len(orders):
                         routed.setdefault(pos, []).append(i)
                     else:
                         unrouted.append(i)
+                fallbacks: List[int] = []
                 if unrouted and not orders:
                     # cold group: one serial reference discovery (the
                     # most-parallel lane), everyone else rides its fresh
                     # order in the megabatch — replay_group's reference
                     # sweep folded into the main dispatch
                     if max_rounds <= 0:
-                        for i in unrouted:
-                            results[gi][i] = serial(gi, i)
-                            if stats is not None and \
-                                    not isinstance(results[gi][i], Retired):
-                                stats.serial_fallback_lanes += 1
-                        unrouted = []
+                        fallbacks, unrouted = unrouted, []
                     else:
                         j = max(unrouted,
                                 key=lambda i: (sum(layouts[i][1]), i))
@@ -1195,12 +1264,7 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                                 else:
                                     stats.reference_lanes += 1
                         if pos is None:         # key full (shared library)
-                            for i in unrouted:
-                                results[gi][i] = serial(gi, i)
-                                if stats is not None and not isinstance(
-                                        results[gi][i], Retired):
-                                    stats.serial_fallback_lanes += 1
-                            unrouted = []
+                            fallbacks, unrouted = unrouted, []
                         else:
                             order_by_pos[pos] = tuple(out)
                             routed.setdefault(pos, []).extend(unrouted)
@@ -1209,6 +1273,7 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                     # untried signatures take the insertion-order first order
                     # (the original reference), like phase 2's first trial
                     routed.setdefault(0, []).extend(unrouted)
+                grp["heap"].extend((i, "fallback", None) for i in fallbacks)
                 for pos, cl in routed.items():
                     cohorts.append({"grp": grp, "position": pos,
                                     "order": order_by_pos[pos], "lanes": cl})
@@ -1219,14 +1284,9 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
         if cohorts and sum(len(c["lanes"]) for c in cohorts) < min_lockstep:
             for c in cohorts:
                 grp = c["grp"]
-                gi = grp["gi"]
                 for i in c["lanes"]:
-                    results[gi][i] = serial(gi, i)
-                    if stats is not None and \
-                            not isinstance(results[gi][i], Retired):
-                        stats.order_pinned_lanes += 1
-                        if c["position"] < grp["n_cached"]:
-                            stats.order_hits += 1
+                    pinned_serial(grp["gi"], i,
+                                  hit=c["position"] < grp["n_cached"])
             cohorts = []
 
     # ---- one megabatch dispatch for every cohort of every family -------
@@ -1262,33 +1322,56 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                             stats.order_hits += 1
                 for pos_l in diverged:
                     i = c["lanes"][pos_l]
-                    sig = tuple(layouts[i][1])
                     if stats is not None:
                         stats.diverged_lanes += 1
-                    if grp["discoveries"] >= max_rounds:
-                        results[gi][i] = serial(gi, i)
-                        if stats is not None and \
-                                not isinstance(results[gi][i], Retired):
-                            stats.serial_fallback_lanes += 1
-                        continue
-                    # serial discovery: the lane's own order is recorded so
-                    # the next sweep routes it (no rescue re-batch here)
-                    out2: List[int] = []
-                    results[gi][i] = serial(gi, i, out2)
-                    grp["discoveries"] += 1
-                    if isinstance(results[gi][i], Retired):
-                        continue                # partial order: never recorded
-                    pos2 = lib.record(key, out2, sig)
-                    if pos2 is None:
-                        if stats is not None:
-                            stats.serial_fallback_lanes += 1
-                        continue
-                    if pos2 == c["position"]:
-                        # its own recorded order is the one it just failed:
-                        # provably a conservative false positive — pin it
-                        lib.pin_sig(key, sig)
+                    grp["heap"].append((i, "diverged", c["position"]))
+
+    # ---- one heap launch for the lanes no order serves -----------------
+    heap_grps = [grp for grp in groups
+                 if len(grp["heap"]) >= min_lockstep]
+    thin = [grp for grp in groups
+            if 0 < len(grp["heap"]) < min_lockstep]
+    if thin:
+        # too few for a launch: the exact serial path
+        with span("replay.assemble"):
+            for grp in thin:
+                for i, why, position in grp["heap"]:
+                    if why == "pinned":
+                        pinned_serial(grp["gi"], i, hit=True)
+                    elif why == "fallback":
+                        fallback_serial(grp["gi"], i)
+                    else:
+                        discover(grp, i, position)
+    if heap_grps:
+        outs = heap_many_fn(
+            [(grp["fg"], [grp["layouts"][i] for i, _, _ in grp["heap"]],
+              None if pr_of(grp["gi"]) is None
+              else pr_of(grp["gi"]).cutoffs(
+                  [i for i, _, _ in grp["heap"]]))
+             for grp in heap_grps], universe)
+        with span("replay.assemble"):
+            for grp, (done, flagged, retired) in zip(heap_grps, outs):
+                gi, systems = grp["gi"], items[grp["gi"]][1]
+                pr = pr_of(gi)
+                lanes = [i for i, _, _ in grp["heap"]]
+                for pos_l, bound in retired.items():
+                    results[gi][lanes[pos_l]] = Retired(float(bound))
+                if stats is not None and retired:
+                    stats.retired_lanes += len(retired)
+                    stats.retire_sweeps += 1
+                for pos_l, sim in done.items():
+                    i = lanes[pos_l]
+                    results[gi][i] = dataclasses.replace(
+                        sim, system=systems[i].name)
+                    if pr is not None:
+                        pr.offer(systems[i].name, sim.makespan)
                     if stats is not None:
-                        stats.reference_lanes += 1
+                        stats.lockstep_lanes += 1
+                        stats.heap_lanes += 1
+                for pos_l in flagged:
+                    # a row the reference raises on: the exact path
+                    # raises it too, or completes the lane
+                    fallback_serial(gi, lanes[pos_l])
     return results  # type: ignore[return-value]
 
 

@@ -53,7 +53,8 @@ delay_chunk    worker, chunk entry; arg = seconds (def. 0.5)  sleep
 corrupt_cache  DiskCache.put; payload written corrupted       bad entry
 delay_put      DiskCache.put, pre-rename; arg = seconds       sleep
 fail_compile   xlacache.CompileCache.load_or_compile          raise
-fail_lockstep  batchsim._run_lockstep entry                   raise
+fail_lockstep  batchsim._run_lockstep entry, and              raise
+               jaxsim._heap_cohorts entry (a heap launch)
 ============== ============================================== ==========
 
 The module lives under ``repro.testing`` but has no dependency on the

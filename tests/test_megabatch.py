@@ -147,9 +147,10 @@ def test_megabatch_matches_per_graph_tier(seed, policy):
 
 
 def test_megabatch_divergent_lanes_fall_back_exactly():
-    """Diverged megabatch lanes take the exact serial path (bit-identical,
-    order recorded — no rescue re-batching), and the per-lane accounting
-    still covers every lane exactly once."""
+    """Diverged megabatch lanes take an exact path — the heap scan, where
+    a group has ``min_lockstep`` of them (bit-identical, no order needed —
+    no rescue re-batching) — and the per-lane accounting still covers
+    every lane exactly once."""
     fg1, _ = frozen_for(synth_trace(40), smp=True)
     fg2, _ = frozen_for(synth_trace(24), smp=False)
     items = [(fg1, [zynq_system(f"a{n}", {"fpga:k": n})
@@ -163,6 +164,7 @@ def test_megabatch_divergent_lanes_fall_back_exactly():
     assert stats.diverged_lanes > 0, "ramp should force exact fallbacks"
     assert stats.lockstep_lanes > 0
     assert stats.rescued_lanes == 0, "megabatch never re-batches"
+    assert stats.heap_lanes == stats.diverged_lanes
     assert (stats.lockstep_lanes + stats.order_pinned_lanes
             + stats.reference_lanes + stats.serial_fallback_lanes
             + stats.small_group_lanes) == n_lanes
@@ -173,9 +175,12 @@ def test_megabatch_divergent_lanes_fall_back_exactly():
 
 
 def test_megabatch_warm_library_routes_everything():
-    """After one cold call the library holds every lane's own order: the
-    next call is all lockstep/pinned with zero discoveries — the warm
-    protocol the megabatch records orders *for*."""
+    """After one cold call the library holds the orders the megabatch
+    discovered: the next call is all lockstep with zero discoveries — the
+    warm protocol the megabatch records orders *for*.  A lane that no
+    order serves records none (it completed in the heap scan, which writes
+    nothing to the shared library), so it diverges again and completes in
+    the heap scan again, with nothing run serially."""
     lib = ReplayLibrary()
     items = _mixed_families(3)
     simulate_jax_many(items, "availability", min_lockstep=2, library=lib)
@@ -183,7 +188,8 @@ def test_megabatch_warm_library_routes_everything():
     stats = BatchStats()
     simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
                       stats=stats)
-    assert stats.diverged_lanes == 0
+    assert stats.diverged_lanes == stats.heap_lanes
+    assert stats.lockstep_lanes == sum(len(s) for _, s in items)
     assert stats.reference_lanes == 0 and stats.serial_fallback_lanes == 0
     assert stats.order_hits > 0
 
@@ -392,15 +398,22 @@ def _record_scans(monkeypatch):
     each cohort's kept lanes × its own steps."""
     import jax.numpy as jnp
     from repro.core import jaxsim
-    rec = {"launches": [], "uploads": [], "launch_args": [], "kept": []}
+    rec = {"launches": [], "uploads": [], "launch_args": [], "kept": [],
+           "heap_launches": []}
     real_load, real_put = jaxsim._load_runner, jnp.asarray
     real_scan = jaxsim._scan_cohorts
+    real_heap = jaxsim._load_heap_runner
 
     def load(cc, args):
         rec["launches"].append(args[0]["valid"].shape)
         rec["launch_args"].append(np.asarray(args[1]).nbytes
                                   + np.asarray(args[8]).nbytes)
         return real_load(cc, args)
+
+    def load_heap(cc, args):
+        rec["heap_launches"].append((args[0]["npred"].shape[0],
+                                     args[1].shape[2]))
+        return real_heap(cc, args)
 
     def put(a, *rest, **kw):
         rec["uploads"].append(np.asarray(a).nbytes)
@@ -416,6 +429,7 @@ def _record_scans(monkeypatch):
     monkeypatch.setattr(jaxsim, "_DEV_XS_CACHE",
                         collections.OrderedDict())
     monkeypatch.setattr(jaxsim, "_load_runner", load)
+    monkeypatch.setattr(jaxsim, "_load_heap_runner", load_heap)
     monkeypatch.setattr(jaxsim, "_scan_cohorts", scan)
     monkeypatch.setattr(jnp, "asarray", put)
     return rec
@@ -423,10 +437,11 @@ def _record_scans(monkeypatch):
 
 def test_scan_counters_match_the_launches_and_uploads(monkeypatch):
     """``scan_lane_steps`` is Σ B × T_max over the launches made,
-    ``scan_kept_lane_steps`` Σ kept lanes × their cohort's steps, and
-    ``h2d_bytes`` the bytes actually handed to ``jnp.asarray``; a warm
-    repeat (every step block a device-cache hit) uploads only each
-    launch's own arguments."""
+    ``scan_kept_lane_steps`` Σ kept lanes × their cohort's steps,
+    ``heap_lane_steps`` Σ B × rows over the heap scan's launches, and
+    ``h2d_bytes`` plus ``heap_h2d_bytes`` the bytes actually handed to
+    ``jnp.asarray``; a warm repeat (every step block a device-cache hit)
+    uploads only each launch's own arguments."""
     items = _ramp_families()
     lib = ReplayLibrary()
     for _ in range(2):              # settle the routing, as a warm sweep
@@ -441,7 +456,9 @@ def test_scan_counters_match_the_launches_and_uploads(monkeypatch):
     assert cold.scan_lane_steps == sum(t * b for t, b in launches)
     assert cold.scan_kept_lane_steps == sum(rec["kept"])
     assert 0 < cold.scan_kept_lane_steps < cold.scan_lane_steps
-    assert cold.h2d_bytes == sum(rec["uploads"])
+    assert rec["heap_launches"], "the diverged lanes must reach the heap"
+    assert cold.heap_lane_steps == sum(r * b for r, b in rec["heap_launches"])
+    assert cold.h2d_bytes + cold.heap_h2d_bytes == sum(rec["uploads"])
     assert cold.h2d_bytes > sum(rec["launch_args"])
 
     for k in rec:
@@ -450,7 +467,8 @@ def test_scan_counters_match_the_launches_and_uploads(monkeypatch):
     simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
                       stats=warm)
     assert rec["launches"] == launches
-    assert warm.h2d_bytes == sum(rec["uploads"]) == sum(rec["launch_args"])
+    assert warm.h2d_bytes == sum(rec["launch_args"])
+    assert warm.h2d_bytes + warm.heap_h2d_bytes == sum(rec["uploads"])
     assert warm.scan_lane_steps == cold.scan_lane_steps
     assert warm.scan_kept_lane_steps == cold.scan_kept_lane_steps
 

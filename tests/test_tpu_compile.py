@@ -12,6 +12,9 @@ library, and every test worker imports every test file.
   Cholesky ``n=2048, bs=128`` graph (4,912 task rows after DMA
   augmentation, 5 pools), and phase B's ``synth:1024`` sweep over 1-128
   accelerators (5,120 task rows, 4 pools, slot axes of 32, 64 and 128).
+* The heap scan (``_heap_runner``) compiles at the launch shapes of the
+  benchmark's matmul and dgemm-ramp cells, with no copy between HBM and
+  vector memory inside its loop.
 * The pallas step-commit kernel compiles to a Mosaic custom call with
   float32 state and x64 off.  No 64-bit operand passes into a Mosaic
   kernel, which is why ``step_impl="auto"`` resolves to ``lax`` inside
@@ -25,7 +28,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.devices import zynq_system
-from repro.core.jaxsim import _runner, resolve_step_impl, simulate_jax
+from repro.core.jaxsim import (_heap_runner, _runner, resolve_step_impl,
+                               simulate_jax)
 from repro.kernels.lockstep_step import step_commit
 from repro.testing.synth import frozen_for, synth_trace
 
@@ -84,6 +88,44 @@ def test_scan_runner_compiles_for_v5e(one_chip, shape):
     # the per-slice step inputs dominate: ~43 MB at 64 lanes, far inside
     # the chip's 16 GB
     assert 0 < mem.argument_size_in_bytes < 1 << 30
+
+
+#: Heap-scan launches of the benchmark's cells: padded rows, cohorts,
+#: options, device kinds, pools, slots, lanes.
+HEAP_LAUNCHES = {"matmul1024-ramp": (3584, 4, 2, 4, 4, 64, 128),
+                 "chol2048-dgemm64": (4480, 4, 2, 4, 4, 64, 128)}
+
+
+@pytest.mark.parametrize("shape", HEAP_LAUNCHES.values(),
+                         ids=HEAP_LAUNCHES.keys())
+def test_heap_runner_compiles_for_v5e(one_chip, shape):
+    """The heap scan at the benchmark's launch shapes: it compiles, its
+    loop copies nothing between HBM and vector memory, and its step input
+    is stacked over the padded rows."""
+    import re
+    R, G, K, NK, P, S, B = shape
+    sd = functools.partial(_sd, one_chip)
+    with jax.enable_x64(True):
+        tabs = {"npred": sd((R, G), jnp.int32), "c": sd((R, G), jnp.int32),
+                "is_comp": sd((R, G), jnp.bool_),
+                "k_first": sd((R, G), jnp.int32),
+                "opts": sd((R, G, K), jnp.int32),
+                "cost": sd((R, G, NK), jnp.int64),
+                "act": sd((R, G, NK), jnp.bool_),
+                "bad_row": sd((R, G), jnp.bool_),
+                "succ": sd((R, G, R // 32), jnp.uint32)}
+        compiled = jax.jit(_heap_runner()).lower(
+            tabs, sd((P, S, B), jnp.int64), sd((B, NK), jnp.int32),
+            sd((B,), jnp.int32), sd((B,), jnp.int32), sd((B,), jnp.int32),
+            sd((), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    body = re.search(r"while\([^)]*\), condition=%?[\w.\-]+, "
+                     r"body=%?([\w.\-]+)", hlo).group(1)
+    start = hlo.index(f"%{body} ")
+    loop = hlo[start:hlo.index("\n}\n", start)]
+    assert "copy-start" not in loop and "copy-done" not in loop
+    assert f"pred[{R},{B}]" in hlo
+    assert 0 < compiled.memory_analysis().argument_size_in_bytes < 1 << 30
 
 
 @pytest.mark.parametrize("P,S,B", [(4, 32, 64), (5, 8, 256)])
