@@ -230,9 +230,9 @@ def _sweep_rows(trace, reports, a9, count: int,
     * ``jaxc``        — same engine with 16-lane vmap-style chunking (the
       compile-cache-friendly bucket shape for very large sweeps).
     * ``jaxm``        — multi-graph megabatch (ISSUE 6,
-      ``jaxsim.simulate_jax_many``): every graph family of the sweep
-      padded along the task axis into **one** compiled scan, warm order
-      library + warm in-memory compile cache (steady state).
+      ``jaxsim.simulate_jax_many``): every lane of every graph family of
+      the sweep in **one** heap-scan launch (no order replayed), warm
+      in-memory compile cache (steady state).
     * ``jaxw``        — per-sweep warm path (ISSUE 10 satellite): fresh
       Explorers sharing a CompileCache whose memory tier a single
       priming sweep loaded from the warm DiskCache ``xla`` store.  Zero
@@ -282,25 +282,16 @@ def _sweep_rows(trace, reports, a9, count: int,
     mk(engine="jax", jax_megabatch=False).explore(cands)
     jax_compile_s = time.perf_counter() - t0
     mk(engine="jax", jax_megabatch=False, jax_chunk=16).explore(cands)
-    # megabatch warm-up: shared order library + disk-backed compile cache.
-    # Discoveries (run 1) and pins (run 2) change the lane routing — and
-    # with it the padded cohort structure XLA compiled — so loop until a
-    # run is discovery-free: from then on the routing, the shapes and the
-    # on-disk executable are the steady state a warm process reproduces.
+    # megabatch warm-up on a disk-backed compile cache: the heap launch's
+    # shapes follow from the sweep's groups alone, so one run compiles
+    # the steady state a warm process reproduces
     xla_dir = str(ARTIFACTS / "fig6_xlacache")
     jaxm_lib = ReplayLibrary()
     jaxm_cc = CompileCache(DiskCache(xla_dir))
-    jaxm_compile_s = 0.0
-    for i in range(5):
-        t0 = time.perf_counter()
-        exm = mk(engine="jax", order_library=jaxm_lib, compile_cache=jaxm_cc)
-        exm.explore(cands)
-        if i == 0:                      # one-off: compile + discoveries
-            jaxm_compile_s = time.perf_counter() - t0
-        s = exm.batch_stats.as_dict()
-        if s["diverged_lanes"] == 0 and s["reference_lanes"] == 0 \
-                and s["serial_fallback_lanes"] == 0:
-            break
+    t0 = time.perf_counter()
+    mk(engine="jax", order_library=jaxm_lib, compile_cache=jaxm_cc) \
+        .explore(cands)
+    jaxm_compile_s = time.perf_counter() - t0
     # prime the shared order library outside the timed rounds: one cold
     # discovery sweep records every lane's dispatch order + signature, so
     # the `batchw` rows measure a fully warm repeat sweep
@@ -476,7 +467,7 @@ def _sweep_rows(trace, reports, a9, count: int,
                  f"speedup={pr1_s / jaxm_s:.1f}x,"
                  f"vs_chunked={jaxm_vs_chunked:.2f}x,"
                  f"lockstep={mstats['lockstep_lanes']},"
-                 f"diverged={mstats['diverged_lanes']}"))
+                 f"heap={mstats['heap_lanes']}"))
     # warm-vs-cold-megabatch paired within a round: the regression this
     # re-gates was the warm path paying CompileCache deserialization per
     # sweep (1.66× *slower* than cold); warm now shares the memory tier

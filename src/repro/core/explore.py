@@ -932,9 +932,9 @@ class Explorer:
         two caps round down to a power of two, so the compiled width
         never exceeds the cap).  ``jax_megabatch`` (default on for the jax
         engine) routes each evaluation chunk's *whole* graph set through
-        one compiled scan (:func:`repro.core.jaxsim.simulate_jax_many`)
-        instead of one scan per graph family; with a ``cache_dir`` the
-        compiled executables also persist
+        one heap-scan launch (:func:`repro.core.jaxsim.simulate_jax_many`,
+        which replays no order) instead of one scan per graph family;
+        with a ``cache_dir`` the compiled executables also persist
         (:class:`~repro.core.xlacache.CompileCache`, DiskCache ``xla``
         namespace), so warm sweeps skip XLA compilation entirely.
         ``compile_cache`` shares an explicit
@@ -954,7 +954,9 @@ class Explorer:
         graph content hash + policy, so repeat sweeps and worker
         processes start warm.  ``max_rescue_rounds`` bounds the serial
         order discoveries per candidate group (see
-        :func:`repro.core.replay.replay_group`).
+        :func:`repro.core.replay.replay_group`).  The library and the
+        bound serve the batch engine and the per-graph jax path
+        (``jax_megabatch=False``); the megabatch uses neither.
 
         Fault tolerance (see docs/architecture.md "Failure model"):
         ``candidate_timeout`` is the per-candidate evaluation deadline —
@@ -2086,16 +2088,17 @@ class Explorer:
                             graph_info: Mapping[Tuple, Tuple],
                             results: List) -> List:
         """Every graph family of one evaluation chunk through a single
-        compiled scan (:func:`repro.core.jaxsim.simulate_jax_many`) —
+        heap-scan launch (:func:`repro.core.jaxsim.simulate_jax_many`) —
         one megabatch dispatch instead of one per-graph scan each, with
-        compiled executables shared via the Explorer's compile cache."""
+        compiled executables shared via the Explorer's compile cache.  It
+        replays no order, so the order library is neither read nor
+        written."""
         from .jaxsim import simulate_jax_many
         gkeys = list(pending)
         fams = []
         prunes: List[Optional[PruneContext]] = []
         for gkey in gkeys:
             payload = graph_info[gkey][0]
-            self._load_orders(payload)
             fam = [cand for _, cand, _, _, _ in pending[gkey]]
             fams.append((payload, [c.system for c in fam]))
             # one context per family, all sharing the live incumbent —
@@ -2104,7 +2107,6 @@ class Explorer:
         kw = {} if self.jax_chunk is None else {"chunk": self.jax_chunk}
         fam_sims = simulate_jax_many(
             fams, self.policy, stats=self.batch_stats,
-            library=self.order_library, max_rounds=self.max_rescue_rounds,
             compile_cache=self.compile_cache,
             prunes=prunes if any(p is not None for p in prunes) else None,
             **kw)

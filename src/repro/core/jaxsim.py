@@ -34,10 +34,9 @@ Invariants (shared with the numpy backend unless stated):
   ``prev_key`` per lane); lanes whose popped ``(ready_t, tie_break)`` keys
   ever violate it are flagged, their scan state is discarded, and they are
   re-simulated through :func:`~repro.core.fastsim.simulate_fast` — the
-  identical contract to the numpy backend — or, on the megabatch, where a
-  group has enough of them, completed in the **heap scan**
-  (:func:`_heap_runner`), a second lockstep scan in which each lane pops
-  its own heap and so needs no order.
+  identical contract to the numpy backend.  The megabatch replays no
+  order at all: it runs the **heap scan** (:func:`_heap_runner`), a
+  second lockstep scan in which each lane pops its own heap.
 * **Fixed-bucket lane chunking.**  Lanes are evaluated in chunks padded to
   power-of-two widths (``chunk`` caps the bucket — non-power-of-two caps
   round *down* to a power of two, so the compiled width never exceeds the
@@ -47,15 +46,12 @@ Invariants (shared with the numpy backend unless stated):
 Beyond the per-graph protocol, two mechanisms flip the engine's cold-start
 economics:
 
-* **Multi-graph megabatch** (:func:`simulate_jax_many`).  One scan serves
-  *every* graph family of a sweep at once: heterogeneous
-  ``(graph, order)`` cohorts are padded along the task axis to a shared
-  ``[T, G, ...]`` step-input block with per-step validity masks, each lane
-  carries its cohort index ``g``, and the step body gathers its row data
-  per lane.  A sweep whose graphs each batched 100 lanes through their own
-  compiled shapes now runs as one wide scan — one compile, no per-graph
-  remainder chunks.  The routing/discovery protocol around it is
-  :func:`repro.core.replay.simulate_many`.
+* **Multi-graph megabatch** (:func:`simulate_jax_many`).  One heap-scan
+  launch serves *every* graph family of a sweep at once: each family's
+  row tables are padded to a shared ``[R, G, ...]`` stack, each lane
+  carries its cohort index ``g``, and the step body reads its rows per
+  lane.  No order is discovered, replayed or validated; the routing
+  around it is :func:`repro.core.replay.simulate_many`.
 * **Persistent compile cache** (:class:`repro.core.xlacache.CompileCache`).
   The scan runner is compiled ahead-of-time per shape signature and the
   serialized executable persists in the sweep's DiskCache (``xla``
@@ -104,17 +100,9 @@ from ..testing import faults
 DEFAULT_CHUNK = 64
 
 #: Lane-bucket cap for the multi-graph megabatch: wider than the per-graph
-#: default because one scan now carries every cohort of the sweep, so the
-#: fixed per-scan overhead amortises over more lanes per launch.
+#: default because one heap launch carries every cohort of the sweep, so
+#: the fixed per-launch overhead amortises over more lanes.
 MEGABATCH_CHUNK = 256
-
-#: Megabatch slice working-set target, in f64 clock elements (``P×S×B``).
-#: The scan's per-step cost has two regimes: a fixed dispatch overhead per
-#: launch-step, and array traffic that scales with the clock block — and
-#: the traffic turns super-linear once the block spills L2.  Slices are
-#: therefore sized so ``P_max × S × B`` stays near this target (64 KiB of
-#: f64): wide lanes for narrow slot axes, narrow lanes for wide ones.
-TARGET_SLICE_ELEMS = 8192
 
 #: Heap-scan launch shapes (:func:`_heap_cohorts`): rows pad to a multiple
 #: of this, and lanes and cohorts bucket to powers of two no smaller than
@@ -266,7 +254,8 @@ def resolve_step_impl(step_impl: str) -> str:
 
 
 # ---------------------------------------------------------------------------
-# The compiled scan runner (one body serves per-graph and megabatch paths)
+# The compiled scan runners: the replay scan (the per-graph path) and the
+# heap scan (the megabatch)
 # ---------------------------------------------------------------------------
 
 
@@ -342,7 +331,7 @@ def _runner():
     whole array through vector memory every step.  :func:`_scan_cohorts`
     turns ``placed`` back into row placements on the host.
 
-    All shapes are megabatch-form and **lane-aligned**: step inputs ``xs``
+    All shapes are multi-cohort form and **lane-aligned**: step inputs ``xs``
     carry the lane axis directly (``[T, B, ...]`` — each lane's cohort
     rows pre-gathered on the host by :func:`_scan_cohorts`), and per-step
     ``valid`` masks make the task-axis padding inert.  Keeping the cohort
@@ -686,7 +675,7 @@ def _pool_caps(fg: FrozenGraph, kind_pool: Sequence[int],
 _XS_CACHE_CAP = 32
 
 # Lane-aligned device blocks, memoised across _scan_cohorts calls: keyed by
-# content (per-cohort graph hash × order × pool template), megabatch dims
+# content (per-cohort graph hash × order × pool template), launch dims
 # and the slice's cohort-index vector.  Entries are a few MB of device
 # arrays each; the cap bounds residency, LRU evicts.
 _DEV_XS_CACHE: "collections.OrderedDict[Tuple, Tuple]" = \
@@ -706,7 +695,7 @@ def _group_xs(fg: FrozenGraph, order: Sequence[int],
     ``placed`` slot of the latest earlier touch of the row / of its
     conditional parent (pad ``2T``); ``fin [n]``, each row's last touch
     over the whole order, which the host reads the final placements at.
-    The pads are remapped to the megabatch's by :func:`_scan_cohorts`.
+    The pads are remapped to the launch's by :func:`_scan_cohorts`.
 
     Memoised on the FrozenGraph like :func:`~repro.core.replay.graph_aux`
     (repeat sweeps — re-ranks, hillclimbs — replay the same order over the
@@ -854,7 +843,7 @@ def _times(a: np.ndarray) -> np.ndarray:
 
 def _repad(slots: np.ndarray, pad: int, to: int) -> np.ndarray:
     """A cohort's state positions with its own pad slot moved to the
-    megabatch's."""
+    launch's."""
     return np.where(slots == pad, to, slots)
 
 
@@ -864,7 +853,6 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                   policy: str, *, chunk: int,
                   compile_cache: Optional[CompileCache] = None,
                   step_impl: str = "auto",
-                  slot_bucketed: bool = False,
                   stats: Optional[BatchStats] = None
                   ) -> List[Tuple[Dict[int, SimResult], List[int],
                                   Dict[int, float]]]:
@@ -876,27 +864,11 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
     own length carry ``valid=False`` and read only the pad slots of the
     step-indexed state (predecessor step ``T_max``, placement slot
     ``2 T_max``), so they update nothing a lane reads; rows/pools/options
-    pad to the megabatch maxima with inert values (``-1`` options, pad
+    pad to the launch's maxima with inert values (``-1`` options, pad
     predecessors, ``inf`` clocks beyond a lane's slot count).  Lanes from
     *all* cohorts share the bucketed lane axis (``chunk`` caps the bucket;
-    padding lanes replicate the last real lane).
-
-    ``slot_bucketed=True`` (the megabatch path) additionally sorts lanes
-    by the slot count they actually need and compiles each slice with the
-    *narrowest* power-of-two slot axis covering it, instead of one global
-    slot axis sized to the widest lane of the sweep.  Per-step cost is
-    ``O(S × B)`` plus a fixed per-step dispatch overhead, so the slicer
-    optimises both terms: lanes pack greedily up to ``chunk``, and a new
-    slice only opens at a slot-bucket boundary once the current one holds
-    ``chunk/8`` lanes (small slot groups merge into their wider neighbour
-    rather than paying another scan launch — on a CPU backend the launch
-    count dominates).  On slot-count ramps (1..N accelerators — the
-    canonical co-design sweep) this cuts the scan work from
-    ``max_slots × n_lanes`` to roughly ``Σ slots_per_lane`` with only a
-    handful of compiled shapes, all persisted by the compile cache.  The
-    per-graph path keeps the single global slot axis: its cohorts come
-    pre-grouped by pool template, and one shape per chunk width keeps the
-    jit cache minimal.
+    padding lanes replicate the last real lane) and one slot axis sized
+    to the widest lane.
 
     Retirement on this engine is **post-scan classification**: the
     ``lax.scan`` trip count is fixed at trace time, so lanes cannot be
@@ -904,7 +876,7 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
     finite ``cutoffs`` entry has its non-diverged lanes whose *final*
     makespan exceeds the cutoff reported as retired (the makespan itself
     is the bound — exact, not an estimate).  Compiled-shape reuse and the
-    megabatch ``valid`` machinery are untouched; the win is
+    ``valid`` machinery are untouched; the win is
     protocol-level (retired lanes skip schedule materialisation and rank
     assembly), not scan-time.
 
@@ -994,7 +966,7 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 mega["own_cost"][:T, gi, :nk] = xs["own_cost"]
                 mega["par_cost"][:T, gi, :nk] = xs["par_cost"]
                 mega["act"][:T, gi, :nk] = xs["act"]
-                # each cohort's own pad slots (T, 2T) -> the megabatch's
+                # each cohort's own pad slots (T, 2T) -> the launch's
                 mega["pred"][:T, gi, :pc] = _repad(xs["pred"], T, T_max)
                 for f in ("at_own", "at_par"):
                     mega[f][:T, gi] = _repad(xs[f], 2 * T, 2 * T_max)
@@ -1018,37 +990,8 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
         retired: List[Dict[int, float]] = [{} for _ in per]
         # the effective power-of-two slice width
         step = _bucket(chunk, cap=chunk)
-
-        def _need(lane):
-            gi, pos = lane
-            return per[gi]["needs"][pos]
-
-        def _width(S_sl: int) -> int:
-            """Lane width keeping the slice's clock block near the cache
-            target: ``P_max × S_sl × width ≈ TARGET_SLICE_ELEMS``, floored
-            at 16 lanes and capped by ``chunk``."""
-            return max(16, min(step,
-                               _bucket(TARGET_SLICE_ELEMS // (P_max * S_sl),
-                                       cap=1 << 30)))
-
-        slices: List[Tuple[List[Tuple[int, int]], int]] = []
-        if slot_bucketed:
-            by_slots = sorted(lanes_flat, key=lambda t: (_need(t), t))
-            cur: List[Tuple[int, int]] = []
-            cur_S = 1
-            for lane in by_slots:
-                nb = max(cur_S, _bucket(_need(lane), cap=1 << 30))
-                if cur and len(cur) >= _width(nb):
-                    slices.append((cur, cur_S))
-                    cur, cur_S = [], 1
-                    nb = _bucket(_need(lane), cap=1 << 30)
-                cur.append(lane)
-                cur_S = nb
-            if cur:
-                slices.append((cur, cur_S))
-        else:
-            slices = [(lanes_flat[lo:lo + step], S)
-                      for lo in range(0, len(lanes_flat), step)]
+        slices = [lanes_flat[lo:lo + step]
+                  for lo in range(0, len(lanes_flat), step)]
 
     with jax.enable_x64(True):
         def _put(a: np.ndarray):
@@ -1082,7 +1025,7 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
             return hit
 
         launched = []
-        for sl, S_sl in slices:
+        for sl in slices:
             with span("jaxsim.prep"):
                 B = _bucket(len(sl), cap=chunk)
                 # pad lanes replicate the last real lane: finite,
@@ -1091,7 +1034,7 @@ def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
                 padded = sl + [sl[-1]] * (B - len(sl))
                 g_np = np.fromiter((gi for gi, _ in padded), dtype=np.int32,
                                    count=B)
-                clocks = np.full((P_max, S_sl, B), np.inf)
+                clocks = np.full((P_max, S, B), np.inf)
                 for li, (gi, pos) in enumerate(padded):
                     for p, cnt in enumerate(per[gi]["lane_counts"][pos]):
                         clocks[p, :cnt, li] = 0.0
@@ -1183,13 +1126,13 @@ def _heap_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[Layout],
     the heap scan (:func:`_heap_runner`), which needs no order.
 
     The launch's shapes come from ``universe``, every ``(fg, layouts)``
-    group of the sweep that could send lanes here (default: the cohorts
-    themselves): rows pad to the largest graph (a multiple of
-    :data:`HEAP_ROW_ALIGN`), pools, kinds and options to the largest, and
-    the slot axis to the widest lane's need (pool slot counts clamped to
-    the rows that could ever dispatch there, as :func:`_pool_caps`).  So
-    which lanes of a sweep diverge moves none of them; lanes and cohorts
-    bucket with floors (:data:`HEAP_MIN_LANES`, :data:`HEAP_MIN_COHORTS`).
+    group of the sweep that sends lanes here (default: the cohorts
+    themselves; prune seeds leave a cohort narrower than its group): rows
+    pad to the largest graph (a multiple of :data:`HEAP_ROW_ALIGN`),
+    pools, kinds and options to the largest, and the slot axis to the
+    widest lane's need (pool slot counts clamped to the rows that could
+    ever dispatch there, as :func:`_pool_caps`); lanes and cohorts bucket
+    with floors (:data:`HEAP_MIN_LANES`, :data:`HEAP_MIN_COHORTS`).
     Lanes of all cohorts share slices of at most ``chunk`` lanes.
 
     Returns one ``(done, flagged, retired)`` triple per cohort, positions
@@ -1404,40 +1347,28 @@ def simulate_jax_many(items: Sequence[Tuple[FrozenGraph,
                       min_lockstep: int = MIN_LOCKSTEP,
                       chunk: Optional[int] = None,
                       stats: Optional[BatchStats] = None,
-                      library: Optional[ReplayLibrary] = None,
-                      max_rounds: int = MAX_RESCUE_ROUNDS,
                       compile_cache: Optional[CompileCache] = None,
-                      step_impl: str = "auto",
                       prunes: Optional[Sequence[Optional[PruneContext]]]
                       = None) -> List[List[SimResult]]:
     """Multi-graph megabatch: every ``(graph, systems)`` family of a sweep
-    through **one** compiled scan.
+    through **one** heap-scan launch.
 
-    Per family the results match ``simulate_jax(fg, systems, ...)`` at the
-    same :data:`~repro.core.replay.JAX_RTOL` tier — routing, discovery and
-    the exact serial fallback are
-    :func:`repro.core.replay.simulate_many` — but heterogeneous graphs
-    share the lane axis (task-axis padding, host-side lane-aligned
-    pre-gather, slot-bucketed slices), so a sweep pays a handful of
-    compiles and no per-graph remainder chunks.  ``chunk`` defaults to
-    the wider :data:`MEGABATCH_CHUNK`.
+    Every lane of a pool-template group with at least ``min_lockstep``
+    lanes completes in the heap scan (:func:`_heap_cohorts`), bit-identical
+    to ``simulate_fast``; smaller groups and flagged lanes take the exact
+    serial path (:func:`repro.core.replay.simulate_many`).  No order is
+    discovered or replayed, so no ``ReplayLibrary`` is involved.
+    ``chunk`` caps the launch's lane bucket and defaults to the wider
+    :data:`MEGABATCH_CHUNK`.
     """
     chunk = MEGABATCH_CHUNK if chunk is None else chunk
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk!r}")
-    resolve_step_impl(step_impl)               # fail fast on bad names
-
-    def lockstep_many(cohorts):
-        return _scan_cohorts(cohorts, policy, chunk=chunk,
-                             compile_cache=compile_cache,
-                             step_impl=step_impl, slot_bucketed=True,
-                             stats=stats)
 
     def heap_many(cohorts, universe):
         return _heap_cohorts(cohorts, policy, chunk=chunk, universe=universe,
                              compile_cache=compile_cache, stats=stats)
 
-    return simulate_many(items, policy, lockstep_many_fn=lockstep_many,
-                         heap_many_fn=heap_many, min_lockstep=min_lockstep,
-                         stats=stats, library=library, max_rounds=max_rounds,
+    return simulate_many(items, policy, heap_many_fn=heap_many,
+                         min_lockstep=min_lockstep, stats=stats,
                          prunes=prunes)

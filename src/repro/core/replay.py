@@ -39,7 +39,9 @@ it.
 This module owns the protocol (grouping, order selection, rescue, fallback,
 per-lane result assembly, the per-graph auxiliary constants) so the two
 backends can never disagree on it; each backend supplies only the inner
-``lockstep_fn`` that advances the stacked per-candidate state.
+``lockstep_fn`` that advances the stacked per-candidate state.  The jax
+megabatch (:func:`simulate_many`) runs none of the replay protocol: every
+lane of a group large enough goes to the heap scan, which needs no order.
 
 It also owns the **engine equivalence tiers**: the exact engines
 (``fast``/``batch``) are pinned bit-identical to the reference object
@@ -130,21 +132,10 @@ LockstepFn = Callable[[FrozenGraph, Sequence[int], Sequence[Layout], str,
                        Optional[np.ndarray]],
                       Tuple[Dict[int, SimResult], List[int],
                             Dict[int, float]]]
-# One megabatch cohort: every lane replays `order` over `fg` (the lanes
-# share a pool template; slot counts vary per layout); the last element is
-# the per-lane cutoff array (or None — no pruning for this cohort).
-CohortSpec = Tuple[FrozenGraph, Tuple[int, ...], List[Layout],
-                   Optional[np.ndarray]]
-# A backend's megabatch sweep: all cohorts advance through ONE backend
-# call; one (done, diverged, retired) triple per cohort, in the LockstepFn
-# contract.
-LockstepManyFn = Callable[[Sequence[CohortSpec]],
-                          List[Tuple[Dict[int, SimResult], List[int],
-                                     Dict[int, float]]]]
 # A backend's heap scan: every lane of every ``(fg, layouts, cutoffs)``
 # cohort completed with no order, in one call; the second argument is every
-# ``(fg, layouts)`` group of the sweep that could send lanes there (it fixes
-# the launch's shapes).  One (done, flagged, retired) triple per cohort:
+# ``(fg, layouts)`` group of the sweep with enough lanes for the launch (it
+# fixes the launch's shapes).  One (done, flagged, retired) triple per cohort:
 # ``flagged`` lanes met a row the reference raises on and take the exact
 # path.
 HeapManyFn = Callable[[Sequence[Tuple[FrozenGraph, List[Layout],
@@ -166,7 +157,8 @@ class BatchStats:
     exact path *and recorded their order* into the library (the initial
     reference plus every rescue discovery); ``serial_fallback_lanes``
     ran serially with nothing recorded (rounds/library budget spent —
-    the cost the library exists to eliminate); ``small_group_lanes`` never
+    the cost the library exists to eliminate — or, in the megabatch, a
+    prune seed or a lane the heap scan flagged); ``small_group_lanes`` never
     entered the protocol (group below ``min_lockstep``).
 
     Event counters (overlapping the above): ``diverged_lanes`` counts
@@ -177,7 +169,8 @@ class BatchStats:
     figure of merit); ``heap_lanes`` counts lanes completed in the heap
     scan, the device scan in which each lane pops its own heap, so that
     no order has to serve it (counted in ``lockstep_lanes`` too, as
-    ``rescued_lanes`` are).
+    ``rescued_lanes`` are).  The jax megabatch sends every lane of its
+    groups there and replays no order (:func:`simulate_many`).
 
     Retirement counters (branch-and-bound pruning fused into the sweep):
     ``retired_lanes`` counts lanes retired mid-sweep because their
@@ -1053,12 +1046,9 @@ def replay_group(fg: FrozenGraph, systems: Sequence[SystemConfig],
 
 def simulate_many(items: Sequence[Tuple[FrozenGraph,
                                         Sequence[SystemConfig]]],
-                  policy: str, *, lockstep_many_fn: LockstepManyFn,
-                  heap_many_fn: HeapManyFn,
+                  policy: str, *, heap_many_fn: HeapManyFn,
                   min_lockstep: int = MIN_LOCKSTEP,
                   stats: Optional[BatchStats] = None,
-                  library: Optional[ReplayLibrary] = None,
-                  max_rounds: int = MAX_RESCUE_ROUNDS,
                   schedule_free: bool = True,
                   prunes: Optional[Sequence[Optional[PruneContext]]] = None
                   ) -> List[List[Union[SimResult, Retired]]]:
@@ -1066,53 +1056,38 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
     call — the megabatch form of :func:`simulate_grouped`.
 
     :func:`simulate_grouped` hands each pool-template group of each graph
-    to its own ``lockstep_fn`` call, so a sweep over many graphs pays one
-    compiled sweep (and its remainder chunks) per group.  This protocol
-    instead *plans* every group of every family up front — the same
-    library routing as :func:`replay_group` phase 1, with the cheapest
-    possible phase-2/3 stand-ins — and dispatches all resulting
-    ``(fg, order, lanes)`` cohorts in a single ``lockstep_many_fn`` call,
-    letting a megabatch-capable backend (``jaxsim._scan_cohorts``) pad the
-    cohorts together and share one compiled scan across the whole sweep.
+    to its own ``lockstep_fn`` call and replays library orders there.  The
+    megabatch replays nothing: every lane of every group with at least
+    ``min_lockstep`` lanes goes to **one** ``heap_many_fn`` call for the
+    whole sweep (``jaxsim._heap_cohorts``: a lockstep scan in which each
+    lane pops its own heap, so it needs no order, no discovery and no
+    validation).  Heap lanes count as ``lockstep_lanes`` (and
+    ``heap_lanes``); ``reference_lanes``, ``diverged_lanes`` and
+    ``order_hits`` stay 0.  ``heap_many_fn`` gets the heap cohorts
+    ``(fg, layouts, cutoffs)`` and every group of the sweep with at least
+    ``min_lockstep`` lanes, which fixes the launch's shapes.
 
-    Protocol differences vs the per-group path, by design:
+    Groups below ``min_lockstep`` take the exact serial path
+    (``small_group_lanes``), and so do the lanes the heap scan flags (a
+    row the reference raises on: ``serial_fallback_lanes``).  Nothing is
+    read from or written to a :class:`ReplayLibrary`: the library stays
+    with the batch engine and the per-graph jax path.
 
-    * Groups with no cached orders run **one** serial reference discovery
-      (their most-parallel lane, order recorded) and route the rest of the
-      group to that fresh order *within the same megabatch* — phase 3's
-      first re-batch, folded into the main sweep.
-    * Unrouted lanes with cached orders try position 0 only (phase 2's
-      first trial); there is **no rescue re-batching**.
-    * The lanes that no order serves — diverged in the replay launch,
-      pinned signatures, and fallbacks past the discovery budget or a
-      full library key — go to **one** ``heap_many_fn`` call for the
-      whole sweep after the replay launch (``jaxsim._heap_cohorts``: a
-      lockstep scan in which each lane pops its own heap, so it needs no
-      order).  Heap lanes count as ``lockstep_lanes`` (and
-      ``heap_lanes``).  They write nothing to the library, which other
-      engines share: a pin there sends a signature to the exact serial
-      path of every engine.  ``heap_many_fn`` gets the heap cohorts
-      ``(fg, layouts, cutoffs)`` and every group of the sweep that could
-      send lanes there, which fixes the launch's shapes.
-    * A group with fewer than ``min_lockstep`` such lanes keeps the exact
-      serial path: a diverged lane is discovered serially (order +
-      signature recorded, bounded by ``max_rounds`` per group, so the
-      *next* sweep routes it) or falls back serially.
-      ``rescued_lanes`` is never counted here.
-
-    Every completion is still either a validated lockstep lane, a heap
-    lane (bit-identical by construction) or an exact serial run, so the
-    engine tiers are preserved.  Returns one result list per family, each
-    in its ``systems`` order.
+    Every completion is a heap lane (bit-identical by construction) or an
+    exact serial run, so the engine tiers are preserved.  Returns one
+    result list per family, each in its ``systems`` order.
 
     ``prunes`` carries one optional :class:`PruneContext` per family
-    (sharing a live :class:`Incumbent` across them); cohorts then ship
-    per-lane cutoffs into the megabatch dispatch, and retired lanes come
-    back as :class:`Retired` markers exactly as in :func:`replay_group`.
+    (sharing a live :class:`Incumbent` across them).  While the incumbent
+    still needs completions, each group first runs that many of its
+    most-parallel lanes serially (phase-0 seeding, as in
+    :func:`replay_group`, with nothing recorded: ``serial_fallback_lanes``)
+    so the launch starts with a finite cutoff; the heap cohorts then ship
+    per-lane cutoffs, and retired lanes come back as :class:`Retired`
+    markers exactly as in :func:`replay_group`.
     """
     if policy not in ("availability", "eft"):
         raise ValueError(f"unknown policy {policy!r}")
-    lib = library if library is not None else ReplayLibrary()
     with_schedule = not schedule_free
     results: List[List[Optional[Union[SimResult, Retired]]]] = \
         [[None] * len(systems) for _fg, systems in items]
@@ -1120,13 +1095,12 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
     def pr_of(gi: int) -> Optional[PruneContext]:
         return prunes[gi] if prunes is not None else None
 
-    def serial(gi: int, i: int, out: Optional[List[int]] = None
-               ) -> Union[SimResult, Retired]:
+    def serial(gi: int, i: int) -> Union[SimResult, Retired]:
         fg, systems = items[gi]
         pr = pr_of(gi)
         with span("replay.exact"):
             res = _serial_sim(fg, systems[i], policy, pr, i,
-                              with_schedule=with_schedule, order_out=out)
+                              with_schedule=with_schedule)
         if isinstance(res, Retired):
             if stats is not None:
                 stats.retired_lanes += 1
@@ -1134,47 +1108,15 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
             pr.offer(systems[i].name, res.makespan)
         return res
 
-    def pinned_serial(gi: int, i: int, hit: bool) -> None:
-        results[gi][i] = serial(gi, i)
-        if stats is not None and not isinstance(results[gi][i], Retired):
-            stats.order_pinned_lanes += 1
-            if hit:
-                stats.order_hits += 1
-
     def fallback_serial(gi: int, i: int) -> None:
         results[gi][i] = serial(gi, i)
         if stats is not None and not isinstance(results[gi][i], Retired):
             stats.serial_fallback_lanes += 1
 
-    def discover(grp: Dict, i: int, position: int) -> None:
-        """A diverged lane's serial discovery: its own order is recorded
-        so the next sweep routes it (no rescue re-batch here)."""
-        gi, key = grp["gi"], grp["key"]
-        sig = tuple(grp["layouts"][i][1])
-        if grp["discoveries"] >= max_rounds:
-            fallback_serial(gi, i)
-            return
-        out2: List[int] = []
-        results[gi][i] = serial(gi, i, out2)
-        grp["discoveries"] += 1
-        if isinstance(results[gi][i], Retired):
-            return                              # partial order: never recorded
-        pos2 = lib.record(key, out2, sig)
-        if pos2 is None:
-            if stats is not None:
-                stats.serial_fallback_lanes += 1
-            return
-        if pos2 == position:
-            # its own recorded order is the one it just failed: provably a
-            # conservative false positive — pin it
-            lib.pin_sig(key, sig)
-        if stats is not None:
-            stats.reference_lanes += 1
-
-    # ---- plan: route every group's lanes to (order, cohort) ------------
+    # ---- plan: every group of min_lockstep lanes to the heap launch ----
     with span("replay.plan"):
-        cohorts: List[Dict] = []
-        groups: List[Dict] = []
+        # (family, the group's lanes, their layouts) of each heap cohort
+        heap: List[Tuple[int, List[int], List[Layout]]] = []
         universe: List[Tuple[FrozenGraph, List[Layout]]] = []
         for gi, (fg, systems) in enumerate(items):
             layouts = [pool_layout(fg.kinds, s) for s in systems]
@@ -1190,188 +1132,52 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
                     if stats is not None:
                         stats.small_group_lanes += len(lanes)
                     continue
-                key = lib.key(fg, layouts[lanes[0]], policy)
                 universe.append((fg, [layouts[i] for i in lanes]))
                 pr = pr_of(gi)
-                if pr is not None and pr.deficit():
-                    # phase-0 incumbent seeding, as in replay_group: the most-
-                    # parallel lanes run serially (orders recorded) so the
-                    # megabatch launches with a finite cutoff
+                need = pr.deficit() if pr is not None else 0
+                if need:
+                    # phase-0 incumbent seeding: the most-parallel lanes
+                    # (the likeliest winners) run serially first, so the
+                    # launch carries a finite cutoff
                     seeds = sorted(lanes, key=lambda i: (-sum(layouts[i][1]),
-                                                         i))[:pr.deficit()]
+                                                         i))[:need]
                     for i in seeds:
-                        out0: List[int] = []
-                        results[gi][i] = serial(gi, i, out0)
-                        if isinstance(results[gi][i], Retired):
-                            continue            # partial order: never recorded
-                        pos0 = lib.record(key, out0, tuple(layouts[i][1]))
-                        if stats is not None:
-                            if pos0 is None:
-                                stats.serial_fallback_lanes += 1
-                            else:
-                                stats.reference_lanes += 1
+                        fallback_serial(gi, i)
                     taken = set(seeds)
                     lanes = [i for i in lanes if i not in taken]
                     if not lanes:
                         continue
-                orders, sig_map, pins = lib.lookup(key)
-                # "heap": (lane, why, replay position) of the lanes no
-                # order serves, for the heap launch
-                grp = {"gi": gi, "fg": fg, "key": key, "layouts": layouts,
-                       "n_cached": len(orders), "discoveries": 0,
-                       "heap": []}
-                groups.append(grp)
-                order_by_pos: Dict[int, Tuple[int, ...]] = \
-                    dict(enumerate(orders))
-                routed: Dict[int, List[int]] = {}
-                unrouted: List[int] = []
-                for i in lanes:
-                    sig = tuple(layouts[i][1])
-                    if sig in pins:
-                        grp["heap"].append((i, "pinned", None))
-                        continue
-                    pos = sig_map.get(sig)
-                    if pos is not None and 0 <= pos < len(orders):
-                        routed.setdefault(pos, []).append(i)
-                    else:
-                        unrouted.append(i)
-                fallbacks: List[int] = []
-                if unrouted and not orders:
-                    # cold group: one serial reference discovery (the
-                    # most-parallel lane), everyone else rides its fresh
-                    # order in the megabatch — replay_group's reference
-                    # sweep folded into the main dispatch
-                    if max_rounds <= 0:
-                        fallbacks, unrouted = unrouted, []
-                    else:
-                        j = max(unrouted,
-                                key=lambda i: (sum(layouts[i][1]), i))
-                        unrouted.remove(j)
-                        out: List[int] = []
-                        results[gi][j] = serial(gi, j, out)
-                        grp["discoveries"] += 1
-                        if isinstance(results[gi][j], Retired):
-                            # the group's likeliest winner is already
-                            # beaten: no order to ride — the rest go
-                            # serial, where the same cutoff aborts them
-                            # just as fast
-                            pos = None
-                        else:
-                            pos = lib.record(key, out, tuple(layouts[j][1]))
-                            if stats is not None:
-                                if pos is None:
-                                    stats.serial_fallback_lanes += 1
-                                else:
-                                    stats.reference_lanes += 1
-                        if pos is None:         # key full (shared library)
-                            fallbacks, unrouted = unrouted, []
-                        else:
-                            order_by_pos[pos] = tuple(out)
-                            routed.setdefault(pos, []).extend(unrouted)
-                            unrouted = []
-                elif unrouted:
-                    # untried signatures take the insertion-order first order
-                    # (the original reference), like phase 2's first trial
-                    routed.setdefault(0, []).extend(unrouted)
-                grp["heap"].extend((i, "fallback", None) for i in fallbacks)
-                for pos, cl in routed.items():
-                    cohorts.append({"grp": grp, "position": pos,
-                                    "order": order_by_pos[pos], "lanes": cl})
+                heap.append((gi, lanes, [layouts[i] for i in lanes]))
 
-        # A megabatch below min_lockstep is a doomed sweep (the same economics
-        # as replay_group's thin routed cohorts): route its lanes straight to
-        # the exact serial path instead.
-        if cohorts and sum(len(c["lanes"]) for c in cohorts) < min_lockstep:
-            for c in cohorts:
-                grp = c["grp"]
-                for i in c["lanes"]:
-                    pinned_serial(grp["gi"], i,
-                                  hit=c["position"] < grp["n_cached"])
-            cohorts = []
-
-    # ---- one megabatch dispatch for every cohort of every family -------
-    if cohorts:
-        outs = lockstep_many_fn(
-            [(c["grp"]["fg"], c["order"],
-              [c["grp"]["layouts"][i] for i in c["lanes"]],
-              None if pr_of(c["grp"]["gi"]) is None
-              else pr_of(c["grp"]["gi"]).cutoffs(c["lanes"]))
-             for c in cohorts])
-        with span("replay.assemble"):
-            for c, (done, diverged, retired) in zip(cohorts, outs):
-                grp = c["grp"]
-                gi, key, layouts = grp["gi"], grp["key"], grp["layouts"]
-                systems = items[gi][1]
-                pr = pr_of(gi)
-                from_cache = c["position"] < grp["n_cached"]
-                for pos_l, bound in retired.items():
-                    results[gi][c["lanes"][pos_l]] = Retired(float(bound))
-                if stats is not None and retired:
-                    stats.retired_lanes += len(retired)
-                    stats.retire_sweeps += 1
-                for pos_l, sim in done.items():
-                    i = c["lanes"][pos_l]
-                    results[gi][i] = dataclasses.replace(
-                        sim, system=systems[i].name)
-                    lib.map_sig(key, tuple(layouts[i][1]), c["position"])
-                    if pr is not None:
-                        pr.offer(systems[i].name, sim.makespan)
-                    if stats is not None:
-                        stats.lockstep_lanes += 1
-                        if from_cache:
-                            stats.order_hits += 1
-                for pos_l in diverged:
-                    i = c["lanes"][pos_l]
-                    if stats is not None:
-                        stats.diverged_lanes += 1
-                    grp["heap"].append((i, "diverged", c["position"]))
-
-    # ---- one heap launch for the lanes no order serves -----------------
-    heap_grps = [grp for grp in groups
-                 if len(grp["heap"]) >= min_lockstep]
-    thin = [grp for grp in groups
-            if 0 < len(grp["heap"]) < min_lockstep]
-    if thin:
-        # too few for a launch: the exact serial path
-        with span("replay.assemble"):
-            for grp in thin:
-                for i, why, position in grp["heap"]:
-                    if why == "pinned":
-                        pinned_serial(grp["gi"], i, hit=True)
-                    elif why == "fallback":
-                        fallback_serial(grp["gi"], i)
-                    else:
-                        discover(grp, i, position)
-    if heap_grps:
-        outs = heap_many_fn(
-            [(grp["fg"], [grp["layouts"][i] for i, _, _ in grp["heap"]],
-              None if pr_of(grp["gi"]) is None
-              else pr_of(grp["gi"]).cutoffs(
-                  [i for i, _, _ in grp["heap"]]))
-             for grp in heap_grps], universe)
-        with span("replay.assemble"):
-            for grp, (done, flagged, retired) in zip(heap_grps, outs):
-                gi, systems = grp["gi"], items[grp["gi"]][1]
-                pr = pr_of(gi)
-                lanes = [i for i, _, _ in grp["heap"]]
-                for pos_l, bound in retired.items():
-                    results[gi][lanes[pos_l]] = Retired(float(bound))
-                if stats is not None and retired:
-                    stats.retired_lanes += len(retired)
-                    stats.retire_sweeps += 1
-                for pos_l, sim in done.items():
-                    i = lanes[pos_l]
-                    results[gi][i] = dataclasses.replace(
-                        sim, system=systems[i].name)
-                    if pr is not None:
-                        pr.offer(systems[i].name, sim.makespan)
-                    if stats is not None:
-                        stats.lockstep_lanes += 1
-                        stats.heap_lanes += 1
-                for pos_l in flagged:
-                    # a row the reference raises on: the exact path
-                    # raises it too, or completes the lane
-                    fallback_serial(gi, lanes[pos_l])
+    # ---- one heap launch for every group of every family ---------------
+    if not heap:
+        return results  # type: ignore[return-value]
+    outs = heap_many_fn(
+        [(items[gi][0], lays,
+          None if pr_of(gi) is None else pr_of(gi).cutoffs(lanes))
+         for gi, lanes, lays in heap], universe)
+    with span("replay.assemble"):
+        for (gi, lanes, _lays), (done, flagged, retired) in zip(heap, outs):
+            systems = items[gi][1]
+            pr = pr_of(gi)
+            for pos_l, bound in retired.items():
+                results[gi][lanes[pos_l]] = Retired(float(bound))
+            if stats is not None and retired:
+                stats.retired_lanes += len(retired)
+                stats.retire_sweeps += 1
+            for pos_l, sim in done.items():
+                i = lanes[pos_l]
+                results[gi][i] = dataclasses.replace(sim,
+                                                     system=systems[i].name)
+                if pr is not None:
+                    pr.offer(systems[i].name, sim.makespan)
+                if stats is not None:
+                    stats.lockstep_lanes += 1
+                    stats.heap_lanes += 1
+            for pos_l in flagged:
+                # a row the reference raises on: the exact path raises it
+                # too, or completes the lane
+                fallback_serial(gi, lanes[pos_l])
     return results  # type: ignore[return-value]
 
 

@@ -6,9 +6,11 @@ lockstep scan: each step pops the lane's minimum of ``(ready time,
 creation index, uid)`` over its ready rows, exactly the reference's heap,
 so it needs no order and no validation.  This file pins it bit for bit to
 ``simulate_fast`` on seeded random graphs, checks that
-``replay.simulate_many`` routes the lanes no order serves there (and only
-groups with at least ``min_lockstep`` of them), and that a sweep through
-``Explorer`` ranks the paper's applications identically on every engine.
+``replay.simulate_many`` routes every lane of a jax megabatch sweep there
+(every group with at least ``min_lockstep`` lanes; smaller groups take the
+exact path) with no discovery, no replay launch and no library, and that a
+sweep through ``Explorer`` ranks the paper's applications identically on
+every engine.
 """
 import random
 
@@ -27,7 +29,10 @@ from repro.core.fastsim import FrozenGraph, pool_layout, simulate_fast
 from repro.core.hlsreport import HLSSynthesisModel
 from repro.core.jaxsim import (HEAP_MIN_LANES, HEAP_ROW_ALIGN, _heap_cohorts,
                                _heap_runner, simulate_jax_many)
-from repro.core.replay import BatchStats, ReplayLibrary
+from repro.core.batchsim import simulate_batch
+from repro.core.replay import (JAX_RTOL, BatchStats, Incumbent, PruneContext,
+                               ReplayLibrary, Retired, sims_equivalent)
+from repro.core.simulator import Simulator
 from repro.core.taskgraph import Task, TaskGraph
 from repro.testing import faults
 from repro.testing.synth import frozen_for, synth_trace
@@ -237,7 +242,7 @@ def test_heap_scan_module_name_and_dense_state_writes():
 
 
 # ---------------------------------------------------------------------------
-# routing: replay.simulate_many sends the lanes no order serves to the heap
+# routing: replay.simulate_many sends every lane of a large group to the heap
 # ---------------------------------------------------------------------------
 
 
@@ -247,75 +252,96 @@ def _ramp_items():
     return [(fg1, _ramp(range(1, 25), "a")), (fg2, _ramp(range(1, 13), "b"))]
 
 
-@pytest.mark.parametrize("min_lockstep", [2, 4, 64])
+def _group_sizes(items):
+    """The lanes of each pool-template group, per family."""
+    sizes = []
+    for fg, systems in items:
+        groups = {}
+        for s in systems:
+            names, _, kp = pool_layout(fg.kinds, s)
+            groups[(tuple(names), tuple(kp))] = \
+                groups.get((tuple(names), tuple(kp)), 0) + 1
+        sizes.extend(groups.values())
+    return sizes
+
+
+def _no_replay_launch(monkeypatch):
+    """Make a replay-scan (``jit_run``) launch fail the test."""
+    from repro.core import jaxsim
+
+    def refuse(*_a, **_k):
+        raise AssertionError("the megabatch launched the replay scan")
+    monkeypatch.setattr(jaxsim, "_load_runner", refuse)
+
+
+def _assert_equals_batch(items, res, policy):
+    """Every lane's result equals the batch engine's bit for bit."""
+    for (fg, systems), sims in zip(items, res):
+        ref = simulate_batch(fg, systems, policy, min_lockstep=2)
+        for system, sim, want in zip(systems, sims, ref):
+            assert (sim.makespan, sim.busy, sim.placements) == \
+                (want.makespan, want.busy, want.placements), system.name
+
+
+@pytest.mark.parametrize("min_lockstep", [2, 4, 13, 64])
 def test_routing_sends_groups_with_enough_unserved_lanes_to_the_heap(
-        min_lockstep):
-    """Diverged lanes of a group with at least ``min_lockstep`` of them
-    complete in the heap scan (counted as lockstep lanes); a thinner
-    group keeps the serial discovery path.  Every lane is classified once
-    and every result equals ``simulate_fast``'s."""
+        min_lockstep, monkeypatch):
+    """Every lane of a group with at least ``min_lockstep`` lanes
+    completes in the heap scan (counted as a lockstep lane), with no
+    discovery, no replay launch and no divergence; a smaller group takes
+    the exact serial path.  Every lane is classified once and every result
+    equals the batch engine's."""
+    _no_replay_launch(monkeypatch)
     items = _ramp_items()
     stats = BatchStats()
     res = simulate_jax_many(items, "availability", min_lockstep=min_lockstep,
                             stats=stats)
     n_lanes = sum(len(s) for _, s in items)
-    assert (stats.lockstep_lanes + stats.order_pinned_lanes
-            + stats.reference_lanes + stats.serial_fallback_lanes
-            + stats.small_group_lanes) == n_lanes
-    if min_lockstep == 64:
-        assert stats.small_group_lanes == n_lanes and stats.heap_lanes == 0
-    elif min_lockstep == 2:
-        assert stats.diverged_lanes > 0
-        assert stats.heap_lanes == stats.diverged_lanes
-        assert stats.reference_lanes == 2         # the two cold discoveries
-        assert stats.heap_lane_steps > 0 and stats.heap_h2d_bytes > 0
-    else:
-        # the groups' diverged lanes are fewer than min_lockstep: exact path
-        assert 0 < stats.diverged_lanes
-        assert stats.heap_lanes == 0 and stats.heap_lane_steps == 0
-        assert stats.reference_lanes == 2 + stats.diverged_lanes
-    assert stats.heap_lanes <= stats.lockstep_lanes
+    big = sum(n for n in _group_sizes(items) if n >= min_lockstep)
+    assert stats.heap_lanes == stats.lockstep_lanes == big
+    assert stats.small_group_lanes == n_lanes - big
+    assert stats.reference_lanes == stats.diverged_lanes == 0
+    assert stats.order_hits == stats.order_pinned_lanes == 0
     assert stats.serial_fallback_lanes == 0
-    for (fg, systems), sims in zip(items, res):
-        for system, sim in zip(systems, sims):
-            ref = simulate_fast(fg, system, "availability")
-            assert (sim.makespan, sim.busy, sim.placements) == \
-                (ref.makespan, ref.busy, ref.placements), system.name
+    assert stats.scan_lane_steps == stats.h2d_bytes == 0
+    assert (stats.heap_lane_steps > 0) == (big > 0)
+    _assert_equals_batch(items, res, "availability")
 
 
-def test_heap_lanes_write_nothing_to_the_library():
-    """A heap lane records no order and no pin: the library stays as the
-    replay launch and the discoveries left it, so a warm sweep replays
-    the lane again (it diverges as before) and completes it in the heap
-    scan, with nothing run serially."""
-    items = _ramp_items()
+def test_heap_lanes_write_nothing_to_the_library(monkeypatch):
+    """A jax megabatch sweep neither reads nor writes the order library:
+    cold, it leaves the library empty; warmed by the batch engine, it
+    leaves it as it was and routes no lane by it.  Either way every lane
+    completes in the heap scan and ranks as the batch engine does."""
+    trace, reports, cands, smp_fn = _matmul(256, 64, range(1, 9))
+    kw = dict(smp_seconds_fn=smp_fn, budget={})
     lib = ReplayLibrary()
-    cold = BatchStats()
-    simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
-                      stats=cold)
-    assert cold.heap_lanes > 0
-    assert lib.counts()["orders"] == cold.reference_lanes
-    for fg, systems in items:
-        for s in systems:
-            layout = pool_layout(fg.kinds, s)
-            assert not lib.lookup(ReplayLibrary.key(fg, layout,
-                                                    "availability"))[2]
-    warm = BatchStats()
-    simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
-                      stats=warm)
-    assert warm.reference_lanes == 0
-    assert warm.serial_fallback_lanes == warm.order_pinned_lanes == 0
-    assert warm.diverged_lanes == warm.heap_lanes == cold.heap_lanes
-    assert warm.lockstep_lanes == sum(len(s) for _, s in items)
+    cold = Explorer(trace, reports, engine="jax", order_library=lib, **kw)
+    _no_replay_launch(monkeypatch)
+    got_cold = cold.explore(cands)
+    assert len(lib) == 0 and lib.counts()["orders"] == 0
+    batch = Explorer(trace, reports, engine="batch", order_library=lib, **kw)
+    ref = batch.explore(cands)
+    warmed = lib.counts()
+    assert warmed["orders"] > 0
+    warm = Explorer(trace, reports, engine="jax", order_library=lib, **kw)
+    got_warm = warm.explore(cands)
+    assert lib.counts() == warmed
+    for ex in (cold, warm):
+        st = ex.batch_stats
+        assert st.heap_lanes == st.lockstep_lanes == len(cands)
+        assert st.reference_lanes == st.diverged_lanes == 0
+        assert st.order_hits == st.order_pinned_lanes == 0
+    for got in (got_cold, got_warm):
+        assert [(o.name, o.makespan_s) for o in got.ranked] == \
+            [(o.name, o.makespan_s) for o in ref.ranked]
 
 
 def test_a_jax_sweep_pins_nothing_for_the_batch_engine():
     """The library is shared across engines: after a jax megabatch sweep
-    whose lanes completed in the heap scan, the library holds no pin, so
-    a batch sweep on it sends to the exact path only lanes whose own
-    order the jax sweep recorded (``order_pinned_lanes`` also counts a
-    routed cohort thinner than ``min_lockstep``), and ranks as a batch
-    sweep on a fresh library does."""
+    the library holds no order and no pin, so a batch sweep on it runs as
+    a batch sweep on a fresh library does, count for count, and ranks the
+    same."""
     trace, reports, cands, smp_fn = _matmul(256, 64, range(1, 9))
     lib = ReplayLibrary()
     jx = Explorer(trace, reports, engine="jax", smp_seconds_fn=smp_fn,
@@ -330,25 +356,103 @@ def test_a_jax_sweep_pins_nothing_for_the_batch_engine():
     fresh = Explorer(trace, reports, engine="batch", smp_seconds_fn=smp_fn,
                      budget={})
     ref = fresh.explore(cands)
-    assert shared.batch_stats.order_pinned_lanes <= \
-        jx.batch_stats.reference_lanes < jx.batch_stats.heap_lanes
+    assert shared.batch_stats.order_pinned_lanes == \
+        fresh.batch_stats.order_pinned_lanes
+    assert shared.batch_stats.reference_lanes == \
+        fresh.batch_stats.reference_lanes
     assert [(o.name, o.makespan_s) for o in res.ranked] == \
         [(o.name, o.makespan_s) for o in ref.ranked]
 
 
-def test_over_budget_fallbacks_take_the_heap():
-    """With no discovery budget (``max_rounds=0``) a cold group has no
-    order at all: every lane completes in the heap scan."""
+def test_over_budget_fallbacks_take_the_heap(monkeypatch):
+    """The discovery budget bounds only the library protocol: a jax
+    megabatch sweep with no budget (``max_rescue_rounds=0``) routes as one
+    with the default budget, every lane to the heap scan, nothing serial,
+    and ranks as the batch engine does under ``eft``."""
+    _no_replay_launch(monkeypatch)
+    trace, reports, cands, smp_fn = _matmul(256, 64, range(1, 9))
+    kw = dict(smp_seconds_fn=smp_fn, budget={}, policy="eft")
+    ref = Explorer(trace, reports, engine="batch", **kw).explore(cands)
+    counts = []
+    for rounds in (0, 32):
+        ex = Explorer(trace, reports, engine="jax", max_rescue_rounds=rounds,
+                      **kw)
+        res = ex.explore(cands)
+        st = ex.batch_stats
+        assert st.heap_lanes == len(cands)
+        assert st.reference_lanes == st.serial_fallback_lanes == 0
+        assert st.scan_lane_steps == 0                 # no replay launch
+        counts.append(st.as_dict())
+        assert [(o.name, o.makespan_s) for o in res.ranked] == \
+            [(o.name, o.makespan_s) for o in ref.ranked]
+    assert counts[0] == counts[1]
+
+
+def test_prune_megabatch_seeds_serially_then_retires_heap_lanes_exactly():
+    """Prune mode: while the incumbent needs completions, the group's
+    most-parallel lanes run serially first (nothing recorded); every other
+    lane goes to the heap launch with the live cutoff, and retires exactly
+    when its exact makespan exceeds it, with that makespan as its bound.
+    The top-k equals the unpruned sweep's."""
     items = _ramp_items()
+    k = 3
+    inc = Incumbent(k)
     stats = BatchStats()
-    res = simulate_jax_many(items, "eft", min_lockstep=2, max_rounds=0,
-                            stats=stats)
-    assert stats.heap_lanes == sum(len(s) for _, s in items)
-    assert stats.reference_lanes == stats.serial_fallback_lanes == 0
-    assert stats.scan_lane_steps == 0               # no replay launch
-    for (fg, systems), sims in zip(items, res):
+    res = simulate_jax_many(
+        items, "availability", min_lockstep=2, stats=stats,
+        prunes=[PruneContext(inc, tolerance=JAX_RTOL) for _ in items])
+    n_lanes = sum(len(s) for _, s in items)
+    fg1, sys1 = items[0]
+    seeds = sorted(range(len(sys1)),
+                   key=lambda i: (-sum(pool_layout(fg1.kinds,
+                                                   sys1[i])[1]), i))[:k]
+    assert stats.serial_fallback_lanes == k
+    assert stats.reference_lanes == stats.diverged_lanes == 0
+    assert stats.heap_lanes + stats.retired_lanes == n_lanes - k
+    assert stats.retired_lanes > 0 and stats.retire_sweeps >= 1
+    seed_ms = sorted(simulate_fast(fg1, sys1[i], "availability").makespan
+                     for i in seeds)
+    cut = seed_ms[k - 1] * (1.0 + 4.0 * JAX_RTOL)
+    for gi, ((fg, systems), sims) in enumerate(zip(items, res)):
+        for i, (system, sim) in enumerate(zip(systems, sims)):
+            ref = simulate_fast(fg, system, "availability")
+            if gi == 0 and i in seeds:
+                assert sim.makespan == ref.makespan
+            elif ref.makespan > cut:
+                assert isinstance(sim, Retired) and sim.bound == ref.makespan
+            else:
+                assert (sim.makespan, sim.placements) == \
+                    (ref.makespan, ref.placements), system.name
+
+    def top(results):
+        done = [(sim.makespan, system.name)
+                for (_fg, systems), sims in zip(items, results)
+                for system, sim in zip(systems, sims)
+                if not isinstance(sim, Retired)]
+        return sorted(done)[:k]
+    assert top(res) == top(simulate_jax_many(items, "availability",
+                                             min_lockstep=2))
+
+
+def test_mixed_sweep_sends_the_small_group_serial_and_the_large_to_the_heap(
+        monkeypatch):
+    """One group below ``min_lockstep`` and one above, in one sweep: the
+    small group runs on the exact serial path, the large one in the heap
+    scan, and every result is bit-identical to the exact reference."""
+    _no_replay_launch(monkeypatch)
+    fg1, g1 = frozen_for(synth_trace(30), smp=True)
+    fg2, g2 = frozen_for(synth_trace(18), smp=False)
+    items = [(fg1, _ramp(range(1, 5), "s")), (fg2, _ramp(range(1, 13), "l"))]
+    stats = BatchStats()
+    res = simulate_jax_many(items, "availability", stats=stats)
+    assert stats.small_group_lanes == 4
+    assert stats.heap_lanes == stats.lockstep_lanes == 12
+    assert stats.reference_lanes == stats.diverged_lanes == 0
+    assert stats.serial_fallback_lanes == stats.scan_lane_steps == 0
+    for g, (_fg, systems), sims in zip((g1, g2), items, res):
         for system, sim in zip(systems, sims):
-            assert sim.makespan == simulate_fast(fg, system, "eft").makespan
+            ref = Simulator(g, system, policy="availability").run()
+            assert sims_equivalent(sim, ref, 0.0), system.name
 
 
 # ---------------------------------------------------------------------------
@@ -388,19 +492,18 @@ def _cholesky(n, bs, accs):
     return trace, chol.report_map(bs), cands, a9_smp_seconds("float64")
 
 
+# each app's two pool-template groups (+-SMP) hold 8 or 12 lanes, at least
+# MIN_LOCKSTEP: the jax megabatch runs every lane in the heap scan
 APPS = {
-    # 64 tasks: 14 lanes diverge from the reference orders -> heap
-    "matmul256-bs64": (lambda: _matmul(256, 64, range(1, 9)), True),
-    # 8 tasks: 3 diverged lanes a group, below MIN_LOCKSTEP -> exact path
-    "matmul256-bs128": (lambda: _matmul(256, 128, range(1, 9)), False),
-    "cholesky512-bs64": (lambda: _cholesky(512, 64, range(1, 13)), True),
+    "matmul256-bs64": lambda: _matmul(256, 64, range(1, 9)),
+    "matmul256-bs128": lambda: _matmul(256, 128, range(1, 9)),
+    "cholesky512-bs64": lambda: _cholesky(512, 64, range(1, 13)),
 }
 
 
 @pytest.mark.parametrize("app", APPS)
 def test_paper_apps_rank_identically_on_every_engine(app):
-    build, heap = APPS[app]
-    trace, reports, cands, smp_fn = build()
+    trace, reports, cands, smp_fn = APPS[app]()
     ranked = {}
     for engine in ("reference", "fast", "batch", "jax"):
         ex = Explorer(trace, reports, engine=engine, smp_seconds_fn=smp_fn,
@@ -410,8 +513,8 @@ def test_paper_apps_rank_identically_on_every_engine(app):
         ranked[engine] = [(o.name, o.makespan_s) for o in res.ranked]
         if engine == "jax":
             st = ex.batch_stats
-            assert (st.heap_lanes > 0) == heap, st
-            assert st.serial_fallback_lanes == 0
+            assert st.heap_lanes == len(cands), st
+            assert st.serial_fallback_lanes == st.reference_lanes == 0
     assert len(ranked["jax"]) == len(cands)
     for engine in ("fast", "batch", "jax"):
         assert ranked[engine] == ranked["reference"], engine

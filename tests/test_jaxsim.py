@@ -21,8 +21,7 @@ from repro.core.batchsim import simulate_batch
 from repro.core.devices import DevicePool, SharedResource, SystemConfig
 from repro.core.explore import ENGINE_NAMES, CacheStats
 from repro.core.fastsim import FrozenGraph, simulate_fast
-from repro.core.jaxsim import (_bits_add, _bits_sub, simulate_jax,
-                               simulate_jax_many)
+from repro.core.jaxsim import _bits_add, _bits_sub, simulate_jax
 from repro.core.replay import (BatchStats, ENGINE_TOLERANCE, JAX_RTOL,
                                makespans_close, rankings_equivalent,
                                sims_equivalent)
@@ -594,35 +593,42 @@ STATE_CASES = {
 
 
 def _run_recorded(monkeypatch, items, policy):
-    """``simulate_jax_many`` over ``items``, recording each launch's
-    ``valid`` block and lane count, each cohort's step inputs, and the
-    ``(fg, system)`` of every lane the scan completed."""
+    """One ``_scan_cohorts`` call over every pool-template group of
+    ``items``, each group replaying the order of its most-parallel lane
+    (a cold reference discovery), recording each launch's ``valid`` block
+    and real lane count, each cohort's step inputs, the ``(fg, system)``
+    of every lane the scan completed, and its result."""
     import types
     from repro.core import jaxsim
     from repro.core.fastsim import pool_layout
-    run = types.SimpleNamespace(valid=[], lanes=[], kept=[])
-    real_load, real_scan = jaxsim._load_runner, jaxsim._scan_cohorts
+    run = types.SimpleNamespace(valid=[], kept=[], sims=[])
+    real_load = jaxsim._load_runner
 
     def load(cc, args):
         run.valid.append(np.asarray(args[0]["valid"]))
         return real_load(cc, args)
 
-    def scan(cohorts, *rest, **kw):
-        outs = real_scan(cohorts, *rest, **kw)
-        run.lanes.append(sum(len(c[2]) for c in cohorts))
-        for (fg, _order, layouts, _cut), (done, _div, _ret) in zip(cohorts,
-                                                                   outs):
-            systems = next(s for f, s in items if f is fg)
-            for pos in done:
-                run.kept.extend((fg, s) for s in systems
-                                if pool_layout(fg.kinds, s) == layouts[pos])
-        return outs
-
     monkeypatch.setattr(jaxsim, "_load_runner", load)
-    monkeypatch.setattr(jaxsim, "_scan_cohorts", scan)
-    stats = BatchStats()
-    run.sims = simulate_jax_many(items, policy, min_lockstep=2, stats=stats)
-    run.stats = stats
+    cohorts, members = [], []
+    for fg, systems in items:
+        groups = {}
+        for system in systems:
+            lay = pool_layout(fg.kinds, system)
+            groups.setdefault((tuple(lay[0]), tuple(lay[2])), []).append(
+                (system, lay))
+        for group in groups.values():
+            order = []
+            simulate_fast(fg, max(group, key=lambda m: sum(m[1][1]))[0],
+                          policy, order_out=order)
+            cohorts.append((fg, order, [lay for _, lay in group], None))
+            members.append(group)
+    outs = jaxsim._scan_cohorts(cohorts, policy,
+                                chunk=jaxsim.MEGABATCH_CHUNK)
+    run.lanes = [sum(len(group) for group in members)]
+    for (fg, _order, _lays, _cut), group, (done, _div, _ret) in zip(
+            cohorts, members, outs):
+        run.kept.extend((fg, group[pos][0]) for pos in done)
+        run.sims.extend(done.values())
     run.xs = [next(iter(fg._jax_xs.values())) for fg, _ in items]
     run.T = [len(x["r"]) for x in run.xs]
     return run
@@ -632,18 +638,21 @@ def _run_recorded(monkeypatch, items, policy):
 def test_step_indexed_state_is_bit_exact(monkeypatch, case):
     """Ready times pulled from the predecessors' steps and placements read
     at their latest touch give every lane ``simulate_fast``'s makespan,
-    busy times and placements bit for bit, on the megabatch and the
-    per-graph path, in each case the step-indexed state has to get right."""
+    busy times and placements bit for bit, in one multi-cohort launch of
+    the scan and on the per-graph path, in each case the step-indexed
+    state has to get right."""
     policy, build, ran = STATE_CASES[case]
     items = build()
     run = _run_recorded(monkeypatch, items, policy)
-    assert run.stats.lockstep_lanes > 0 and run.kept, "no lane ran the scan"
+    assert run.kept, "no lane ran the scan"
     assert ran(run), f"the scan never exercised {case}"
-    for (fg, systems), sims in zip(items, run.sims):
+    for (fg, system), sim in zip(run.kept, run.sims):
+        ref = simulate_fast(fg, system, policy)
+        assert sims_equivalent(sim, ref, 0.0), (case, system.name)
+    for fg, systems in items:
         per_graph = simulate_jax(fg, systems, policy, min_lockstep=2)
-        for system, sim, pg in zip(systems, sims, per_graph):
+        for system, pg in zip(systems, per_graph):
             ref = simulate_fast(fg, system, policy)
-            assert sims_equivalent(sim, ref, 0.0), (case, system.name)
             assert sims_equivalent(pg, ref, 0.0), (case, system.name)
 
 

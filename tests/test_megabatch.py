@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from repro.core import Explorer, zynq_system
+from repro.core.batchsim import simulate_batch
 from repro.core.devices import DevicePool, SharedResource, SystemConfig
 from repro.core.diskcache import DiskCache
 from repro.core.explore import _pool_mp_context
@@ -146,52 +147,72 @@ def test_megabatch_matches_per_graph_tier(seed, policy):
             assert sim.placements == ref.placements
 
 
-def test_megabatch_divergent_lanes_fall_back_exactly():
-    """Diverged megabatch lanes take an exact path — the heap scan, where
-    a group has ``min_lockstep`` of them (bit-identical, no order needed —
-    no rescue re-batching) — and the per-lane accounting still covers
-    every lane exactly once."""
+def _no_replay_launch(monkeypatch):
+    """Make a replay-scan (``jit_run``) launch fail the test."""
+    from repro.core import jaxsim
+
+    def refuse(*_a, **_k):
+        raise AssertionError("the megabatch launched the replay scan")
+    monkeypatch.setattr(jaxsim, "_load_runner", refuse)
+
+
+def test_megabatch_divergent_lanes_fall_back_exactly(monkeypatch):
+    """A slot-count ramp whose lanes diverge from any replayed order (the
+    per-graph path shows it) completes in the megabatch's heap scan, every
+    lane bit-identical to the batch engine, with no replay launch, no
+    discovery and no divergence; the per-lane accounting covers every lane
+    exactly once."""
     fg1, _ = frozen_for(synth_trace(40), smp=True)
     fg2, _ = frozen_for(synth_trace(24), smp=False)
     items = [(fg1, [zynq_system(f"a{n}", {"fpga:k": n})
                     for n in range(1, 25)]),
              (fg2, [zynq_system(f"b{n}", {"fpga:k": n})
                     for n in range(1, 13)])]
+    per_graph = BatchStats()
+    for fg, systems in items:
+        simulate_jax(fg, systems, "availability", min_lockstep=2,
+                     stats=per_graph)
+    assert per_graph.diverged_lanes > 0, "ramp should diverge on replay"
+    _no_replay_launch(monkeypatch)
     stats = BatchStats()
     res = simulate_jax_many(items, "availability", min_lockstep=2,
                             stats=stats)
     n_lanes = sum(len(systems) for _, systems in items)
-    assert stats.diverged_lanes > 0, "ramp should force exact fallbacks"
-    assert stats.lockstep_lanes > 0
-    assert stats.rescued_lanes == 0, "megabatch never re-batches"
-    assert stats.heap_lanes == stats.diverged_lanes
-    assert (stats.lockstep_lanes + stats.order_pinned_lanes
-            + stats.reference_lanes + stats.serial_fallback_lanes
-            + stats.small_group_lanes) == n_lanes
+    assert stats.heap_lanes == stats.lockstep_lanes == n_lanes
+    assert stats.reference_lanes == stats.diverged_lanes == 0
+    assert stats.rescued_lanes == stats.order_pinned_lanes == 0
+    assert stats.serial_fallback_lanes == stats.small_group_lanes == 0
+    assert stats.scan_lane_steps == 0
     for (fg, systems), sims in zip(items, res):
-        for system, sim in zip(systems, sims):
-            ref = simulate_fast(fg, system, "availability")
-            assert sims_equivalent(sim, ref, JAX_RTOL)
+        ref = simulate_batch(fg, systems, "availability", min_lockstep=2)
+        for system, sim, want in zip(systems, sims, ref):
+            assert sims_equivalent(sim, want, 0.0), system.name
 
 
-def test_megabatch_warm_library_routes_everything():
-    """After one cold call the library holds the orders the megabatch
-    discovered: the next call is all lockstep with zero discoveries — the
-    warm protocol the megabatch records orders *for*.  A lane that no
-    order serves records none (it completed in the heap scan, which writes
-    nothing to the shared library), so it diverges again and completes in
-    the heap scan again, with nothing run serially."""
+def test_megabatch_warm_library_routes_everything(monkeypatch):
+    """A library warmed by the per-graph jax path steers no megabatch
+    lane: the megabatch sweep on it routes every lane to the heap scan,
+    with no order hit, no discovery and no replay launch, leaves the
+    library as it found it, and ranks as the batch engine does."""
+    reports, rep = synth_reports(), synth_report()
+    tr = synth_trace(24)
+    cands = synth_candidates(range(1, 7), rep)
     lib = ReplayLibrary()
-    items = _mixed_families(3)
-    simulate_jax_many(items, "availability", min_lockstep=2, library=lib)
-    simulate_jax_many(items, "availability", min_lockstep=2, library=lib)
-    stats = BatchStats()
-    simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
-                      stats=stats)
-    assert stats.diverged_lanes == stats.heap_lanes
-    assert stats.lockstep_lanes == sum(len(s) for _, s in items)
-    assert stats.reference_lanes == 0 and stats.serial_fallback_lanes == 0
-    assert stats.order_hits > 0
+    Explorer(tr, reports, engine="jax", jax_megabatch=False,
+             order_library=lib).explore(cands)
+    warmed = lib.counts()
+    assert warmed["orders"] > 0
+    _no_replay_launch(monkeypatch)
+    ex = Explorer(tr, reports, engine="jax", order_library=lib)
+    got = ex.explore(cands)
+    st = ex.batch_stats
+    assert lib.counts() == warmed
+    assert st.heap_lanes == st.lockstep_lanes == len(cands)
+    assert st.order_hits == st.order_pinned_lanes == 0
+    assert st.reference_lanes == st.diverged_lanes == 0
+    batch = Explorer(tr, reports, engine="batch").explore(cands)
+    assert [(o.name, o.makespan_s) for o in got.ranked] == \
+        [(o.name, o.makespan_s) for o in batch.ranked]
 
 
 def test_megabatch_rejects_bad_arguments():
@@ -201,8 +222,6 @@ def test_megabatch_rejects_bad_arguments():
         simulate_jax_many(items, "heft")
     with pytest.raises(ValueError, match="chunk"):
         simulate_jax_many(items, "availability", chunk=0)
-    with pytest.raises(ValueError, match="step_impl"):
-        simulate_jax_many(items, "availability", step_impl="cuda")
     with pytest.raises(ValueError, match="step_impl"):
         simulate_jax(fg, [zynq_system("s", {"fpga:k": 1})],
                      step_impl="nope")
@@ -313,35 +332,26 @@ def test_compile_cache_cross_process_warm_start(tmp_path):
     DiskCache ``xla`` namespace (disk_hits >= 1)."""
     store = str(tmp_path / "store")
     items = _mixed_families(1)
-    lib = ReplayLibrary()
     cc = CompileCache(DiskCache(store))
-    # three runs stabilise the cohort structure: discoveries (run 1) and
-    # conservative-false-positive pins (run 2) change the routing, run 3's
-    # signature is the steady state a warm process will reproduce
-    for _ in range(3):
-        simulate_jax_many(items, "availability", min_lockstep=2,
-                          library=lib, compile_cache=cc)
+    # the heap launch's shapes follow from the sweep's groups alone, so
+    # one run compiles everything a fresh process will need
+    simulate_jax_many(items, "availability", min_lockstep=2,
+                      compile_cache=cc)
     payload = str(tmp_path / "families.pkl")
-    exports = [lib.export(fg.content_hash(), "availability")
-               for fg, _ in items]
     with open(payload, "wb") as f:
-        pickle.dump((items, exports), f)
+        pickle.dump(items, f)
 
     script = """
 import json, pickle, sys
 from repro.core.diskcache import DiskCache
 from repro.core.jaxsim import simulate_jax_many
-from repro.core.replay import ReplayLibrary
 from repro.core.xlacache import CompileCache
 
 with open(sys.argv[1], "rb") as f:
-    items, exports = pickle.load(f)
-lib = ReplayLibrary()
-for (fg, _), export in zip(items, exports):
-    lib.merge(fg, "availability", export)
+    items = pickle.load(f)
 cc = CompileCache(DiskCache(sys.argv[2]))
 res = simulate_jax_many(items, "availability", min_lockstep=2,
-                        library=lib, compile_cache=cc)
+                        compile_cache=cc)
 print(json.dumps({"cc": cc.as_dict(),
                   "spans": [[s.makespan for s in fam] for fam in res]}))
 """
@@ -399,7 +409,7 @@ def _record_scans(monkeypatch):
     import jax.numpy as jnp
     from repro.core import jaxsim
     rec = {"launches": [], "uploads": [], "launch_args": [], "kept": [],
-           "heap_launches": []}
+           "heap_launches": [], "heap_args": []}
     real_load, real_put = jaxsim._load_runner, jnp.asarray
     real_scan = jaxsim._scan_cohorts
     real_heap = jaxsim._load_heap_runner
@@ -413,6 +423,7 @@ def _record_scans(monkeypatch):
     def load_heap(cc, args):
         rec["heap_launches"].append((args[0]["npred"].shape[0],
                                      args[1].shape[2]))
+        rec["heap_args"].append(sum(np.asarray(a).nbytes for a in args[1:]))
         return real_heap(cc, args)
 
     def put(a, *rest, **kw):
@@ -436,41 +447,35 @@ def _record_scans(monkeypatch):
 
 
 def test_scan_counters_match_the_launches_and_uploads(monkeypatch):
-    """``scan_lane_steps`` is Σ B × T_max over the launches made,
-    ``scan_kept_lane_steps`` Σ kept lanes × their cohort's steps,
-    ``heap_lane_steps`` Σ B × rows over the heap scan's launches, and
-    ``h2d_bytes`` plus ``heap_h2d_bytes`` the bytes actually handed to
-    ``jnp.asarray``; a warm repeat (every step block a device-cache hit)
-    uploads only each launch's own arguments."""
+    """A megabatch sweep makes no replay-scan launch, so
+    ``scan_lane_steps``, ``scan_kept_lane_steps`` and ``h2d_bytes`` stay
+    0; ``heap_lane_steps`` is Σ B × rows over the heap scan's launches and
+    ``heap_h2d_bytes`` the bytes actually handed to ``jnp.asarray``.  A
+    warm repeat (the stacked graph tables a device-cache hit) uploads only
+    each launch's own arguments, over the same launches."""
     items = _ramp_families()
-    lib = ReplayLibrary()
-    for _ in range(2):              # settle the routing, as a warm sweep
-        simulate_jax_many(items, "availability", min_lockstep=2,
-                          library=lib)
     rec = _record_scans(monkeypatch)
     cold = BatchStats()
-    simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
-                      stats=cold)
-    launches = list(rec["launches"])
-    assert launches, "the families must reach the scan"
-    assert cold.scan_lane_steps == sum(t * b for t, b in launches)
-    assert cold.scan_kept_lane_steps == sum(rec["kept"])
-    assert 0 < cold.scan_kept_lane_steps < cold.scan_lane_steps
-    assert rec["heap_launches"], "the diverged lanes must reach the heap"
-    assert cold.heap_lane_steps == sum(r * b for r, b in rec["heap_launches"])
-    assert cold.h2d_bytes + cold.heap_h2d_bytes == sum(rec["uploads"])
-    assert cold.h2d_bytes > sum(rec["launch_args"])
+    simulate_jax_many(items, "availability", min_lockstep=2, stats=cold)
+    assert rec["launches"] == [] and rec["kept"] == []
+    assert (cold.scan_lane_steps, cold.scan_kept_lane_steps,
+            cold.h2d_bytes) == (0, 0, 0)
+    heap_launches = list(rec["heap_launches"])
+    assert heap_launches, "every lane must reach the heap scan"
+    assert cold.heap_lanes == sum(len(s) for _, s in items)
+    assert cold.heap_lane_steps == sum(r * b for r, b in heap_launches)
+    assert cold.heap_h2d_bytes == sum(rec["uploads"])
+    assert cold.heap_h2d_bytes > sum(rec["heap_args"])
 
     for k in rec:
         rec[k].clear()
     warm = BatchStats()
-    simulate_jax_many(items, "availability", min_lockstep=2, library=lib,
-                      stats=warm)
-    assert rec["launches"] == launches
-    assert warm.h2d_bytes == sum(rec["launch_args"])
-    assert warm.h2d_bytes + warm.heap_h2d_bytes == sum(rec["uploads"])
-    assert warm.scan_lane_steps == cold.scan_lane_steps
-    assert warm.scan_kept_lane_steps == cold.scan_kept_lane_steps
+    simulate_jax_many(items, "availability", min_lockstep=2, stats=warm)
+    assert rec["heap_launches"] == heap_launches
+    assert warm.heap_h2d_bytes == sum(rec["heap_args"]) \
+        == sum(rec["uploads"])
+    assert warm.heap_lane_steps == cold.heap_lane_steps
+    assert warm.h2d_bytes == warm.scan_lane_steps == 0
 
 
 def test_span_needs_no_jax_and_marks_the_trace_with_it():

@@ -7,14 +7,13 @@ module is imported — because only one process at a time may load the TPU
 library, and every test worker imports every test file.
 
 * The jax engine's scan runner (the ``lax`` step: times as int64 float64
-  bit patterns, under x64) compiles at the slice shapes ``chip_smoke.py``
-  compiles: phase A's
+  bit patterns, under x64) compiles at full-size slice shapes: the
   Cholesky ``n=2048, bs=128`` graph (4,912 task rows after DMA
-  augmentation, 5 pools), and phase B's ``synth:1024`` sweep over 1-128
+  augmentation, 5 pools), and a ``synth:1024`` sweep over 1-128
   accelerators (5,120 task rows, 4 pools, slot axes of 32, 64 and 128).
-* The heap scan (``_heap_runner``) compiles at the launch shapes of the
-  benchmark's matmul and dgemm-ramp cells, with no copy between HBM and
-  vector memory inside its loop.
+* The heap scan (``_heap_runner``), which runs every megabatch lane,
+  compiles at the launch shapes of the benchmark's three cells, with no
+  copy between HBM and vector memory inside its loop.
 * The pallas step-commit kernel compiles to a Mosaic custom call with
   float32 state and x64 off.  No 64-bit operand passes into a Mosaic
   kernel, which is why ``step_impl="auto"`` resolves to ``lax`` inside
@@ -33,8 +32,8 @@ from repro.core.jaxsim import (_heap_runner, _runner, resolve_step_impl,
 from repro.kernels.lockstep_step import step_commit
 from repro.testing.synth import frozen_for, synth_trace
 
-#: Scan slices of chip_smoke.py: task rows (T = n), pools, slots, lanes,
-#: per-row device options, device kinds and predecessor-list width.
+#: Full-size scan slices: task rows (T = n), pools, slots, lanes, per-row
+#: device options, device kinds and predecessor-list width.
 SLICES = {"phaseA": (4912, 5, 8, 32, 2, 5, 10),
           "phaseB-S32": (5120, 4, 32, 64, 2, 4, 4),
           "phaseB-S64": (5120, 4, 64, 32, 2, 4, 4),
@@ -90,10 +89,13 @@ def test_scan_runner_compiles_for_v5e(one_chip, shape):
     assert 0 < mem.argument_size_in_bytes < 1 << 30
 
 
-#: Heap-scan launches of the benchmark's cells: padded rows, cohorts,
-#: options, device kinds, pools, slots, lanes.
+#: Heap-scan launches of the benchmark's cells, where every lane of a
+#: group of at least ``MIN_LOCKSTEP`` goes to the heap scan: padded rows,
+#: cohorts, options, device kinds, pools, slots, lanes (read from
+#: ``_heap_cohorts``'s launch arguments in a CPU sweep of each cell).
 HEAP_LAUNCHES = {"matmul1024-ramp": (3584, 4, 2, 4, 4, 64, 128),
-                 "chol2048-dgemm64": (4480, 4, 2, 4, 4, 64, 128)}
+                 "chol2048-dgemm64": (4480, 4, 2, 4, 4, 64, 256),
+                 "chol2048-fig9": (4992, 8, 2, 5, 5, 8, 64)}
 
 
 @pytest.mark.parametrize("shape", HEAP_LAUNCHES.values(),
