@@ -207,7 +207,7 @@ def _sweep_rows(trace, reports, a9, count: int,
     """Tentpole measurement: the candidate-axis engines vs the
     per-candidate fast path vs the PR-1 cached path on one big batch.
 
-    Ten engine configurations over the same candidates, each
+    Nine engine configurations over the same candidates, each
     fresh-Explorer (so the in-memory caches start cold), best-of-``reps``
     to tame this box's scheduler jitter:
 
@@ -223,16 +223,11 @@ def _sweep_rows(trace, reports, a9, count: int,
     * ``batch_procs`` — batch engine sliced across the same pool.
     * ``disk``        — repeat-sweep: warm on-disk store (the iterative
       co-design workflow; re-ranks without building a single graph).
-    * ``jax``         — jit-compiled ``lax.scan`` candidate-axis engine
-      (PR 4, ``repro.core.jaxsim``), per-graph scans, full-width lane
-      chunks, warm jit cache (the one-off compile is recorded separately
-      as ``jax_compile_seconds``).
-    * ``jaxc``        — same engine with 16-lane vmap-style chunking (the
-      compile-cache-friendly bucket shape for very large sweeps).
-    * ``jaxm``        — multi-graph megabatch (ISSUE 6,
-      ``jaxsim.simulate_jax_many``): every lane of every graph family of
-      the sweep in **one** heap-scan launch (no order replayed), warm
-      in-memory compile cache (steady state).
+    * ``jaxm``        — the jax engine (``repro.core.jaxsim``), its
+      multi-graph megabatch (``jaxsim.simulate_jax_many``): every lane of
+      every graph family of the sweep in **one** heap-scan launch (no
+      order replayed), warm in-memory compile cache (steady state; the
+      one-off compile is recorded separately as ``jax_compile_seconds``).
     * ``jaxw``        — per-sweep warm path (ISSUE 10 satellite): fresh
       Explorers sharing a CompileCache whose memory tier a single
       priming sweep loaded from the warm DiskCache ``xla`` store.  Zero
@@ -276,12 +271,12 @@ def _sweep_rows(trace, reports, a9, count: int,
     # spin up the shared worker pool outside the timed rows: the executor is
     # worker-persistent across sweeps, so steady state never pays the fork
     mk(processes=2, batch=False).explore(cands[:max(4, len(cands) // 25)])
-    # warm the jax jit cache outside the timed rounds too, and record the
-    # one-off cost: first call = trace + XLA compile + the sweep itself
+    # record the jax engine's one-off cost outside the timed rounds:
+    # first call = trace + XLA compile + the sweep itself
     t0 = time.perf_counter()
-    mk(engine="jax", jax_megabatch=False).explore(cands)
+    jax_ex = mk(engine="jax")
+    jax_ex.explore(cands)
     jax_compile_s = time.perf_counter() - t0
-    mk(engine="jax", jax_megabatch=False, jax_chunk=16).explore(cands)
     # megabatch warm-up on a disk-backed compile cache: the heap launch's
     # shapes follow from the sweep's groups alone, so one run compiles
     # the steady state a warm process reproduces
@@ -328,8 +323,6 @@ def _sweep_rows(trace, reports, a9, count: int,
         "fastp": dict(batch=False, processes=2),
         "batchp": dict(processes=2),
         "disk": dict(cache_dir=cache_dir),
-        "jax": dict(engine="jax", jax_megabatch=False),
-        "jaxc": dict(engine="jax", jax_megabatch=False, jax_chunk=16),
         "jaxm": dict(engine="jax", order_library=jaxm_lib,
                      compile_cache=jaxm_cc),
         "jaxw": dict(engine="jax", order_library=jaxm_lib,
@@ -356,14 +349,11 @@ def _sweep_rows(trace, reports, a9, count: int,
                 best[name] = dt
     pr1_s, fast_s, batch_s = best["pr1"], best["fast"], best["batch"]
     fastp_s, batchp_s, disk_s = best["fastp"], best["batchp"], best["disk"]
-    jax_s, jaxc_s, batchw_s = best["jax"], best["jaxc"], best["batchw"]
-    jaxm_s, jaxw_s = best["jaxm"], best["jaxw"]
+    batchw_s, jaxm_s, jaxw_s = best["batchw"], best["jaxm"], best["jaxw"]
     pr1, fast, batch = res["pr1"], res["fast"], res["batch"]
     fastp, batchp, disk = res["fastp"], res["batchp"], res["disk"]
-    jaxr, jaxcr, batchw = res["jax"], res["jaxc"], res["batchw"]
-    jaxmr, jaxwr = res["jaxm"], res["jaxw"]
-    batch_ex, jax_ex, warm_ex = exs["batch"], exs["jax"], exs["batchw"]
-    jaxm_ex = exs["jaxm"]
+    batchw, jaxmr, jaxwr = res["batchw"], res["jaxm"], res["jaxw"]
+    batch_ex, warm_ex, jaxm_ex = exs["batch"], exs["batchw"], exs["jaxm"]
 
     # the per-sweep warm contract: the timed `jaxw` rounds above ran over
     # the already-loaded memory tier — zero compiles AND zero further
@@ -381,7 +371,7 @@ def _sweep_rows(trace, reports, a9, count: int,
         "every exact engine must produce the bit-identical ranking"
     spans = {o.name: o.makespan_s for o in batch.ranked}
     names = lambda r: [o.name for o in r.ranked]
-    for jr in (jaxr, jaxcr, jaxmr, jaxwr):
+    for jr in (jaxmr, jaxwr):
         assert rankings_equivalent(names(jr), names(batch), spans, JAX_RTOL), \
             "jax rows must rank identically to the batch engine under the " \
             "documented rtol tie-break"
@@ -407,7 +397,7 @@ def _sweep_rows(trace, reports, a9, count: int,
     batch_vs_pr2_fast = max(
         paired + [(PR2_FAST_SERIAL_S * speed_scale) / batch_best])
     sweep_speedup = pr1_s / min(fast_s, batch_s, fastp_s, batchp_s, disk_s,
-                                jax_s, jaxc_s, jaxm_s, batchw_s)
+                                jaxm_s, batchw_s)
     # warm-vs-cold paired within a round (same machine conditions), best
     # round taken — the order-library win at equal machine speed
     wpaired = [rd["batch"] / rd["batchw"] for rd in per_round
@@ -447,25 +437,11 @@ def _sweep_rows(trace, reports, a9, count: int,
                  f"candidates={nc},seconds={disk_s:.4f},"
                  f"speedup={pr1_s / disk_s:.1f}x,"
                  f"disk_hits={disk.cache['disk_hits']}"))
-    rows.append(("fig6/sweep_jax_serial", jax_s * 1e6,
-                 f"candidates={nc},seconds={jax_s:.3f},"
-                 f"speedup={pr1_s / jax_s:.1f}x,"
-                 f"lockstep={jstats['lockstep_lanes']},"
-                 f"diverged={jstats['diverged_lanes']}"))
-    rows.append(("fig6/sweep_jax_chunked", jaxc_s * 1e6,
-                 f"candidates={nc},seconds={jaxc_s:.3f},"
-                 f"speedup={pr1_s / jaxc_s:.1f}x,chunk=16"))
     mstats = jaxm_ex.batch_stats.as_dict()
     mcc = jaxm_cc.as_dict()
-    # megabatch-vs-chunked paired within a round (same machine conditions),
-    # best round taken — the one-compiled-scan win at equal machine speed
-    mpaired = [rd["jaxc"] / rd["jaxm"] for rd in per_round
-               if "jaxc" in rd and "jaxm" in rd]
-    jaxm_vs_chunked = max(mpaired) if mpaired else jaxc_s / jaxm_s
     rows.append(("fig6/sweep_jax_megabatch", jaxm_s * 1e6,
                  f"candidates={nc},seconds={jaxm_s:.3f},"
                  f"speedup={pr1_s / jaxm_s:.1f}x,"
-                 f"vs_chunked={jaxm_vs_chunked:.2f}x,"
                  f"lockstep={mstats['lockstep_lanes']},"
                  f"heap={mstats['heap_lanes']}"))
     # warm-vs-cold-megabatch paired within a round: the regression this
@@ -504,15 +480,12 @@ def _sweep_rows(trace, reports, a9, count: int,
         "sweep_fast_procs_seconds": fastp_s,
         "sweep_batch_procs_seconds": batchp_s,
         "sweep_disk_rerank_seconds": disk_s,
-        "sweep_jax_serial_seconds": jax_s,
-        "sweep_jax_chunked_seconds": jaxc_s,
         "sweep_jax_megabatch_seconds": jaxm_s,
         "sweep_jax_warm_seconds": jaxw_s,
         "sweep_jax_warmstart_seconds": jaxws_s,
         "sweep_jax_warm_vs_megabatch_speedup": jaxw_vs_megabatch,
         "jax_compile_seconds": jax_compile_s,
         "jax_megabatch_compile_seconds": jaxm_compile_s,
-        "jax_megabatch_vs_chunked_speedup": jaxm_vs_chunked,
         "sweep_speedup": sweep_speedup,
         "sweep_fast_serial_speedup": pr1_s / fast_s,
         "sweep_disk_rerank_speedup": pr1_s / disk_s,
@@ -521,7 +494,6 @@ def _sweep_rows(trace, reports, a9, count: int,
         "candidates_per_sec_fast": nc / min(fast_s, fastp_s),
         "candidates_per_sec_batch": nc / batch_best,
         "candidates_per_sec_batch_warm": nc / batchw_s,
-        "candidates_per_sec_jax": nc / min(jax_s, jaxc_s),
         "candidates_per_sec_jax_megabatch": nc / jaxm_s,
         "batch_vs_pr2_fast_speedup": batch_vs_pr2_fast,
         "fast_procs_vs_serial_speedup": fast_s / fastp_s,
@@ -563,15 +535,6 @@ def _sweep_rows(trace, reports, a9, count: int,
             f"path (PR-2 pickling regression guard): best paired ratio " \
             f"{fast_procs_ratio:.2f}x (procs {fastp_s:.3f}s vs serial " \
             f"{fast_s:.3f}s best-of)"
-        # on a single-core XLA CPU backend the scan is per-lane-bound
-        # (carry traffic ~ lanes x (n + P*S) per step), and that term is
-        # identical for the megabatch and the per-graph chunked path — so
-        # parity-or-better is the honest single-core contract (it was
-        # 0.94x before the slot-clamped, cache-sized, lane-aligned
-        # slices).  The megabatch's structural wins on this box are the
-        # sweep-wide executable family (cohort-drift-immune signatures,
-        # zero-compile warm starts — asserted on the sweep_jax_warm row);
-        # the throughput crossover is a multi-core story (ROADMAP).
         # per-sweep warm runs the *same* megabatch engine over the same
         # routing with a pre-loaded executable tier — structurally it can
         # only differ from jaxm by cache-lookup noise, so the honest gate
@@ -581,10 +544,6 @@ def _sweep_rows(trace, reports, a9, count: int,
             f"warm jax sweep must stay within jitter of the cold " \
             f"megabatch (got {jaxw_vs_megabatch:.2f}x: warm " \
             f"{jaxw_s:.3f}s vs megabatch {jaxm_s:.3f}s)"
-        assert jaxm_vs_chunked >= 1.0, \
-            f"the megabatch scan must not lose to the per-graph chunked " \
-            f"jax path (got {jaxm_vs_chunked:.2f}x: megabatch " \
-            f"{jaxm_s:.3f}s vs chunked {jaxc_s:.3f}s)"
         # the pr1 yardstick scales machine speed through the *reference*
         # engine (pure Python), while the numerator is the vectorised
         # batch engine — their relative speeds drift ±10% across boxes

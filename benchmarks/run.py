@@ -46,7 +46,6 @@ def _gated_metric(key: str) -> bool:
     return (key.startswith("sweep_") and not key.endswith("_stats")) \
         or key.startswith("candidates_per_sec") \
         or key == "batch_vs_pr2_fast_speedup" \
-        or key == "jax_megabatch_vs_chunked_speedup" \
         or key == "serve_coalesced_8c_speedup"
 
 

@@ -260,10 +260,8 @@ def phase_served(ref, request: dict = SWEEP_REQUEST,
 
 def main() -> int:
     dev = require_tpu()
-    from repro.core.jaxsim import resolve_step_impl
     from repro.core.xlacache import enable_persistent_cache
-    info("setup", step_impl=resolve_step_impl("auto"),
-         jax_cache_dir=enable_persistent_cache())
+    info("setup", jax_cache_dir=enable_persistent_cache())
     counter = CompileCounter()
     t0 = time.perf_counter()
     phase_cholesky()

@@ -17,7 +17,7 @@ from .batchsim import BatchStats, simulate_batch
 from .replay import (ENGINE_TOLERANCE, JAX_RTOL, MAX_RESCUE_ROUNDS,
                      ReplayLibrary, order_valid, rankings_equivalent,
                      sims_equivalent)
-from .jaxsim import simulate_jax
+from .jaxsim import simulate_jax_many
 from .diskcache import DiskCache, trace_fingerprint
 from .estimator import (PerfEstimate, contention_time_model, estimate,
                         reference_run, same_best, spearman_rank_correlation,
@@ -41,7 +41,7 @@ __all__ = [
     "BatchStats", "simulate_batch",
     "ENGINE_TOLERANCE", "JAX_RTOL", "MAX_RESCUE_ROUNDS", "ReplayLibrary",
     "order_valid", "rankings_equivalent", "sims_equivalent",
-    "simulate_jax",
+    "simulate_jax_many",
     "DiskCache", "trace_fingerprint",
     "PerfEstimate", "contention_time_model", "estimate", "reference_run",
     "same_best", "spearman_rank_correlation", "speedup_table",

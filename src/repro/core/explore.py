@@ -908,7 +908,6 @@ class Explorer:
                  cache_dir: Optional[str] = None,
                  engine: Optional[str] = None,
                  jax_chunk: Optional[int] = None,
-                 jax_megabatch: Optional[bool] = None,
                  compile_cache: Optional["CompileCache"] = None,
                  order_library: Optional[ReplayLibrary] = None,
                  max_rescue_rounds: int = MAX_RESCUE_ROUNDS,
@@ -925,15 +924,12 @@ class Explorer:
         booleans (kept for compatibility: ``fast=False`` is
         ``engine="reference"``, ``fast=True, batch=False`` is
         ``engine="fast"``, the default is ``engine="batch"``).
-        ``engine="jax"`` evaluates each graph-sharing candidate family
-        through the jit-compiled ``lax.scan`` backend
-        (:mod:`repro.core.jaxsim`, rtol-tier, in-process only;
-        ``jax_chunk`` caps its compiled lane-bucket width — non-power-of-
-        two caps round down to a power of two, so the compiled width
-        never exceeds the cap).  ``jax_megabatch`` (default on for the jax
-        engine) routes each evaluation chunk's *whole* graph set through
-        one heap-scan launch (:func:`repro.core.jaxsim.simulate_jax_many`,
-        which replays no order) instead of one scan per graph family;
+        ``engine="jax"`` routes each evaluation chunk's *whole* graph set
+        through one jit-compiled heap-scan launch
+        (:func:`repro.core.jaxsim.simulate_jax_many`, rtol-tier,
+        in-process only, which replays no order; ``jax_chunk`` caps its
+        compiled lane-bucket width — non-power-of-two caps round down to
+        a power of two, so the compiled width never exceeds the cap);
         with a ``cache_dir`` the compiled executables also persist
         (:class:`~repro.core.xlacache.CompileCache`, DiskCache ``xla``
         namespace), so warm sweeps skip XLA compilation entirely.
@@ -955,8 +951,7 @@ class Explorer:
         processes start warm.  ``max_rescue_rounds`` bounds the serial
         order discoveries per candidate group (see
         :func:`repro.core.replay.replay_group`).  The library and the
-        bound serve the batch engine and the per-graph jax path
-        (``jax_megabatch=False``); the megabatch uses neither.
+        bound serve the batch engine only; the jax engine uses neither.
 
         Fault tolerance (see docs/architecture.md "Failure model"):
         ``candidate_timeout`` is the per-candidate evaluation deadline —
@@ -1035,14 +1030,9 @@ class Explorer:
                 raise ValueError(f"jax_chunk only applies to engine='jax' "
                                  f"(got engine={engine!r})")
         self.jax_chunk = jax_chunk
-        if jax_megabatch is not None and engine != "jax":
-            raise ValueError(f"jax_megabatch only applies to engine='jax' "
-                             f"(got engine={engine!r})")
         if compile_cache is not None and engine != "jax":
             raise ValueError(f"compile_cache only applies to engine='jax' "
                              f"(got engine={engine!r})")
-        self.jax_megabatch = (engine == "jax") if jax_megabatch is None \
-            else bool(jax_megabatch)
         self._sim_tier = "jax" if engine == "jax" else "exact"
         if engine == "jax":
             if self.processes:
@@ -1272,7 +1262,6 @@ class Explorer:
         self.engine = nxt
         self.fast = nxt != "reference"
         self.batch = nxt == "batch"
-        self.jax_megabatch = False
         self._sim_tier = "exact"
         if not self.fast:
             # cached FrozenGraph payloads are the wrong shape for the
@@ -1850,7 +1839,7 @@ class Explorer:
             pending.setdefault(gkey, []).append((pos, cand, key, text, ghit))
 
         if not use_procs:                      # serial lockstep evaluation
-            if self.engine == "jax" and self.jax_megabatch and pending:
+            if self.engine == "jax" and pending:
                 try:
                     return self._evaluate_megabatch(pending, graph_info,
                                                     results)
@@ -2089,10 +2078,10 @@ class Explorer:
                             results: List) -> List:
         """Every graph family of one evaluation chunk through a single
         heap-scan launch (:func:`repro.core.jaxsim.simulate_jax_many`) —
-        one megabatch dispatch instead of one per-graph scan each, with
-        compiled executables shared via the Explorer's compile cache.  It
-        replays no order, so the order library is neither read nor
-        written."""
+        the jax engine's only path, one megabatch dispatch for the whole
+        chunk, with compiled executables shared via the Explorer's compile
+        cache.  It replays no order, so the order library is neither read
+        nor written."""
         from .jaxsim import simulate_jax_many
         gkeys = list(pending)
         fams = []
@@ -2153,12 +2142,14 @@ class Explorer:
                          prune: Optional[PruneContext] = None) \
             -> List[Union[SimResult, Retired]]:
         """One graph-sharing candidate family through the configured
-        candidate-axis backend (numpy lockstep or the jax scan), replaying
-        orders from the sweep's (disk-warmed) library.  With ``prune``,
-        lanes may come back as :class:`~repro.core.replay.Retired`
-        markers (the ``family_runner`` seam stays unpruned in-flight —
-        its sweeps run out-of-process of the incumbent; the pre-cut in
-        ``_explore`` still applies to its candidates).
+        exact engine: the numpy lockstep, replaying orders from the
+        sweep's (disk-warmed) library, or a serial engine a demotion left.
+        The jax engine takes :meth:`_evaluate_megabatch` instead.  With
+        ``prune``, lanes may come back as
+        :class:`~repro.core.replay.Retired` markers (the ``family_runner``
+        seam stays unpruned in-flight — its sweeps run out-of-process of
+        the incumbent; the pre-cut in ``_explore`` still applies to its
+        candidates).
 
         An engine fault demotes down :data:`~repro.core.replay.
         ENGINE_FALLBACK` and re-runs the *whole family* on the next tier
@@ -2168,17 +2159,6 @@ class Explorer:
         systems = [c.system for c in cands]
         while True:
             try:
-                if self.engine == "jax":
-                    self._load_orders(payload)
-                    from .jaxsim import simulate_jax
-                    kw = {} if self.jax_chunk is None \
-                        else {"chunk": self.jax_chunk}
-                    return simulate_jax(payload, systems, self.policy,
-                                        stats=self.batch_stats,
-                                        library=self.order_library,
-                                        max_rounds=self.max_rescue_rounds,
-                                        compile_cache=self.compile_cache,
-                                        prune=prune, **kw)
                 if self.engine == "batch":
                     self._load_orders(payload)
                     if self.family_runner is not None:
@@ -2252,7 +2232,6 @@ def explore(trace: Trace, candidates: Sequence[Candidate], reports: ReportMap,
             cache_dir: Optional[str] = None,
             engine: Optional[str] = None,
             jax_chunk: Optional[int] = None,
-            jax_megabatch: Optional[bool] = None,
             compile_cache: Optional["CompileCache"] = None,
             order_library: Optional[ReplayLibrary] = None,
             max_rescue_rounds: int = MAX_RESCUE_ROUNDS,
@@ -2272,7 +2251,7 @@ def explore(trace: Trace, candidates: Sequence[Candidate], reports: ReportMap,
                   max_workers=max_workers, cache=cache, fast=fast,
                   batch=batch, processes=processes, cache_dir=cache_dir,
                   engine=engine, jax_chunk=jax_chunk,
-                  jax_megabatch=jax_megabatch, compile_cache=compile_cache,
+                  compile_cache=compile_cache,
                   order_library=order_library,
                   max_rescue_rounds=max_rescue_rounds,
                   objectives=objectives, budgets=budgets, hwspec=hwspec)

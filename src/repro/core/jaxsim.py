@@ -1,50 +1,40 @@
 """jax ``lax.scan`` candidate-axis engine — the sweep loop, compiled.
 
 :mod:`repro.core.batchsim` proved the lockstep formulation: every candidate
-sharing one :class:`~repro.core.fastsim.FrozenGraph` advances through one
-replayed reference event order with per-candidate state stacked on a
-candidate ("lane") axis.  Its per-step cost is numpy-*dispatch*-bound
-(~20 µs/step of Python/C boundary crossings per task row).  This module
-compiles the identical per-step semantics into a single jit-compiled
-:func:`jax.lax.scan` over the replayed order, so a whole sweep runs as one
-XLA computation with the full per-candidate state carried as scan state on
-a device-resident candidate axis.
+sharing one :class:`~repro.core.fastsim.FrozenGraph` advances step by step
+with per-candidate state stacked on a candidate ("lane") axis.  Its
+per-step cost is numpy-*dispatch*-bound.  This module compiles one lockstep
+step into a single jit-compiled :func:`jax.lax.scan`, the **heap scan**
+(:func:`_heap_runner`), in which each lane pops its *own* heap, so a whole
+sweep runs as one XLA computation with the per-candidate state carried as
+scan state on a device-resident lane axis.
 
-Invariants (shared with the numpy backend unless stated):
+Invariants:
 
 * **Lane-last axis convention.**  Per-candidate state is stacked with the
   lane axis *last* — pool free-slot clocks ``[P, S, B]``, busy ``[P, B]``,
-  and the step-indexed ends ``[T+1, B]`` and placements ``[2T+1, B]``
-  (:func:`_runner`), which the host turns back into the batchsim layout's
-  placement ids ``[n, B]``, so the shared assembly helper
-  (:func:`repro.core.replay.lane_results`) serves both backends.
-* **rtol tier, not bit-identity.**  The exact engines replicate the
-  reference engine's float ops in the reference order; XLA owns its own op
-  scheduling, so this engine is pinned at the relaxed tier instead:
-  makespans and busy sums within :data:`repro.core.replay.JAX_RTOL`
-  (relative) of the reference, placements/pool layouts discrete-identical,
-  and rankings stable under the documented tie-break (sub-tolerance
-  makespan ties break by candidate submission order).  The scan runs
-  under ``jax.enable_x64``, and its ``lax`` step does exact IEEE float64
-  time arithmetic on int64 bit patterns (:func:`_bits_add`), so on every
-  backend — the TPU, whose own float64 is inexact, included — its
-  makespans match the reference bit for bit.
-* **Divergence falls back to the exact path.**  The same per-step heap-key
-  monotonicity check as batchsim runs *inside* the scan (carried
-  ``prev_key`` per lane); lanes whose popped ``(ready_t, tie_break)`` keys
-  ever violate it are flagged, their scan state is discarded, and they are
-  re-simulated through :func:`~repro.core.fastsim.simulate_fast` — the
-  identical contract to the numpy backend.  The megabatch replays no
-  order at all: it runs the **heap scan** (:func:`_heap_runner`), a
-  second lockstep scan in which each lane pops its own heap.
+  and the row-indexed ready times, predecessor counts and placements
+  ``[R, B]`` — so the shared assembly helper
+  (:func:`repro.core.replay.lane_results`) turns a launch's outputs into
+  results as it does the batchsim layout's.
+* **Bit-identical times.**  The scan runs under ``jax.enable_x64`` and does
+  exact IEEE float64 time arithmetic on int64 bit patterns
+  (:func:`_bits_add`), so on every backend — the TPU, whose own float64 is
+  inexact, included — its makespans match the reference bit for bit.  Its
+  pop is the reference heap's, ties included, so no lane can diverge.  The
+  engine is still declared at the relaxed tier
+  (:data:`repro.core.replay.JAX_RTOL`); its results meet the exact one.
+* **Bad rows fall back to the exact path.**  A lane that *live*-dispatches
+  a row the reference engine would raise on comes back flagged and is
+  re-simulated through :func:`~repro.core.fastsim.simulate_fast`, which
+  raises the reference error or completes.
 * **Fixed-bucket lane chunking.**  Lanes are evaluated in chunks padded to
   power-of-two widths (``chunk`` caps the bucket — non-power-of-two caps
   round *down* to a power of two, so the compiled width never exceeds the
   cap and the jit cache stays keyed on a handful of shapes); padding lanes
   replicate a real lane and are dropped before assembly.
 
-Beyond the per-graph protocol, two mechanisms flip the engine's cold-start
-economics:
+Two mechanisms set the engine's cold-start economics:
 
 * **Multi-graph megabatch** (:func:`simulate_jax_many`).  One heap-scan
   launch serves *every* graph family of a sweep at once: each family's
@@ -53,19 +43,10 @@ economics:
   lane.  No order is discovered, replayed or validated; the routing
   around it is :func:`repro.core.replay.simulate_many`.
 * **Persistent compile cache** (:class:`repro.core.xlacache.CompileCache`).
-  The scan runner is compiled ahead-of-time per shape signature and the
+  The heap scan is compiled ahead-of-time per shape signature and the
   serialized executable persists in the sweep's DiskCache (``xla``
   namespace), so a warm store turns the multi-second cold compile into a
-  millisecond deserialize — across processes and runs, exactly like the
-  order library.
-
-The step body's commit (pool select + slot argmin + clock/busy/seen
-update) is the pure-``lax`` form on every backend, the TPU included
-(``step_impl="auto"`` resolves to ``"lax"``).  The fused pallas kernel
-:func:`repro.kernels.lockstep_step.step_commit` stays out of the scan: the
-scan carries 64-bit state, and XLA passes no 64-bit operand into a Mosaic
-kernel, so the kernel cannot compile inside it until time fits 32 bits
-(integer ticks).  ``step_impl="pallas"`` raises with that reason.
+  millisecond deserialize — across processes and runs.
 """
 from __future__ import annotations
 
@@ -77,31 +58,22 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .devices import SystemConfig
-from .fastsim import FrozenGraph, simulate_fast
-# JAX_RTOL is re-exported here on purpose: it is this engine's tier constant.
-from .replay import (BatchStats, JAX_RTOL, Layout,  # noqa: F401
-                     MAX_RESCUE_ROUNDS, MIN_LOCKSTEP, PruneContext,
-                     RESCUE_MIN, ReplayLibrary, graph_aux, lane_results,
-                     simulate_grouped, simulate_many)
+from .fastsim import FrozenGraph
+from .replay import (BatchStats, Layout, MIN_LOCKSTEP, PruneContext,
+                     graph_aux, lane_results, simulate_many)
 from .simulator import SimResult
 from .spans import span
 from .xlacache import CompileCache
 from ..testing import faults
 
 # The jax import is deferred until the engine is actually used: importing
-# repro.core (which re-exports simulate_jax) must stay cheap and must not
+# repro.core (which re-exports the engines) must stay cheap and must not
 # load a multithreaded runtime before the exploration engine's process
 # pools pick a start method.  _jax() performs the import.
 
-#: Lanes per compiled scan chunk (the bucket cap) on the per-graph path.
-#: Chunks are padded up to power-of-two widths so the jit cache is keyed
-#: on a handful of shapes; non-power-of-two caps round down to a power of
-#: two (the effective cap), so the compiled width never exceeds the cap.
-DEFAULT_CHUNK = 64
-
-#: Lane-bucket cap for the multi-graph megabatch: wider than the per-graph
-#: default because one heap launch carries every cohort of the sweep, so
-#: the fixed per-launch overhead amortises over more lanes.
+#: Lane-bucket cap for the multi-graph megabatch: one heap launch carries
+#: every cohort of the sweep, so the fixed per-launch overhead amortises
+#: over many lanes.
 MEGABATCH_CHUNK = 256
 
 #: Heap-scan launch shapes (:func:`_heap_cohorts`): rows pad to a multiple
@@ -112,13 +84,9 @@ HEAP_ROW_ALIGN = 128
 HEAP_MIN_LANES = 32
 HEAP_MIN_COHORTS = 4
 
-#: Valid ``step_impl`` names: ``auto`` resolves to ``lax`` on every
-#: backend (the scan's 64-bit state cannot enter the Mosaic kernel — see
-#: :func:`resolve_step_impl`).
-STEP_IMPLS = ("auto", "lax")
-
-#: Fallback in-memory compile cache for bare ``simulate_jax`` calls with no
-#: Explorer-owned cache: still deduplicates compiles within the process.
+#: Fallback in-memory compile cache for bare ``simulate_jax_many`` calls
+#: with no Explorer-owned cache: still deduplicates compiles within the
+#: process.
 _MEM_COMPILE_CACHE = CompileCache()
 
 
@@ -230,32 +198,8 @@ def _bits_sub(x, y):
     return jnp.where(x >= _INF_BITS, _INF_BITS, out)
 
 
-def resolve_step_impl(step_impl: str) -> str:
-    """The step implementation the scan compiles with.
-
-    The scan state is 64-bit (the scan runs under ``jax.enable_x64``).
-    XLA refuses to pass 64-bit operands into a Mosaic (TPU pallas) kernel,
-    so the compiled kernel cannot run inside the scan on any backend:
-    ``auto`` resolves to ``lax``, and ``pallas`` raises — never a silent
-    fall back to the interpreter or to another engine."""
-    if step_impl == "pallas":
-        jax, _ = _jax()
-        backend = jax.default_backend()
-        why = (f"the backend is {backend!r}, and the compiled kernel runs "
-               f"only on a TPU" if backend != "tpu" else
-               "the scan carries 64-bit state, and a Mosaic kernel takes "
-               "no 64-bit operands")
-        raise ValueError(f"step_impl='pallas' cannot compile here: {why}; "
-                         f"use 'lax' (what 'auto' resolves to)")
-    if step_impl not in STEP_IMPLS:
-        raise ValueError(f"unknown step_impl {step_impl!r}: valid names are "
-                         + ", ".join(repr(s) for s in STEP_IMPLS))
-    return "lax"
-
-
 # ---------------------------------------------------------------------------
-# The compiled scan runners: the replay scan (the per-graph path) and the
-# heap scan (the megabatch)
+# The compiled scan: each lane pops its own heap
 # ---------------------------------------------------------------------------
 
 
@@ -265,7 +209,7 @@ def _choose(opts, cost, rt, minc, kind_pool, smp_kid, eft, aB):
     identical tie-breaks to the exact engines.  ``opts [B, K]`` / ``cost
     [B, NK]`` are each lane's own row's tables; ``minc [P, B]`` is the
     step's hoisted earliest-free-slot reduction, so each option costs a
-    [B] gather instead of its own [B, S] min.  Shared by both scans."""
+    [B] gather instead of its own [B, S] min."""
     _, jnp = _jax()
     B = aB.shape[0]
     zero = jnp.zeros((), dtype=minc.dtype)
@@ -291,8 +235,7 @@ def _choose(opts, cost, rt, minc, kind_pool, smp_kid, eft, aB):
 
 
 def _commit(clocks, busy, seen, p, rt, base, live, aB):
-    """Slot argmin + clock/busy/seen update — the step's dense tail, shared
-    by both scans."""
+    """Slot argmin + clock/busy/seen update — the step's dense tail."""
     _, jnp = _jax()
     cl = clocks[p, :, aB]                                       # [B, S]
     s = jnp.argmin(cl, axis=1)                  # first-minimum, like ref
@@ -306,129 +249,6 @@ def _commit(clocks, busy, seen, p, rt, base, live, aB):
         jnp.where(live, _bits_add(b0, _bits_sub(end, start)), b0))
     seen = seen.at[p, aB].set(seen[p, aB] | live)
     return clocks, busy, seen, end
-
-
-@functools.lru_cache(maxsize=None)
-def _runner():
-    """The pure scan function.
-
-    The step carries every time (clocks, ends, costs, busy, makespan) as
-    its float64 bit pattern in int64, with exact ``+``/``-``
-    (:func:`_bits_add`, :func:`_bits_sub`).
-
-    **Step-indexed state.**  Nothing the step writes is indexed by task
-    row.  ``ends [T+1, B]`` holds, at row ``t``, the end time step ``t``
-    left (a skipped conditional row's ready time passes through); a row's
-    ready time is *pulled* as the max over its predecessors' step
-    positions (``xs["pred"]``, padded to the slot ``T`` that stays 0).
-    ``placed [2T+1, B]`` holds, at ``2t``, the placement of step ``t``'s
-    row after the step and, at ``2t + 1``, that of its conditional parent
-    (whose device a child may decide before the parent's own step); a
-    placement is read at its latest earlier touch (``xs["at_own"]``,
-    ``xs["at_par"]``, padded to the slot ``2T`` that stays -1).  Each step
-    writes both arrays once, in place, at the shared step counter: on a
-    TPU a per-step scatter into row-indexed ``[n+1, B]`` state moves the
-    whole array through vector memory every step.  :func:`_scan_cohorts`
-    turns ``placed`` back into row placements on the host.
-
-    All shapes are multi-cohort form and **lane-aligned**: step inputs ``xs``
-    carry the lane axis directly (``[T, B, ...]`` — each lane's cohort
-    rows pre-gathered on the host by :func:`_scan_cohorts`), and per-step
-    ``valid`` masks make the task-axis padding inert.  Keeping the cohort
-    gathers out of the compiled body matters on CPU, where the scan is
-    dispatch-bound: a dozen per-step gather ops cost more than the dense
-    math they feed.  It also keeps the cohort count out of the shape
-    signature, so warm-run routing drift (cohorts splitting as orders are
-    discovered) cannot invalidate the compile cache.  Compiled
-    ahead-of-time per shape signature via
-    :class:`~repro.core.xlacache.CompileCache` (see :func:`_load_runner`).
-    """
-    jax, jnp = _jax()
-
-    def run(xs, clocks, ends, placed, busy, seen, kind_pool, smp_kid, eft):
-        # the scan's operations carry a stable name in the HLO metadata
-        with jax.named_scope("lockstep_scan"):
-            B = clocks.shape[2]
-            aB = jnp.arange(B)
-            zero = jnp.zeros((), dtype=clocks.dtype)
-
-            def step(carry, x):
-                (t, clocks, ends, placed, busy, seen, makespan, prev_rt,
-                 prev_tb, div) = carry
-                valid = x["valid"]                                  # [B]
-                # pull, not push: the row's ready time is the latest end of
-                # its predecessors, read at the steps that ran them (the
-                # pad slot T_max holds 0, the roots' ready time)
-                rt = jnp.max(ends[x["pred"], aB[:, None]], axis=1)  # [B]
-                tbv = x["tb"]
-                # heap-key monotonicity: a lane whose popped (ready_t, tb)
-                # key ever fails to strictly increase is not executing its
-                # own heap order — flag it for the exact fallback.  Invalid
-                # (padding) steps read only pad slots, so every check and
-                # write below is gated on `valid`.
-                div = div | (valid & ((rt < prev_rt)
-                                      | ((rt == prev_rt) & (tbv <= prev_tb))))
-                # (div also absorbs bad dispatches below: any lane that
-                # *live*-executes a row the reference would raise on takes
-                # the exact fallback, which re-raises — or completes when
-                # the lane never actually reaches the row under its own
-                # order)
-
-                # earliest-free slot per (pool, lane), shared by both choose
-                # passes (clocks are only committed after them)
-                minc = jnp.min(clocks, axis=1)                      # [P, B]
-
-                # ---- conditional pass-through (per-lane mask) ---------------
-                # the parent's placement as its latest earlier touch left it
-                # (pad slot 2 T_max holds -1: not placed yet)
-                has_cond = (x["c"] >= 0) & valid
-                pk = placed[x["at_par"], aB]                        # [B]
-                chosen_p = _choose(x["par_opts"], x["par_cost"], rt, minc,
-                                   kind_pool, smp_kid, eft, aB)
-                pk = jnp.where(pk < 0, chosen_p, pk)
-                live = jnp.where(has_cond, x["act"][aB, jnp.maximum(pk, 0)],
-                                 True) & valid
-
-                # ---- dispatch + commit for the lanes executing the row ------
-                k_own = placed[x["at_own"], aB]
-                und = k_own < 0
-                chosen_o = _choose(x["own_opts"], x["own_cost"], rt, minc,
-                                   kind_pool, smp_kid, eft, aB)
-                is_comp = x["is_comp"]
-                k = jnp.where(is_comp, jnp.where(und, chosen_o, k_own),
-                              x["k_first"])
-                k_row = jnp.where(is_comp & live & und, k, k_own)
-                div = div | (live & (x["bad_row"] | (k < 0)))
-                kk = jnp.maximum(k, 0)
-                p = jnp.maximum(kind_pool[aB, kk], 0)               # [B]
-                base = x["own_cost"][aB, kk]                        # [B]
-                clocks, busy, seen, end = _commit(clocks, busy, seen, p, rt,
-                                                  base, live, aB)
-                end_eff = jnp.where(live, end, jnp.where(valid, rt, zero))
-                makespan = jnp.maximum(makespan, end_eff)
-                # one in-place row write each at the shared step counter:
-                # the row's end, its placement (slot 2t) and its conditional
-                # parent's (slot 2t + 1)
-                ends = jax.lax.dynamic_update_index_in_dim(ends, end_eff, t, 0)
-                placed = jax.lax.dynamic_update_slice_in_dim(
-                    placed, jnp.stack([k_row, pk]), 2 * t, 0)
-                prev_rt = jnp.where(valid, rt, prev_rt)
-                prev_tb = jnp.where(valid, tbv, prev_tb)
-                return (t + 1, clocks, ends, placed, busy, seen, makespan,
-                        prev_rt, prev_tb, div), None
-
-            makespan = jnp.zeros((B,), dtype=clocks.dtype)
-            # a negative bit pattern sorts below every (non-negative) time
-            prev_rt = jnp.full((B,), -1, dtype=clocks.dtype)
-            prev_tb = jnp.full((B,), -1, dtype=xs["tb"].dtype)
-            div = jnp.zeros((B,), dtype=bool)
-            init = (jnp.zeros((), dtype=jnp.int32), clocks, ends, placed,
-                    busy, seen, makespan, prev_rt, prev_tb, div)
-            (_t, clocks, ends, placed, busy, seen, makespan, _rt, _tb,
-             div), _ = jax.lax.scan(step, init, xs)
-            return makespan, busy, seen, placed, div
-
-    return run
 
 
 #: ``cnt`` of a row a lane has popped: never 0 again, since a popped row
@@ -456,9 +276,10 @@ def _heap_runner():
     (int64 float64 bit patterns, the max of the popped predecessors'
     ends), ``cnt [R, B]`` (predecessors still unpopped; ``_POPPED`` once
     popped, ``_PAD_PREDS`` on padding rows) and ``placement [R, B]``,
-    beside the pool clocks, busy and seen of the replay scan.  Each step
-    pops, runs the replay step's conditional pass-through, :func:`_choose`
-    and :func:`_commit` on the popped row, then releases the row's
+    beside the pool clocks ``[P, S, B]``, busy and seen ``[P, B]``.  Each
+    step pops, runs the conditional pass-through (a conditional row whose
+    parent is not placed yet places it), :func:`_choose` and
+    :func:`_commit` on the popped row, then releases the row's
     successors with its end time.  Every row-indexed write is a dense
     ``where`` over ``[R, B]`` (the popped row's one-hot, and its successor
     bitset row expanded to a mask): on a TPU a per-step scatter into
@@ -578,26 +399,19 @@ def _code_fingerprint() -> str:
 
 
 def _signature(args: Tuple) -> Tuple:
-    """Shape/dtype signature of one runner invocation — the compile-cache
-    key body (the environment half lives in CompileCache)."""
+    """Shape/dtype signature of one heap-scan invocation — the
+    compile-cache key body (the environment half lives in CompileCache)."""
     def one(a):
         return (tuple(a.shape), str(a.dtype))
-    xs = args[0]
+    tabs = args[0]
     return (_code_fingerprint(),
-            tuple((k, one(v)) for k, v in sorted(xs.items())),
+            tuple((k, one(v)) for k, v in sorted(tabs.items())),
             tuple(one(a) for a in args[1:]))
 
 
-def _load_runner(cc: CompileCache, args: Tuple):
-    """The AOT-compiled executable for this signature: in-memory hit, disk
-    deserialize, or fresh ``lower().compile()`` (then persisted)."""
-    jax, _ = _jax()
-    return cc.load_or_compile(
-        _signature(args), lambda: jax.jit(_runner()).lower(*args))
-
-
 def _load_heap_runner(cc: CompileCache, args: Tuple):
-    """:func:`_load_runner` for the heap scan, under its own signatures."""
+    """The AOT-compiled heap-scan executable for this signature: in-memory
+    hit, disk deserialize, or fresh ``lower().compile()`` (then persisted)."""
     jax, _ = _jax()
     return cc.load_or_compile(
         ("heap",) + _signature(args),
@@ -605,7 +419,7 @@ def _load_heap_runner(cc: CompileCache, args: Tuple):
 
 
 # ---------------------------------------------------------------------------
-# Per-cohort step inputs
+# Per-cohort graph tables
 # ---------------------------------------------------------------------------
 
 
@@ -654,8 +468,7 @@ def _pool_caps(fg: FrozenGraph, kind_pool: Sequence[int],
     a lane's slot count to the cap changes nothing about its schedule.  A
     1000-slot candidate over a 64-task graph then costs a 64-wide slot
     axis, not 1024 (the canonical over-provisioned end of a co-design
-    ramp).  Every replayed order holds every row, so the cap is the
-    graph's, whatever the order.
+    ramp).
     """
     lens = np.diff(fg.dev_indptr)
     first = np.zeros(len(fg.dev_kids), dtype=bool)
@@ -668,124 +481,31 @@ def _pool_caps(fg: FrozenGraph, kind_pool: Sequence[int],
     return cap
 
 
-# Per-FrozenGraph cap on memoised (order, kind_pool) -> xs entries.  With
-# the multi-order replay library a warm sweep replays one order per
-# signature-routed cohort, so the cap matches the library's per-key order
-# cap instead of the old one-reference-order assumption.
+# Per-FrozenGraph cap on memoised pool template -> heap tables entries.
 _XS_CACHE_CAP = 32
 
-# Lane-aligned device blocks, memoised across _scan_cohorts calls: keyed by
-# content (per-cohort graph hash × order × pool template), launch dims
-# and the slice's cohort-index vector.  Entries are a few MB of device
-# arrays each; the cap bounds residency, LRU evicts.
+# Stacked device tables, memoised across _heap_cohorts calls: keyed by
+# content (per-cohort graph hash × pool template) and launch dims.
+# Entries are a few MB of device arrays each; the cap bounds residency,
+# LRU evicts.
 _DEV_XS_CACHE: "collections.OrderedDict[Tuple, Tuple]" = \
     collections.OrderedDict()
 _DEV_XS_CACHE_CAP = 16
 
 
-def _group_xs(fg: FrozenGraph, order: Sequence[int],
-              kind_pool: Sequence[int]) -> Dict[str, np.ndarray]:
-    """Per-step scan inputs shared by every lane of the cohort, in replay
-    order: row ids, tie-break scalars, conditional parents, device options
-    and cost rows for the row *and* its conditional parent (the parent's
-    placement may be decided at this step), activation-mask rows,
-    bad-dispatch flags (:func:`_bad_rows`), and the step-indexed state's
-    read positions (see :func:`_runner`): ``pred``, the steps of each
-    row's predecessors (pad ``T``); ``at_own`` / ``at_par``, the
-    ``placed`` slot of the latest earlier touch of the row / of its
-    conditional parent (pad ``2T``); ``fin [n]``, each row's last touch
-    over the whole order, which the host reads the final placements at.
-    The pads are remapped to the launch's by :func:`_scan_cohorts`.
-
-    Memoised on the FrozenGraph like :func:`~repro.core.replay.graph_aux`
-    (repeat sweeps — re-ranks, hillclimbs — replay the same order over the
-    same payload many times); dropped on pickling like ``_rt``.
-    """
-    cache = getattr(fg, "_jax_xs", None)
-    if cache is None:
-        cache = fg._jax_xs = {}
-    ckey = (tuple(order), tuple(kind_pool))
-    cached = cache.get(ckey)
-    if cached is not None:
-        return cached
-    (uids, ci, cond, dev_first, dev_opts, asets, costs, _succs,
-     _npred, is_comp, rankmaps, *_rest) = fg._runtime()
-    n = fg.n
-    tb, act_mask = graph_aux(fg, ci, rankmaps[0], asets)
-    cost_np = fg.cost
-    T = len(order)
-    K = max(1, max(len(dev_opts[i]) for i in range(n)) if n else 1)
-    n_kinds = len(fg.kinds)
-    # predecessor steps: every edge (src -> dst) as pos[src] in the row of
-    # pos[dst], in ascending column order (a replayed order is
-    # topological, so each predecessor's step comes first)
-    pos = np.empty(n, dtype=np.int64)
-    pos[np.asarray(order, dtype=np.int64)] = np.arange(T)
-    src = np.repeat(np.arange(n), np.diff(fg.succ_indptr))
-    e_dst, e_src = pos[fg.succ_rows], pos[src]
-    by = np.lexsort((e_src, e_dst))
-    e_dst, e_src = e_dst[by], e_src[by]
-    indeg = np.bincount(e_dst, minlength=T)
-    col = np.arange(len(e_dst)) - np.repeat(np.cumsum(indeg) - indeg, indeg)
-    PC = max(1, int(indeg.max(initial=0)))
-
-    xs = {
-        "r": np.empty(T, dtype=np.int32),
-        "tb": np.empty(T, dtype=np.int64),
-        "c": np.empty(T, dtype=np.int32),
-        "is_comp": np.empty(T, dtype=bool),
-        "k_first": np.empty(T, dtype=np.int32),
-        "own_opts": np.full((T, K), -1, dtype=np.int32),
-        "own_cost": np.zeros((T, n_kinds), dtype=np.float64),
-        "par_opts": np.full((T, K), -1, dtype=np.int32),
-        "par_cost": np.zeros((T, n_kinds), dtype=np.float64),
-        "act": np.zeros((T, n_kinds), dtype=bool),
-        "bad_row": _bad_rows(fg, kind_pool)[list(order)],
-        "pred": np.full((T, PC), T, dtype=np.int32),
-        "at_own": np.empty(T, dtype=np.int32),
-        "at_par": np.full(T, 2 * T, dtype=np.int32),
-    }
-    xs["pred"][e_dst, col] = e_src
-    last = [2 * T] * n                  # each row's latest `placed` slot
-    for t, r in enumerate(order):
-        xs["r"][t] = r
-        xs["tb"][t] = tb[r]
-        c = cond[r]
-        xs["c"][t] = c
-        xs["is_comp"][t] = is_comp[r]
-        xs["k_first"][t] = dev_first[r]
-        xs["own_opts"][t, :len(dev_opts[r])] = dev_opts[r]
-        xs["own_cost"][t] = cost_np[r]
-        xs["at_own"][t] = last[r]
-        last[r] = 2 * t
-        if c >= 0:
-            xs["par_opts"][t, :len(dev_opts[c])] = dev_opts[c]
-            xs["par_cost"][t] = cost_np[c]
-            xs["act"][t] = act_mask[r]
-            xs["at_par"][t] = last[c]
-            last[c] = 2 * t + 1
-    xs["fin"] = np.asarray(last, dtype=np.int32)
-    # bad-row flags capture every NaN a live dispatch could reach; scrub
-    # the rest so no masked-out lane arithmetic can produce a NaN
-    np.nan_to_num(xs["own_cost"], copy=False)
-    np.nan_to_num(xs["par_cost"], copy=False)
-    if len(cache) >= _XS_CACHE_CAP:
-        cache.pop(next(iter(cache)))
-    cache[ckey] = xs
-    return xs
-
-
 def _heap_tables(fg: FrozenGraph, kind_pool: Sequence[int]
                  ) -> Dict[str, np.ndarray]:
     """The heap scan's per-cohort graph tables (see :func:`_heap_runner`),
-    rows sorted by the heap's tie-break ``(creation_index, uid)`` (the
-    replay's ``tb``): ``npred``, ``c`` (the conditional parent's sorted
-    position, or -1), ``is_comp``, ``k_first``, ``opts [n, K]``, ``cost [n,
-    NK]``, ``act [n, NK]``, ``bad_row`` (:func:`_bad_rows`), ``succ [n,
+    rows sorted by the heap's tie-break ``(creation_index, uid)``
+    (:func:`~repro.core.replay.graph_aux`'s ``tb``): ``npred``, ``c``
+    (the conditional parent's sorted position, or -1), ``is_comp``,
+    ``k_first``, ``opts [n, K]``, ``cost [n, NK]``, ``act [n, NK]``,
+    ``bad_row`` (:func:`_bad_rows`), ``succ [n,
     W]`` (each row's successors as a bitset over sorted positions, ``W =
     ceil(n / 32)`` words), and ``pos [n]``, each row's sorted position,
     which the host reads the final placements at.  Memoised on the
-    FrozenGraph beside :func:`_group_xs`."""
+    FrozenGraph (repeat sweeps — re-ranks, hillclimbs — launch the same
+    payload many times); dropped on pickling like ``_rt``."""
     cache = getattr(fg, "_jax_xs", None)
     if cache is None:
         cache = fg._jax_xs = {}
@@ -817,7 +537,8 @@ def _heap_tables(fg: FrozenGraph, kind_pool: Sequence[int]
         "is_comp": fg.is_compute[order],
         "k_first": np.asarray(dev_first, dtype=np.int32)[order],
         "opts": opts,
-        # NaN costs are flagged as bad rows; scrub them like _group_xs
+        # bad-row flags capture every NaN a live dispatch could reach;
+        # scrub the rest so no masked-out lane arithmetic makes a NaN
         "cost": np.nan_to_num(fg.cost[order]),
         "act": act_mask[order],
         "bad_row": _bad_rows(fg, kind_pool)[order],
@@ -831,285 +552,14 @@ def _heap_tables(fg: FrozenGraph, kind_pool: Sequence[int]
 
 
 # ---------------------------------------------------------------------------
-# Cohort driver: task-axis padding, chunked lanes, shared compiled scan
+# Cohort launches: row padding, chunked lanes, one shared compiled scan
 # ---------------------------------------------------------------------------
 
 
 def _times(a: np.ndarray) -> np.ndarray:
-    """Host float64 times as the scans' int64 bit patterns (see
+    """Host float64 times as the scan's int64 bit patterns (see
     :func:`_bits_add`); other arrays unchanged."""
     return a.view(np.int64) if a.dtype == np.float64 else a
-
-
-def _repad(slots: np.ndarray, pad: int, to: int) -> np.ndarray:
-    """A cohort's state positions with its own pad slot moved to the
-    launch's."""
-    return np.where(slots == pad, to, slots)
-
-
-def _scan_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[int],
-                                          Sequence[Layout],
-                                          Optional[np.ndarray]]],
-                  policy: str, *, chunk: int,
-                  compile_cache: Optional[CompileCache] = None,
-                  step_impl: str = "auto",
-                  stats: Optional[BatchStats] = None
-                  ) -> List[Tuple[Dict[int, SimResult], List[int],
-                                  Dict[int, float]]]:
-    """Drive every lane of every ``(fg, order, layouts, cutoffs)`` cohort
-    through one shared compiled scan.
-
-    Task-axis padding layout: per-cohort step inputs (:func:`_group_xs`)
-    are stacked into ``[T_max, G, ...]`` blocks — steps beyond a cohort's
-    own length carry ``valid=False`` and read only the pad slots of the
-    step-indexed state (predecessor step ``T_max``, placement slot
-    ``2 T_max``), so they update nothing a lane reads; rows/pools/options
-    pad to the launch's maxima with inert values (``-1`` options, pad
-    predecessors, ``inf`` clocks beyond a lane's slot count).  Lanes from
-    *all* cohorts share the bucketed lane axis (``chunk`` caps the bucket;
-    padding lanes replicate the last real lane) and one slot axis sized
-    to the widest lane.
-
-    Retirement on this engine is **post-scan classification**: the
-    ``lax.scan`` trip count is fixed at trace time, so lanes cannot be
-    dropped mid-flight without recompiling — instead a cohort with a
-    finite ``cutoffs`` entry has its non-diverged lanes whose *final*
-    makespan exceeds the cutoff reported as retired (the makespan itself
-    is the bound — exact, not an estimate).  Compiled-shape reuse and the
-    ``valid`` machinery are untouched; the win is
-    protocol-level (retired lanes skip schedule materialisation and rank
-    assembly), not scan-time.
-
-    Returns one ``(done, diverged, retired)`` triple per cohort in the
-    :data:`repro.core.replay.LockstepFn` contract, positions indexing the
-    cohort's own ``layouts``; ``retired`` maps position to its bound.
-    ``stats`` receives the scan counters (``scan_lane_steps``,
-    ``scan_kept_lane_steps``, ``h2d_bytes``).
-    """
-    jax, jnp = _jax()
-    resolve_step_impl(step_impl)
-
-    cc = compile_cache if compile_cache is not None else _MEM_COMPILE_CACHE
-    eft = policy == "eft"
-
-    with span("jaxsim.prep"):
-        per = []
-        for fg, order, layouts, cuts in cohorts:
-            pool_names, _, kind_pool = layouts[0]           # template-shared
-            kinds = fg.kinds
-            caps = _pool_caps(fg, kind_pool, len(pool_names))
-            lane_counts = [lay[1] for lay in layouts]
-            per.append({
-                "fg": fg, "xs": _group_xs(fg, order, kind_pool), "cuts": cuts,
-                "pool_names": pool_names, "kind_pool": list(kind_pool),
-                "smp_kid": kinds.index("smp") if "smp" in kinds else -1,
-                "lane_counts": lane_counts,
-                # slot-axis need per lane: pool slot counts clamped to the
-                # dispatch caps (exact — see _pool_caps)
-                "needs": [max(1, max((min(int(c), int(caps[p]))
-                                      for p, c in enumerate(cnt)), default=1))
-                          for cnt in lane_counts],
-                "P": len(pool_names),
-            })
-        G = len(per)
-        P_max = max(c["P"] for c in per)
-        T_max = max(len(c["xs"]["r"]) for c in per)
-        K = max(c["xs"]["own_opts"].shape[1] for c in per)
-        NK = max(len(c["kind_pool"]) for c in per)
-        PC = max(c["xs"]["pred"].shape[1] for c in per)
-        for c in per:
-            c["fin"] = _repad(c["xs"]["fin"], 2 * len(c["xs"]["r"]),
-                              2 * T_max)
-        S = _bucket(max(nd for c in per for nd in c["needs"]), cap=1 << 30)
-
-        kind_pool_m = np.full((G, NK), -1, dtype=np.int32)
-        smp_kid_m = np.full((G,), -1, dtype=np.int32)
-        for gi, c in enumerate(per):
-            nk = len(c["kind_pool"])
-            kind_pool_m[gi, :nk] = c["kind_pool"]
-            smp_kid_m[gi] = c["smp_kid"]
-
-        _mega_memo: List[Optional[Dict[str, np.ndarray]]] = [None]
-
-        def _mega() -> Dict[str, np.ndarray]:
-            """The ``[T_max, G, ...]`` task-axis-padded step-input stack —
-            built lazily: a warm repeat sweep whose slices all hit the device
-            cache never stacks it at all."""
-            if _mega_memo[0] is not None:
-                return _mega_memo[0]
-            mega = {
-                "valid": np.zeros((T_max, G), dtype=bool),
-                "tb": np.zeros((T_max, G), dtype=np.int64),
-                "c": np.full((T_max, G), -1, dtype=np.int32),
-                "is_comp": np.zeros((T_max, G), dtype=bool),
-                "k_first": np.zeros((T_max, G), dtype=np.int32),
-                "own_opts": np.full((T_max, G, K), -1, dtype=np.int32),
-                "own_cost": np.zeros((T_max, G, NK), dtype=np.float64),
-                "par_opts": np.full((T_max, G, K), -1, dtype=np.int32),
-                "par_cost": np.zeros((T_max, G, NK), dtype=np.float64),
-                "act": np.zeros((T_max, G, NK), dtype=bool),
-                "bad_row": np.zeros((T_max, G), dtype=bool),
-                "pred": np.full((T_max, G, PC), T_max, dtype=np.int32),
-                "at_own": np.full((T_max, G), 2 * T_max, dtype=np.int32),
-                "at_par": np.full((T_max, G), 2 * T_max, dtype=np.int32),
-            }
-            for gi, c in enumerate(per):
-                xs = c["xs"]
-                T = len(xs["r"])
-                kg, nk, pc = (xs["own_opts"].shape[1], xs["own_cost"].shape[1],
-                              xs["pred"].shape[1])
-                mega["valid"][:T, gi] = True
-                for f in ("tb", "c", "is_comp", "k_first", "bad_row"):
-                    mega[f][:T, gi] = xs[f]
-                mega["own_opts"][:T, gi, :kg] = xs["own_opts"]
-                mega["par_opts"][:T, gi, :kg] = xs["par_opts"]
-                mega["own_cost"][:T, gi, :nk] = xs["own_cost"]
-                mega["par_cost"][:T, gi, :nk] = xs["par_cost"]
-                mega["act"][:T, gi, :nk] = xs["act"]
-                # each cohort's own pad slots (T, 2T) -> the launch's
-                mega["pred"][:T, gi, :pc] = _repad(xs["pred"], T, T_max)
-                for f in ("at_own", "at_par"):
-                    mega[f][:T, gi] = _repad(xs[f], 2 * T, 2 * T_max)
-            _mega_memo[0] = mega
-            return mega
-
-        # cache key prefix for the lane-aligned device blocks: content-based
-        # (graph hash × order × pool template per cohort), so repeat sweeps
-        # hit it across fresh Explorers — a warm re-rank re-launches resident
-        # device blocks without re-stacking or re-transferring anything
-        base_key = (tuple((c["fg"].content_hash(), tuple(c["xs"]["r"]),
-                           tuple(c["kind_pool"])) for c in per),
-                    (T_max, P_max, K, NK, PC),
-                    kind_pool_m.tobytes(), smp_kid_m.tobytes())
-
-        lanes_flat = [(gi, pos) for gi, c in enumerate(per)
-                      for pos in range(len(c["lane_counts"]))]
-        accs = [{"kept": [], "mk": [], "busy": [], "seen": [], "place": []}
-                for _ in per]
-        diverged: List[List[int]] = [[] for _ in per]
-        retired: List[Dict[int, float]] = [{} for _ in per]
-        # the effective power-of-two slice width
-        step = _bucket(chunk, cap=chunk)
-        slices = [lanes_flat[lo:lo + step]
-                  for lo in range(0, len(lanes_flat), step)]
-
-    with jax.enable_x64(True):
-        def _put(a: np.ndarray):
-            """One host-to-device upload, counted in ``h2d_bytes``."""
-            if stats is not None:
-                stats.h2d_bytes += a.nbytes
-            return jnp.asarray(a)
-
-        # lane-aligned step inputs per distinct cohort-index vector: the
-        # host gathers [T, G, ...] -> [T, B, ...] once per slice shape so
-        # the compiled body carries no gather ops (and no G in its shape
-        # signature).  The device blocks are memoised across calls
-        # (module-level LRU): per-graph chunking reuses one upload across
-        # its equal-width slices, and warm repeat sweeps re-launch the
-        # resident blocks without re-stacking or re-transferring anything.
-        def _lane_aligned(g_np: np.ndarray) -> Tuple:
-            key = (base_key, g_np.tobytes())
-            hit = _DEV_XS_CACHE.get(key)
-            if hit is None:
-                mega = _mega()
-                host = {k: _times(np.ascontiguousarray(v[:, g_np]))
-                        for k, v in mega.items()}
-                with span("jaxsim.transfer"):
-                    hit = ({k: _put(v) for k, v in host.items()},
-                           _put(kind_pool_m[g_np]), _put(smp_kid_m[g_np]))
-                if len(_DEV_XS_CACHE) >= _DEV_XS_CACHE_CAP:
-                    _DEV_XS_CACHE.popitem(last=False)
-                _DEV_XS_CACHE[key] = hit
-            else:
-                _DEV_XS_CACHE.move_to_end(key)
-            return hit
-
-        launched = []
-        for sl in slices:
-            with span("jaxsim.prep"):
-                B = _bucket(len(sl), cap=chunk)
-                # pad lanes replicate the last real lane: finite,
-                # well-defined state whose results are simply dropped
-                # before assembly
-                padded = sl + [sl[-1]] * (B - len(sl))
-                g_np = np.fromiter((gi for gi, _ in padded), dtype=np.int32,
-                                   count=B)
-                clocks = np.full((P_max, S, B), np.inf)
-                for li, (gi, pos) in enumerate(padded):
-                    for p, cnt in enumerate(per[gi]["lane_counts"][pos]):
-                        clocks[p, :cnt, li] = 0.0
-                xs_j, kp_j, sk_j = _lane_aligned(g_np)
-                with span("jaxsim.transfer"):
-                    args = (xs_j, _put(_times(clocks)),
-                            # ends, placed, busy, seen: made on device
-                            jnp.zeros((T_max + 1, B), dtype=jnp.int64),
-                            jnp.full((2 * T_max + 1, B), -1, dtype=jnp.int32),
-                            jnp.zeros((P_max, B), dtype=jnp.int64),
-                            jnp.zeros((P_max, B), dtype=bool),
-                            kp_j, sk_j, _put(np.asarray(eft)))
-                exe = _load_runner(cc, args)
-            if stats is not None:
-                stats.scan_lane_steps += B * T_max
-            with span("jaxsim.launch"):
-                makespan, busy, seen, placed, div = exe(*args)
-                launched.append((sl, np.asarray(div),
-                                 np.asarray(makespan).view(np.float64),
-                                 np.asarray(busy).view(np.float64),
-                                 np.asarray(seen), np.asarray(placed)))
-
-    with span("replay.assemble"):
-        for sl, div_np, mk_np, busy_np, seen_np, place_np in launched:
-            for li, (gi, pos) in enumerate(sl):
-                if div_np[li]:
-                    diverged[gi].append(pos)
-                    continue
-                acc, c = accs[gi], per[gi]
-                cuts = c["cuts"]
-                if cuts is not None and mk_np[li] > cuts[pos]:
-                    # post-scan retirement: the final makespan is its own
-                    # (exact) bound, and it exceeds the incumbent cutoff
-                    retired[gi][pos] = float(mk_np[li])
-                    continue
-                acc["kept"].append(pos)
-                acc["mk"].append(mk_np[li:li + 1])
-                acc["busy"].append(busy_np[:c["P"], li:li + 1])
-                acc["seen"].append(seen_np[:c["P"], li:li + 1])
-                # each row's placement at its last touch (see _group_xs)
-                acc["place"].append(place_np[c["fin"], li:li + 1])
-        results: List[Tuple[Dict[int, SimResult], List[int],
-                            Dict[int, float]]] = []
-        for gi, c in enumerate(per):
-            acc = accs[gi]
-            if stats is not None:
-                stats.scan_kept_lane_steps += \
-                    len(acc["kept"]) * len(c["xs"]["r"])
-            done: Dict[int, SimResult] = {}
-            if acc["kept"]:
-                done = lane_results(
-                    c["fg"], c["pool_names"], c["lane_counts"], acc["kept"],
-                    policy, np.concatenate(acc["mk"]),
-                    np.concatenate(acc["busy"], axis=1),
-                    np.concatenate(acc["seen"], axis=1),
-                    np.concatenate(acc["place"], axis=1).astype(np.int64))
-            results.append((done, diverged[gi], retired[gi]))
-    return results
-
-
-def _scan_group(fg: FrozenGraph, order: Sequence[int],
-                layouts: Sequence[Layout], policy: str,
-                cutoffs: Optional[np.ndarray] = None, *,
-                chunk: int = DEFAULT_CHUNK,
-                compile_cache: Optional[CompileCache] = None,
-                step_impl: str = "auto",
-                stats: Optional[BatchStats] = None
-                ) -> Tuple[Dict[int, SimResult], List[int],
-                           Dict[int, float]]:
-    """One-cohort form of :func:`_scan_cohorts` — the per-graph
-    :data:`repro.core.replay.LockstepFn`."""
-    (triple,) = _scan_cohorts([(fg, order, layouts, cutoffs)], policy,
-                              chunk=chunk, compile_cache=compile_cache,
-                              step_impl=step_impl, stats=stats)
-    return triple
 
 
 def _heap_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[Layout],
@@ -1137,10 +587,11 @@ def _heap_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[Layout],
 
     Returns one ``(done, flagged, retired)`` triple per cohort, positions
     indexing its ``layouts``: ``flagged`` lanes met a row the reference
-    raises on (or a deadlock) and take the exact path; retirement is
-    post-scan classification against ``cutoffs``, as in
-    :func:`_scan_cohorts`.  ``stats`` receives ``heap_lane_steps`` and
-    ``heap_h2d_bytes``.
+    raises on (or a deadlock) and take the exact path.  Retirement is
+    **post-scan classification**: the ``lax.scan`` trip count is fixed at
+    trace time, so a lane whose *final* makespan exceeds its cutoff is
+    reported as retired, with the makespan as its (exact) bound.
+    ``stats`` receives ``heap_lane_steps`` and ``heap_h2d_bytes``.
     """
     if not cohorts:
         return []
@@ -1291,56 +742,6 @@ def _heap_cohorts(cohorts: Sequence[Tuple[FrozenGraph, Sequence[Layout],
 # ---------------------------------------------------------------------------
 
 
-def simulate_jax(fg: FrozenGraph, systems: Sequence[SystemConfig],
-                 policy: str = "availability", *,
-                 min_lockstep: int = MIN_LOCKSTEP,
-                 chunk: int = DEFAULT_CHUNK,
-                 stats: Optional[BatchStats] = None,
-                 library: Optional[ReplayLibrary] = None,
-                 max_rounds: int = MAX_RESCUE_ROUNDS,
-                 rescue_min: int = RESCUE_MIN,
-                 compile_cache: Optional[CompileCache] = None,
-                 step_impl: str = "auto",
-                 prune: Optional[PruneContext] = None):
-    """Schedule-free :class:`SimResult` per system, in input order.
-
-    The jax tier of :func:`repro.core.batchsim.simulate_batch`: equivalent
-    to ``[simulate_fast(fg, s, policy) for s in systems]`` at
-    :data:`~repro.core.replay.JAX_RTOL` relative makespan/busy error with
-    identical placements, and ranking-stable under the documented
-    tie-break.  Grouping, multi-order library replay (``library`` —
-    orders are engine-agnostic: they are recorded by the exact serial
-    path and each lane re-validates in-scan, so a batch-warmed library
-    serves this engine unchanged) and the per-lane exact fallback are the
-    shared :mod:`repro.core.replay` protocol; ``chunk`` caps the compiled
-    lane-bucket width (non-power-of-two caps round down to a power of
-    two).  ``compile_cache`` persists compiled executables (default: a
-    process-local in-memory cache); ``step_impl`` picks the step-commit
-    implementation (see :data:`STEP_IMPLS`).
-
-    ``prune`` enables in-flight lane retirement
-    (:class:`~repro.core.replay.PruneContext`): lanes whose makespan
-    exceeds the inflated incumbent cutoff come back as
-    :class:`~repro.core.replay.Retired` markers instead of results.
-    Cutoffs on this engine are pre-inflated by the
-    :data:`~repro.core.replay.JAX_RTOL` tolerance so sub-rtol ties never
-    retire.
-    """
-    if chunk < 1:
-        raise ValueError(f"chunk must be >= 1, got {chunk!r}")
-    resolve_step_impl(step_impl)               # fail fast on bad names
-
-    def lockstep(fg, order, layouts, policy, cutoffs=None):
-        return _scan_group(fg, order, layouts, policy, cutoffs, chunk=chunk,
-                           compile_cache=compile_cache, step_impl=step_impl,
-                           stats=stats)
-
-    return simulate_grouped(fg, systems, policy, min_lockstep=min_lockstep,
-                            stats=stats, library=library,
-                            max_rounds=max_rounds, rescue_min=rescue_min,
-                            lockstep_fn=lockstep, prune=prune)
-
-
 def simulate_jax_many(items: Sequence[Tuple[FrozenGraph,
                                             Sequence[SystemConfig]]],
                       policy: str = "availability", *,
@@ -1358,7 +759,7 @@ def simulate_jax_many(items: Sequence[Tuple[FrozenGraph,
     to ``simulate_fast``; smaller groups and flagged lanes take the exact
     serial path (:func:`repro.core.replay.simulate_many`).  No order is
     discovered or replayed, so no ``ReplayLibrary`` is involved.
-    ``chunk`` caps the launch's lane bucket and defaults to the wider
+    ``chunk`` caps the launch's lane bucket and defaults to
     :data:`MEGABATCH_CHUNK`.
     """
     chunk = MEGABATCH_CHUNK if chunk is None else chunk

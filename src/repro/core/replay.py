@@ -1,8 +1,7 @@
-"""Order-replay machinery shared by the candidate-axis engines.
+"""Order-replay machinery and routing for the candidate-axis engines.
 
-Both lockstep backends (:mod:`repro.core.batchsim` — numpy;
-:mod:`repro.core.jaxsim` — a jit-compiled ``jax.lax.scan``) run the same
-protocol around their inner sweep:
+The numpy lockstep backend (:mod:`repro.core.batchsim`, the batch engine)
+runs this protocol around its inner sweep:
 
 1. **Group** the candidate systems by *pool template* (pool names/kinds and
    the kind→pool map; slot counts are free to vary inside a group) — lanes
@@ -37,11 +36,12 @@ for such a lane is skipping it out of a doomed lockstep, not vectorising
 it.
 
 This module owns the protocol (grouping, order selection, rescue, fallback,
-per-lane result assembly, the per-graph auxiliary constants) so the two
-backends can never disagree on it; each backend supplies only the inner
-``lockstep_fn`` that advances the stacked per-candidate state.  The jax
-megabatch (:func:`simulate_many`) runs none of the replay protocol: every
-lane of a group large enough goes to the heap scan, which needs no order.
+per-lane result assembly, the per-graph auxiliary constants); a backend
+supplies only the inner ``lockstep_fn`` that advances the stacked
+per-candidate state.  The jax engine (:func:`simulate_many`, its only
+path) runs none of the replay protocol: every lane of a group large enough
+goes to the heap scan (:mod:`repro.core.jaxsim`), which needs no order,
+and it shares the grouping, the exact fallback and the result assembly.
 
 It also owns the **engine equivalence tiers**: the exact engines
 (``fast``/``batch``) are pinned bit-identical to the reference object
@@ -50,10 +50,10 @@ engine, while the jax engine is pinned at ``rtol``-level
 deterministically by candidate submission order).  :func:`sims_equivalent`
 and :func:`rankings_equivalent` are the single implementation of those
 contracts, used by the test suite and the fig6 benchmark asserts alike.
-Cached orders are **tier-agnostic**: every order is recorded by the exact
-serial path, and each backend re-validates every lane against it, so a
-library warmed by the batch engine serves the jax engine unchanged (and
-vice versa) without laundering rtol results into the exact tier.
+Cached orders are exact: every order is recorded by the exact serial
+path, and the batch engine re-validates every lane against it.  The jax
+engine neither reads nor writes the library, so a library shared across
+Explorers of both engines serves the batch engine alone.
 """
 from __future__ import annotations
 
@@ -181,15 +181,10 @@ class BatchStats:
     :class:`Incumbent` trackers (local and worker-side).
 
     Scan counters (the jax engine only; zero elsewhere):
-    ``scan_lane_steps`` sums lanes × steps over every scan launch, the
-    work the device was asked for, padding lanes and padding steps
-    included; ``scan_kept_lane_steps`` sums, over the lanes whose scan
-    result was kept (neither diverged nor retired), the steps of their own
-    cohort's order; ``h2d_bytes`` counts the bytes uploaded host→device
-    for the scan (step-input blocks on a device-cache miss, and the
-    arguments each launch builds on the host).  ``heap_lane_steps`` and
-    ``heap_h2d_bytes`` are the same two sums for the heap scan's launches
-    (its stacked graph tables on a device-cache miss, and each launch's
+    ``heap_lane_steps`` sums lanes × steps over every heap-scan launch,
+    the work the device was asked for, padding lanes and padding rows
+    included; ``heap_h2d_bytes`` counts the bytes uploaded host→device for
+    it (its stacked graph tables on a device-cache miss, and each launch's
     lane arguments).
     """
 
@@ -205,6 +200,9 @@ class BatchStats:
     retired_lanes: int = 0
     retire_sweeps: int = 0
     incumbent_updates: int = 0
+    # Nothing writes these three any more; the benchmark's layer reader
+    # (bench/layers.py) still reads them, so they stay, always 0, until it
+    # stops.
     scan_lane_steps: int = 0
     scan_kept_lane_steps: int = 0
     h2d_bytes: int = 0
@@ -1071,7 +1069,7 @@ def simulate_many(items: Sequence[Tuple[FrozenGraph,
     (``small_group_lanes``), and so do the lanes the heap scan flags (a
     row the reference raises on: ``serial_fallback_lanes``).  Nothing is
     read from or written to a :class:`ReplayLibrary`: the library stays
-    with the batch engine and the per-graph jax path.
+    with the batch engine.
 
     Every completion is a heap lane (bit-identical by construction) or an
     exact serial run, so the engine tiers are preserved.  Returns one

@@ -5,12 +5,9 @@
 #   flash_attention — fused causal/windowed/softcapped GQA attention (prefill)
 #   linear_attn     — chunked decayed linear attention (RWKV6 / Mamba2 / GLA)
 #   cholesky_tiles  — syrk / trsm tile kernels of the Fig. 4 Cholesky
-#   lockstep_step   — fused step-commit of the jaxsim candidate-axis scan
 #
 # All kernels are written against pl.pallas_call + explicit BlockSpec VMEM
 # tiling for TPU v5e and validated on CPU with interpret=True.
-# lockstep_step is imported lazily by repro.core.jaxsim (not via ops) so
-# the core simulator keeps its gated jax dependency.
 from . import ops, ref
 
 __all__ = ["ops", "ref"]

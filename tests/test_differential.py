@@ -3,7 +3,7 @@
 One randomized generator (via hypothesis, or the deterministic
 ``repro.testing.minihypothesis`` stand-in that prints the falsifying
 example when the real package is absent) drives the same candidate sets
-through reference/fast/batch — and jax (+ megabatch) where available —
+through reference/fast/batch — and jax where available —
 and asserts tier-correct equivalence of both the scalar rankings and the
 Pareto frontiers:
 
@@ -170,18 +170,17 @@ def test_pruned_column_rtol_stable_on_jax(seed):
     _, ref = _run("batch", world)
     ref_names = [o.name for o in ref.ranked]
     ref_spans = {o.name: o.makespan_s for o in ref.ranked}
-    for megabatch in (True, False):
-        ex, got = _run("jax", world, prune=True, jax_megabatch=megabatch)
-        assert ex.engine == "jax"
-        names = [o.name for o in got.ranked]
-        assert rankings_equivalent(names[:3], ref_names[:3], ref_spans,
-                                   JAX_RTOL)
-        if ref.objectives is not None:
-            ref_objs = {o.name: o.objectives for o in ref.ranked}
-            assert frontiers_equivalent(
-                [o.name for o in got.frontier],
-                [o.name for o in ref.frontier],
-                ref_objs, ref.objectives, JAX_RTOL)
+    ex, got = _run("jax", world, prune=True)
+    assert ex.engine == "jax"
+    names = [o.name for o in got.ranked]
+    assert rankings_equivalent(names[:3], ref_names[:3], ref_spans,
+                               JAX_RTOL)
+    if ref.objectives is not None:
+        ref_objs = {o.name: o.objectives for o in ref.ranked}
+        assert frontiers_equivalent(
+            [o.name for o in got.frontier],
+            [o.name for o in ref.frontier],
+            ref_objs, ref.objectives, JAX_RTOL)
 
 
 # ---------------------------------------------------------------------------
@@ -198,27 +197,26 @@ def test_jax_tier_ranking_and_frontier_stable(seed):
     ref_spans = {o.name: o.makespan_s for o in ref.ranked}
     ref_objs = {o.name: o.objectives for o in ref.ranked}
     axes = ref.objectives or ["makespan_s"]
-    for megabatch in (True, False):
-        ex, got = _run("jax", world, jax_megabatch=megabatch)
-        assert ex.engine == "jax"
-        # same candidates survived (power/area budgets are static, so
-        # feasibility can never be tier-dependent here)
-        assert sorted(o.name for o in got.ranked) == sorted(ref_names)
-        assert rankings_equivalent([o.name for o in got.ranked],
-                                   ref_names, ref_spans, JAX_RTOL)
-        if ref.objectives is not None:
-            assert frontiers_equivalent(
-                [o.name for o in got.frontier],
-                [o.name for o in ref.frontier],
-                ref_objs, axes, JAX_RTOL)
-        # placements/discrete structure are exact even at the rtol tier:
-        # area and peak power are spec arithmetic and must be identical
-        for o in got.ranked:
-            if o.objectives is not None:
-                assert o.objectives["area_mm2"] == \
-                    ref_objs[o.name]["area_mm2"]
-                assert o.objectives["power_w"] == \
-                    ref_objs[o.name]["power_w"]
+    ex, got = _run("jax", world)
+    assert ex.engine == "jax"
+    # same candidates survived (power/area budgets are static, so
+    # feasibility can never be tier-dependent here)
+    assert sorted(o.name for o in got.ranked) == sorted(ref_names)
+    assert rankings_equivalent([o.name for o in got.ranked],
+                               ref_names, ref_spans, JAX_RTOL)
+    if ref.objectives is not None:
+        assert frontiers_equivalent(
+            [o.name for o in got.frontier],
+            [o.name for o in ref.frontier],
+            ref_objs, axes, JAX_RTOL)
+    # placements/discrete structure are exact even at the rtol tier:
+    # area and peak power are spec arithmetic and must be identical
+    for o in got.ranked:
+        if o.objectives is not None:
+            assert o.objectives["area_mm2"] == \
+                ref_objs[o.name]["area_mm2"]
+            assert o.objectives["power_w"] == \
+                ref_objs[o.name]["power_w"]
 
 
 # ---------------------------------------------------------------------------

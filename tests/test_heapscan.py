@@ -1,5 +1,5 @@
-"""The heap scan: lanes that no replayed order serves, completed on the
-device with each lane popping its own heap.
+"""The heap scan: the jax engine's lanes, completed on the device with
+each lane popping its own heap.
 
 ``jaxsim._heap_cohorts`` runs every lane's own list schedule in one
 lockstep scan: each step pops the lane's minimum of ``(ready time,
@@ -8,7 +8,7 @@ so it needs no order and no validation.  This file pins it bit for bit to
 ``simulate_fast`` on seeded random graphs, checks that
 ``replay.simulate_many`` routes every lane of a jax megabatch sweep there
 (every group with at least ``min_lockstep`` lanes; smaller groups take the
-exact path) with no discovery, no replay launch and no library, and that a
+exact path) with no discovery, no replayed order and no library, and that a
 sweep through ``Explorer`` ranks the paper's applications identically on
 every engine.
 """
@@ -92,6 +92,91 @@ HEAP_CASES = {
         (_random_dag(3, 150), _two_pools(2, 3)),
         (_random_dag(4, 7, zero_share=0.8), _two_pools(3, 2))]),
 }
+
+
+def _dag(n, edges):
+    """A bare DAG over two device kinds with the given row edges."""
+    g = TaskGraph()
+    uids = []
+    for i in range(n):
+        kinds = ("a", "b") if i % 3 else ("b", "a")
+        t = Task(uid=g.new_uid(), name=f"t{i}", devices=kinds,
+                 costs={"a": 0.5 + (i % 5) * 0.25, "b": 1.0 + (i % 3) * 0.5},
+                 creation_index=i, meta={"role": "compute"})
+        g.add_task(t, infer_deps=False)
+        uids.append(t.uid)
+    for a, b in edges:
+        g.add_edge(uids[a], uids[b])
+    return FrozenGraph.freeze(g)
+
+
+def _skip_graph(units=3):
+    """A chain of units ``r -> d -> x -> z``: ``d`` is a transfer
+    conditional on the compute ``x``, active only when ``x`` runs on
+    ``a``.  Under ``eft`` the SMP wins ``x`` (0.5 against 2.0), so ``d``
+    decides ``x``'s device at its own pop, is skipped, and passes its
+    ready time on to ``x``."""
+    g = TaskGraph()
+    prev = None
+
+    def add(name, devices, costs, ci, **meta):
+        t = Task(uid=g.new_uid(), name=name, devices=devices, costs=costs,
+                 creation_index=ci, meta=meta)
+        g.add_task(t, infer_deps=False)
+        return t.uid
+
+    for u in range(units):
+        r = add(f"r{u}", ("b",), {"b": 1.0}, 4 * u, role="compute")
+        x = add(f"x{u}", ("a", "smp"), {"a": 2.0, "smp": 0.5}, 4 * u + 2,
+                role="compute")
+        d = add(f"d{u}", ("d",), {"d": 0.1}, 4 * u + 1, role="submit_in",
+                conditional_on=x, active_kinds=("a",))
+        z = add(f"z{u}", ("b",), {"b": 1.0}, 4 * u + 3, role="compute")
+        if prev is not None:
+            g.add_edge(prev, r)
+        for a, b in ((r, d), (d, x), (x, z)):
+            g.add_edge(a, b)
+        prev = z
+    fg = FrozenGraph.freeze(g)
+    systems = [SystemConfig(name=f"s{i}{j}{k}",
+                            pools=[DevicePool("smp", ("smp",), k),
+                                   DevicePool("pa", ("a",), i),
+                                   DevicePool("pb", ("b",), j),
+                                   DevicePool("pd", ("d",), 1)],
+                            shared=[SharedResource("x", 1)])
+               for i in (1, 2) for j in (1, 2, 3) for k in (1, 2)]
+    return [(fg, systems)]
+
+
+#: Nine independent rows joined by one, which three rows follow.
+_FAN_IN = [(i, 9) for i in range(9)] + [(9, j) for j in (10, 11, 12)]
+
+#: Graph shapes, each under both policies: the first policy named is the
+#: one the shape was first pinned under.
+_SHAPES = {
+    # every row a root: all ready at once, the tie-break orders every pop
+    "roots": ("availability", lambda: [(_dag(12, []), _two_pools(3, 3))]),
+    # the join row waits on nine predecessors
+    "widest_preds": ("availability",
+                     lambda: [(_dag(13, _FAN_IN), _two_pools(3, 3))]),
+    # conditional rows skipped: they pass their ready time through
+    "skipped_conditional": ("eft", _skip_graph),
+    # a DMA-in child decides its compute parent's device before the
+    # parent's own pop
+    "parent_placed_early": ("availability", lambda: [
+        (frozen_for(synth_trace(16), smp=True)[0], _ramp(range(2, 9)))]),
+    # one launch holds lanes of cohorts with different row counts
+    "mixed_lengths": ("availability", lambda: [
+        (frozen_for(synth_trace(10), smp=True)[0], _ramp(range(1, 7))),
+        (frozen_for(synth_trace(17), smp=False)[0], _ramp(range(1, 7), "b"))]),
+    # the launch is wider than the lanes it carries
+    "padding_lanes": ("availability", lambda: [
+        (frozen_for(synth_trace(12), smp=False)[0], _ramp(range(1, 6)))]),
+}
+for _name, (_policy, _build) in _SHAPES.items():
+    _other = "eft" if _policy == "availability" else "availability"
+    HEAP_CASES[_name] = (_policy, _build)
+    HEAP_CASES[f"{_name}_{_other}"] = (_other, _build)
 
 
 def _heap_run(items, policy, cut_frac=None, chunk=256):
@@ -265,15 +350,6 @@ def _group_sizes(items):
     return sizes
 
 
-def _no_replay_launch(monkeypatch):
-    """Make a replay-scan (``jit_run``) launch fail the test."""
-    from repro.core import jaxsim
-
-    def refuse(*_a, **_k):
-        raise AssertionError("the megabatch launched the replay scan")
-    monkeypatch.setattr(jaxsim, "_load_runner", refuse)
-
-
 def _assert_equals_batch(items, res, policy):
     """Every lane's result equals the batch engine's bit for bit."""
     for (fg, systems), sims in zip(items, res):
@@ -285,13 +361,12 @@ def _assert_equals_batch(items, res, policy):
 
 @pytest.mark.parametrize("min_lockstep", [2, 4, 13, 64])
 def test_routing_sends_groups_with_enough_unserved_lanes_to_the_heap(
-        min_lockstep, monkeypatch):
+        min_lockstep):
     """Every lane of a group with at least ``min_lockstep`` lanes
     completes in the heap scan (counted as a lockstep lane), with no
-    discovery, no replay launch and no divergence; a smaller group takes
+    discovery and no divergence; a smaller group takes
     the exact serial path.  Every lane is classified once and every result
     equals the batch engine's."""
-    _no_replay_launch(monkeypatch)
     items = _ramp_items()
     stats = BatchStats()
     res = simulate_jax_many(items, "availability", min_lockstep=min_lockstep,
@@ -308,7 +383,7 @@ def test_routing_sends_groups_with_enough_unserved_lanes_to_the_heap(
     _assert_equals_batch(items, res, "availability")
 
 
-def test_heap_lanes_write_nothing_to_the_library(monkeypatch):
+def test_heap_lanes_write_nothing_to_the_library():
     """A jax megabatch sweep neither reads nor writes the order library:
     cold, it leaves the library empty; warmed by the batch engine, it
     leaves it as it was and routes no lane by it.  Either way every lane
@@ -317,7 +392,6 @@ def test_heap_lanes_write_nothing_to_the_library(monkeypatch):
     kw = dict(smp_seconds_fn=smp_fn, budget={})
     lib = ReplayLibrary()
     cold = Explorer(trace, reports, engine="jax", order_library=lib, **kw)
-    _no_replay_launch(monkeypatch)
     got_cold = cold.explore(cands)
     assert len(lib) == 0 and lib.counts()["orders"] == 0
     batch = Explorer(trace, reports, engine="batch", order_library=lib, **kw)
@@ -364,12 +438,11 @@ def test_a_jax_sweep_pins_nothing_for_the_batch_engine():
         [(o.name, o.makespan_s) for o in ref.ranked]
 
 
-def test_over_budget_fallbacks_take_the_heap(monkeypatch):
+def test_over_budget_fallbacks_take_the_heap():
     """The discovery budget bounds only the library protocol: a jax
     megabatch sweep with no budget (``max_rescue_rounds=0``) routes as one
     with the default budget, every lane to the heap scan, nothing serial,
     and ranks as the batch engine does under ``eft``."""
-    _no_replay_launch(monkeypatch)
     trace, reports, cands, smp_fn = _matmul(256, 64, range(1, 9))
     kw = dict(smp_seconds_fn=smp_fn, budget={}, policy="eft")
     ref = Explorer(trace, reports, engine="batch", **kw).explore(cands)
@@ -381,7 +454,7 @@ def test_over_budget_fallbacks_take_the_heap(monkeypatch):
         st = ex.batch_stats
         assert st.heap_lanes == len(cands)
         assert st.reference_lanes == st.serial_fallback_lanes == 0
-        assert st.scan_lane_steps == 0                 # no replay launch
+        assert st.scan_lane_steps == 0
         counts.append(st.as_dict())
         assert [(o.name, o.makespan_s) for o in res.ranked] == \
             [(o.name, o.makespan_s) for o in ref.ranked]
@@ -434,12 +507,10 @@ def test_prune_megabatch_seeds_serially_then_retires_heap_lanes_exactly():
                                              min_lockstep=2))
 
 
-def test_mixed_sweep_sends_the_small_group_serial_and_the_large_to_the_heap(
-        monkeypatch):
+def test_mixed_sweep_sends_the_small_group_serial_and_the_large_to_the_heap():
     """One group below ``min_lockstep`` and one above, in one sweep: the
     small group runs on the exact serial path, the large one in the heap
     scan, and every result is bit-identical to the exact reference."""
-    _no_replay_launch(monkeypatch)
     fg1, g1 = frozen_for(synth_trace(30), smp=True)
     fg2, g2 = frozen_for(synth_trace(18), smp=False)
     items = [(fg1, _ramp(range(1, 5), "s")), (fg2, _ramp(range(1, 13), "l"))]

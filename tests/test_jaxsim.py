@@ -1,13 +1,16 @@
-"""The jax scan engine's tier contract, and the exactness of everyone else.
+"""The jax scan engine's results, and the exactness of everyone else.
 
-``repro.core.jaxsim`` is pinned at the relaxed equivalence tier
+``repro.core.jaxsim`` is declared at the relaxed equivalence tier
 (``JAX_RTOL`` relative makespan/busy error, discrete-identical placements,
 ranking-stable under the documented tie-break), while ``fastsim`` and
 ``batchsim`` stay bit-identical to the reference object engine.  Both
-contracts live in ``repro.core.replay`` and both are enforced here — the
-regression half of this file exists so a future change can never silently
-launder rtol-level results into the exact engines (through the sim caches
-or otherwise).
+contracts live in ``repro.core.replay`` and both are enforced here.  The
+jax engine's one path (``simulate_jax_many``: the heap scan, and the exact
+serial path for small groups and flagged lanes) is held to tolerance 0
+here — bit for bit — so the tier is headroom, not error.  The regression
+half of this file exists so a future change can never silently launder
+rtol-level results into the exact engines (through the sim caches or
+otherwise).
 """
 import hypothesis
 import hypothesis.strategies as st
@@ -21,7 +24,7 @@ from repro.core.batchsim import simulate_batch
 from repro.core.devices import DevicePool, SharedResource, SystemConfig
 from repro.core.explore import ENGINE_NAMES, CacheStats
 from repro.core.fastsim import FrozenGraph, simulate_fast
-from repro.core.jaxsim import _bits_add, _bits_sub, simulate_jax
+from repro.core.jaxsim import _bits_add, _bits_sub, simulate_jax_many
 from repro.core.replay import (BatchStats, ENGINE_TOLERANCE, JAX_RTOL,
                                makespans_close, rankings_equivalent,
                                sims_equivalent)
@@ -32,16 +35,22 @@ from repro.testing.synth import (frozen_for, synth_candidates, synth_report,
                                  synth_reports, synth_trace)
 
 
+def jax_sims(fg, systems, policy="availability", **kw):
+    """One family through the jax engine's path."""
+    (sims,) = simulate_jax_many([(fg, systems)], policy, **kw)
+    return sims
+
+
 def assert_jax_tier(fg, systems, policy, **kw):
-    """Every lane within the jax tier of its own ``simulate_fast`` run."""
-    sims = simulate_jax(fg, systems, policy, **kw)
+    """Every lane equal to its own ``simulate_fast`` run bit for bit
+    (tolerance 0, inside the jax tier), and the ranking exact."""
+    sims = jax_sims(fg, systems, policy, **kw)
     refs = [simulate_fast(fg, system, policy) for system in systems]
     for sim, ref, system in zip(sims, refs, systems):
         assert sim.schedule == []
         assert sim.system == system.name and sim.policy == policy
-        assert sims_equivalent(sim, ref, ENGINE_TOLERANCE["jax"]), \
+        assert sims_equivalent(sim, ref, 0.0), \
             (system.name, sim.makespan, ref.makespan)
-        # the discrete halves of the contract are never relaxed
         assert sim.placements == ref.placements
         assert sim.pool_slots == ref.pool_slots
     got = [s.name for _, s in sorted(
@@ -49,7 +58,7 @@ def assert_jax_tier(fg, systems, policy, **kw):
     want = [s.name for _, s in sorted(
         ((ref.makespan, i), systems[i]) for i, ref in enumerate(refs))]
     spans = {system.name: ref.makespan for system, ref in zip(systems, refs)}
-    assert rankings_equivalent(got, want, spans, JAX_RTOL)
+    assert rankings_equivalent(got, want, spans, 0.0)
     return sims
 
 
@@ -76,8 +85,8 @@ def random_trace(draw):
 @hypothesis.settings(deadline=None, max_examples=8)
 def test_jax_tier_on_augmented_graphs(tr, smp, policy, slot_counts):
     """±smp exercises the conditional per-lane masking both ways; random
-    slot lists mix saturated lanes (lockstep) with contended ones (the
-    divergence fallback)."""
+    slot lists mix saturated lanes with contended ones, every lane
+    popping its own heap."""
     fg, _ = frozen_for(tr, smp)
     systems = [zynq_system(f"{n}acc{i}", {"fpga:k": n})
                for i, n in enumerate(slot_counts)]
@@ -110,38 +119,19 @@ def test_jax_tier_on_bare_dags_with_two_pools(n, ca, cb, policy):
     assert_jax_tier(fg, systems, policy, min_lockstep=2)
 
 
-def test_jax_divergent_lanes_fall_back_exactly():
-    """A wide slot ramp forces event-order divergence; diverged lanes must
-    be flagged by the in-scan monotonicity check and re-simulated through
-    the exact path, with the whole batch staying inside the tier."""
-    fg, _ = frozen_for(synth_trace(40), smp=True)
-    systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 25)]
-    stats = BatchStats()
-    assert_jax_tier(fg, systems, "availability", min_lockstep=2, stats=stats)
-    assert stats.groups == 1
-    assert stats.reference_lanes >= 1, "every discovery records an order"
-    assert stats.diverged_lanes > 0, "ramp should force exact fallbacks"
-    assert stats.lockstep_lanes > 0, "saturated lanes should stay in the scan"
-    assert (stats.lockstep_lanes + stats.order_pinned_lanes
-            + stats.reference_lanes + stats.serial_fallback_lanes
-            + stats.small_group_lanes) == len(systems)
-    # diverged lanes come from the exact path: bit-identical, not just close
-    sims = simulate_jax(fg, systems, "availability", min_lockstep=2)
-    for sim, system in zip(sims, systems):
-        if sim.makespan == simulate_fast(fg, system, "availability").makespan:
-            continue
-        pytest.fail(f"{system.name}: fallback lane not bit-identical")
-
-
 def test_jax_chunking_is_invariant():
     """Chunk width is a perf knob, never a semantics knob: every chunking
     of the lane axis yields the same results."""
     fg, _ = frozen_for(synth_trace(20), smp=False)
     systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 13)]
-    base = simulate_jax(fg, systems, "availability", min_lockstep=2)
+    base = jax_sims(fg, systems, min_lockstep=2)
+    assert [s.makespan for s in base] == \
+        [simulate_fast(fg, s, "availability").makespan for s in systems]
     for chunk in (2, 3, 8, 64):
-        got = simulate_jax(fg, systems, "availability", min_lockstep=2,
-                           chunk=chunk)
+        stats = BatchStats()
+        got = jax_sims(fg, systems, min_lockstep=2, chunk=chunk,
+                       stats=stats)
+        assert stats.heap_lanes == len(systems)
         assert [s.makespan for s in got] == [s.makespan for s in base]
         assert [s.placements for s in got] == [s.placements for s in base]
 
@@ -149,7 +139,7 @@ def test_jax_chunking_is_invariant():
 def test_jax_rejects_unknown_policy():
     fg, _ = frozen_for(synth_trace(4), smp=False)
     with pytest.raises(ValueError, match="policy"):
-        simulate_jax(fg, [zynq_system("s", {"fpga:k": 1})], "heft")
+        jax_sims(fg, [zynq_system("s", {"fpga:k": 1})], "heft")
 
 
 def test_jax_pure_smp_lanes_skip_inactive_dma_rows():
@@ -163,7 +153,9 @@ def test_jax_pure_smp_lanes_skip_inactive_dma_rows():
                             pools=[DevicePool("smp", ("smp",), i)],
                             shared=[SharedResource("submit", 1)])
                for i in range(1, 9)]
-    sims = simulate_jax(fg, systems, "availability", min_lockstep=2)
+    stats = BatchStats()
+    sims = jax_sims(fg, systems, min_lockstep=2, stats=stats)
+    assert stats.heap_lanes == len(systems)
     for sim, system in zip(sims, systems):
         ref = simulate_fast(fg, system, "availability")
         assert sim.makespan == ref.makespan
@@ -172,7 +164,8 @@ def test_jax_pure_smp_lanes_skip_inactive_dma_rows():
 
 def test_jax_raises_reference_error_on_live_bad_dispatch():
     """A row the reference engine raises on (no compatible pool) must
-    surface the same error from the scan — via the exact fallback."""
+    surface the same error from the heap scan — it flags the lane, and
+    the exact fallback raises."""
     g = TaskGraph()
     for i in range(10):
         kinds = ("a",) if i != 5 else ("gpu",)
@@ -184,7 +177,7 @@ def test_jax_raises_reference_error_on_live_bad_dispatch():
                             shared=[SharedResource("x", 1)])
                for i in range(1, 9)]
     with pytest.raises(RuntimeError, match="no compatible pool"):
-        simulate_jax(fg, systems, "availability", min_lockstep=2)
+        jax_sims(fg, systems, min_lockstep=2)
 
 
 # ---------------------------------------------------------------------------
@@ -370,7 +363,7 @@ def test_bad_chunk_values_fail_fast():
     systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 9)]
     for bad in (0, -4):
         with pytest.raises(ValueError, match="chunk"):
-            simulate_jax(fg, systems, chunk=bad)
+            jax_sims(fg, systems, chunk=bad)
         with pytest.raises(ValueError, match="jax_chunk"):
             Explorer(synth_trace(4), synth_reports(), engine="jax",
                      jax_chunk=bad)
@@ -381,43 +374,43 @@ def test_bad_chunk_values_fail_fast():
 
 
 def test_scan_inputs_memoised_on_frozen_graph():
-    """Repeat sweeps over the same payload reuse the per-step scan inputs
-    (and pickling drops them, like `_rt`)."""
+    """Repeat sweeps over the same payload reuse the heap scan's graph
+    tables (and pickling drops them, like `_rt`)."""
     import pickle
     fg, _ = frozen_for(synth_trace(12), smp=False)
     systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 9)]
-    first = simulate_jax(fg, systems, "availability", min_lockstep=2)
+    first = jax_sims(fg, systems, min_lockstep=2)
     cache = fg._jax_xs
     assert len(cache) == 1
     xs_id = id(next(iter(cache.values())))
-    again = simulate_jax(fg, systems, "availability", min_lockstep=2)
+    again = jax_sims(fg, systems, min_lockstep=2)
     assert id(next(iter(fg._jax_xs.values()))) == xs_id   # reused, not rebuilt
     assert [s.makespan for s in again] == [s.makespan for s in first]
     assert not hasattr(pickle.loads(pickle.dumps(fg)), "_jax_xs")
 
 
 def test_scan_module_and_scope_names_are_stable(monkeypatch):
-    """The trace reads the scan by name: its module is ``jit_run`` and its
-    operations carry the ``lockstep_scan`` scope in their metadata."""
+    """The trace reads the scan by name: its module is ``jit_run_heap``
+    and its operations carry the ``heap_scan`` scope in their metadata."""
     from repro.core import jaxsim
     launched = []
-    real = jaxsim._load_runner
+    real = jaxsim._load_heap_runner
 
     def load(cc, args):
         launched.append(args)
         return real(cc, args)
 
-    monkeypatch.setattr(jaxsim, "_load_runner", load)
+    monkeypatch.setattr(jaxsim, "_load_heap_runner", load)
     fg, _ = frozen_for(synth_trace(8), smp=False)
     systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 7)]
-    simulate_jax(fg, systems, "availability", min_lockstep=2)
+    jax_sims(fg, systems, min_lockstep=2)
     with jax.enable_x64(True):
-        lowered = jax.jit(jaxsim._runner()).lower(*launched[0])
+        lowered = jax.jit(jaxsim._heap_runner()).lower(*launched[0])
     text = lowered.as_text(debug_info=True)
-    assert "module @jit_run" in text
-    assert "lockstep_scan/" in text
+    assert "module @jit_run_heap" in text
+    assert "heap_scan/" in text
     hlo = lowered.compile().as_text()
-    assert 'op_name="jit(run)/lockstep_scan/while' in hlo
+    assert 'op_name="jit(run_heap)/heap_scan/while' in hlo
 
 
 def test_cache_stats_repr_has_disk_counters():
@@ -467,229 +460,12 @@ def test_bits_add_and_sub_are_ieee_float64():
 @pytest.mark.parametrize("policy", ["availability", "eft"])
 def test_lax_scan_times_are_bit_exact(policy):
     """The lax step's bit-pattern arithmetic is exact IEEE float64, so its
-    lockstep lanes match the reference engine at tolerance 0 — the jax
-    tier's rtol is headroom, not error, on every backend."""
+    heap lanes match the reference engine at tolerance 0 — the jax tier's
+    rtol is headroom, not error, on every backend."""
     fg, _ = frozen_for(synth_trace(24), smp=True)
     systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 9)]
     stats = BatchStats()
-    sims = simulate_jax(fg, systems, policy, min_lockstep=2, stats=stats)
-    assert stats.lockstep_lanes > 0
+    sims = jax_sims(fg, systems, policy, min_lockstep=2, stats=stats)
+    assert stats.heap_lanes == stats.lockstep_lanes == len(systems)
     for sim, system in zip(sims, systems):
         assert sims_equivalent(sim, simulate_fast(fg, system, policy), 0.0)
-
-
-# ---------------------------------------------------------------------------
-# the step-indexed scan state: pulled ready times, in-place placement slots
-# ---------------------------------------------------------------------------
-
-
-def _dag(n, edges):
-    """A bare DAG over two device kinds with the given row edges."""
-    g = TaskGraph()
-    uids = []
-    for i in range(n):
-        kinds = ("a", "b") if i % 3 else ("b", "a")
-        t = Task(uid=g.new_uid(), name=f"t{i}", devices=kinds,
-                 costs={"a": 0.5 + (i % 5) * 0.25, "b": 1.0 + (i % 3) * 0.5},
-                 creation_index=i, meta={"role": "compute"})
-        g.add_task(t, infer_deps=False)
-        uids.append(t.uid)
-    for a, b in edges:
-        g.add_edge(uids[a], uids[b])
-    return FrozenGraph.freeze(g)
-
-
-def _two_pools(ca, cb):
-    return [SystemConfig(name=f"s{i}-{j}",
-                         pools=[DevicePool("pa", ("a",), i),
-                                DevicePool("pb", ("b",), j)],
-                         shared=[SharedResource("x", 1)])
-            for i in range(1, ca + 1) for j in range(1, cb + 1)]
-
-
-def _ramp(ns, prefix="a"):
-    return [zynq_system(f"{prefix}{n}", {"fpga:k": n}) for n in ns]
-
-
-def _skip_graph(units=3):
-    """A chain of units ``r -> d -> x -> z``: ``d`` is a transfer
-    conditional on the compute ``x``, active only when ``x`` runs on
-    ``a``.  Under ``eft`` the SMP wins ``x`` (0.5 against 2.0), so ``d``
-    decides ``x``'s device at its own step, is skipped, and passes its
-    ready time on to ``x``, which pops after it (a higher creation index)
-    with the heap key still increasing: the lanes stay in lockstep."""
-    g = TaskGraph()
-    prev = None
-
-    def add(name, devices, costs, ci, **meta):
-        t = Task(uid=g.new_uid(), name=name, devices=devices, costs=costs,
-                 creation_index=ci, meta=meta)
-        g.add_task(t, infer_deps=False)
-        return t.uid
-
-    for u in range(units):
-        r = add(f"r{u}", ("b",), {"b": 1.0}, 4 * u, role="compute")
-        x = add(f"x{u}", ("a", "smp"), {"a": 2.0, "smp": 0.5}, 4 * u + 2,
-                role="compute")
-        d = add(f"d{u}", ("d",), {"d": 0.1}, 4 * u + 1, role="submit_in",
-                conditional_on=x, active_kinds=("a",))
-        z = add(f"z{u}", ("b",), {"b": 1.0}, 4 * u + 3, role="compute")
-        if prev is not None:
-            g.add_edge(prev, r)
-        for a, b in ((r, d), (d, x), (x, z)):
-            g.add_edge(a, b)
-        prev = z
-    fg = FrozenGraph.freeze(g)
-    systems = [SystemConfig(name=f"s{i}{j}{k}",
-                            pools=[DevicePool("smp", ("smp",), k),
-                                   DevicePool("pa", ("a",), i),
-                                   DevicePool("pb", ("b",), j),
-                                   DevicePool("pd", ("d",), 1)],
-                            shared=[SharedResource("x", 1)])
-               for i in (1, 2) for j in (1, 2, 3) for k in (1, 2)]
-    return [(fg, systems)]
-
-
-#: Nine independent rows joined by one, which three rows follow.
-_FAN_IN = [(i, 9) for i in range(9)] + [(9, j) for j in (10, 11, 12)]
-
-#: case -> (policy, families, what the scan must have run for the case to
-#: count).
-STATE_CASES = {
-    # every row a root: all predecessor slots are the pad
-    "roots": ("availability", lambda: [(_dag(12, []), _two_pools(3, 3))],
-              lambda run: (run.xs[0]["pred"] == run.T[0]).all()),
-    # the join row fills the whole predecessor list
-    "widest_preds": (
-        "availability", lambda: [(_dag(13, _FAN_IN), _two_pools(3, 3))],
-        lambda run: run.xs[0]["pred"].shape[1] == 9
-        and (run.xs[0]["pred"] < run.T[0]).all(axis=1).any()),
-    # lanes the scan completed passed conditional rows through untouched
-    "skipped_conditional": (
-        "eft", _skip_graph,
-        lambda run: all(any(st.kind == "skipped" for st in simulate_fast(
-            fg, s, "eft", with_schedule=True).schedule)
-            for fg, s in run.kept)),
-    # a DMA-in child decided its compute parent's device before the
-    # parent's own step: the parent reads an odd (parent) slot
-    "parent_placed_early": (
-        "availability",
-        lambda: [(frozen_for(synth_trace(16), smp=True)[0], _ramp(range(2, 9)))],
-        lambda run: (run.xs[0]["at_own"] % 2 == 1).any()),
-    # one launch holds lanes of cohorts with different step counts
-    "mixed_lengths": (
-        "availability",
-        lambda: [(frozen_for(synth_trace(10), smp=True)[0], _ramp(range(1, 7))),
-                 (frozen_for(synth_trace(17), smp=False)[0],
-                  _ramp(range(1, 7), "b"))],
-        lambda run: any(len(set(v.sum(axis=0).tolist())) > 1
-                        for v in run.valid)),
-    # the launches are wider than the lanes they carry
-    "padding_lanes": (
-        "availability",
-        lambda: [(frozen_for(synth_trace(12), smp=False)[0], _ramp(range(1, 6)))],
-        lambda run: sum(v.shape[1] for v in run.valid) > sum(run.lanes)),
-}
-
-
-def _run_recorded(monkeypatch, items, policy):
-    """One ``_scan_cohorts`` call over every pool-template group of
-    ``items``, each group replaying the order of its most-parallel lane
-    (a cold reference discovery), recording each launch's ``valid`` block
-    and real lane count, each cohort's step inputs, the ``(fg, system)``
-    of every lane the scan completed, and its result."""
-    import types
-    from repro.core import jaxsim
-    from repro.core.fastsim import pool_layout
-    run = types.SimpleNamespace(valid=[], kept=[], sims=[])
-    real_load = jaxsim._load_runner
-
-    def load(cc, args):
-        run.valid.append(np.asarray(args[0]["valid"]))
-        return real_load(cc, args)
-
-    monkeypatch.setattr(jaxsim, "_load_runner", load)
-    cohorts, members = [], []
-    for fg, systems in items:
-        groups = {}
-        for system in systems:
-            lay = pool_layout(fg.kinds, system)
-            groups.setdefault((tuple(lay[0]), tuple(lay[2])), []).append(
-                (system, lay))
-        for group in groups.values():
-            order = []
-            simulate_fast(fg, max(group, key=lambda m: sum(m[1][1]))[0],
-                          policy, order_out=order)
-            cohorts.append((fg, order, [lay for _, lay in group], None))
-            members.append(group)
-    outs = jaxsim._scan_cohorts(cohorts, policy,
-                                chunk=jaxsim.MEGABATCH_CHUNK)
-    run.lanes = [sum(len(group) for group in members)]
-    for (fg, _order, _lays, _cut), group, (done, _div, _ret) in zip(
-            cohorts, members, outs):
-        run.kept.extend((fg, group[pos][0]) for pos in done)
-        run.sims.extend(done.values())
-    run.xs = [next(iter(fg._jax_xs.values())) for fg, _ in items]
-    run.T = [len(x["r"]) for x in run.xs]
-    return run
-
-
-@pytest.mark.parametrize("case", STATE_CASES)
-def test_step_indexed_state_is_bit_exact(monkeypatch, case):
-    """Ready times pulled from the predecessors' steps and placements read
-    at their latest touch give every lane ``simulate_fast``'s makespan,
-    busy times and placements bit for bit, in one multi-cohort launch of
-    the scan and on the per-graph path, in each case the step-indexed
-    state has to get right."""
-    policy, build, ran = STATE_CASES[case]
-    items = build()
-    run = _run_recorded(monkeypatch, items, policy)
-    assert run.kept, "no lane ran the scan"
-    assert ran(run), f"the scan never exercised {case}"
-    for (fg, system), sim in zip(run.kept, run.sims):
-        ref = simulate_fast(fg, system, policy)
-        assert sims_equivalent(sim, ref, 0.0), (case, system.name)
-    for fg, systems in items:
-        per_graph = simulate_jax(fg, systems, policy, min_lockstep=2)
-        for system, pg in zip(systems, per_graph):
-            ref = simulate_fast(fg, system, policy)
-            assert sims_equivalent(pg, ref, 0.0), (case, system.name)
-
-
-def test_scan_step_scatters_into_no_task_indexed_state():
-    """The loop body writes its ``[T+1, B]`` ends and ``[2T+1, B]``
-    placements with one in-place dynamic-update-slice each; every scatter
-    left (clocks, busy, seen) is O(lanes)-sized, and none combines with a
-    64-bit ``max`` — the property the step's cost on the chip rests on."""
-    import re
-    from repro.core import jaxsim
-    T, P, S, B, K, NK, PC = 37, 3, 4, 8, 2, 3, 5
-    with jax.enable_x64(True):
-        def sd(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype)
-        xs = {"valid": sd((T, B), jnp.bool_), "tb": sd((T, B), jnp.int64),
-              "c": sd((T, B), jnp.int32), "is_comp": sd((T, B), jnp.bool_),
-              "k_first": sd((T, B), jnp.int32),
-              "own_opts": sd((T, B, K), jnp.int32),
-              "own_cost": sd((T, B, NK), jnp.int64),
-              "par_opts": sd((T, B, K), jnp.int32),
-              "par_cost": sd((T, B, NK), jnp.int64),
-              "act": sd((T, B, NK), jnp.bool_),
-              "bad_row": sd((T, B), jnp.bool_),
-              "pred": sd((T, B, PC), jnp.int32),
-              "at_own": sd((T, B), jnp.int32),
-              "at_par": sd((T, B), jnp.int32)}
-        hlo = jax.jit(jaxsim._runner()).lower(
-            xs, sd((P, S, B), jnp.int64), sd((T + 1, B), jnp.int64),
-            sd((2 * T + 1, B), jnp.int32), sd((P, B), jnp.int64),
-            sd((P, B), jnp.bool_), sd((B, NK), jnp.int32),
-            sd((B,), jnp.int32), sd((), jnp.bool_)).as_text(dialect="hlo")
-    regions = dict(re.findall(r"\n(\S+) [^\n]*\{\n(.*?)\n\}", hlo, re.S))
-    scatters = re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(.*to_apply=([\w.]+)",
-                          hlo)
-    assert scatters, "clocks, busy and seen are scattered"
-    for dims, region in scatters:
-        assert max(int(d) for d in dims.split(",")) < T, dims
-        assert "maximum" not in regions[region], (dims, region)
-    updates = re.findall(r"= \w+\[([\d,]*)\]\S* dynamic-update-slice\(", hlo)
-    assert sorted(updates) == sorted([f"{T + 1},{B}", f"{2 * T + 1},{B}"])

@@ -1,17 +1,14 @@
-"""The multi-graph megabatch, the pallas step body and the compile cache.
+"""The multi-graph megabatch and the compile cache.
 
-Three mechanisms flip the jax engine's cold-start economics (ISSUE 6):
-one compiled scan serving every graph family of a sweep
-(``simulate_jax_many`` / ``replay.simulate_many``), a fused pallas kernel
-for the step-commit (``kernels.lockstep_step``, kept out of the scan while
-its state is 64-bit), and a persistent
-XLA compile cache (``xlacache.CompileCache``, DiskCache ``xla``
-namespace).  This file pins their contracts — megabatch results stay
-inside the documented ``JAX_RTOL`` tier of the per-graph path, the kernel
-matches a numpy oracle bit-for-bit in interpret mode, and a warm store
-serves a fresh process with zero compiles — plus the two satellite
-bugfixes: ``_bucket`` can never exceed its cap, and process pools never
-fork a jax-loaded parent.
+Two mechanisms set the jax engine's cold-start economics: one compiled
+heap scan serving every graph family of a sweep (``simulate_jax_many`` /
+``replay.simulate_many``, the engine's only path), and a persistent XLA
+compile cache (``xlacache.CompileCache``, DiskCache ``xla`` namespace).
+This file pins their contracts — megabatch results equal ``simulate_fast``
+bit for bit, launch shapes that drift inside their buckets compile once,
+and a warm store serves a fresh process with zero compiles — plus two
+satellite bugfixes: ``_bucket`` can never exceed its cap, and process
+pools never fork a jax-loaded parent.
 """
 import collections
 import json
@@ -30,8 +27,7 @@ from repro.core.devices import DevicePool, SharedResource, SystemConfig
 from repro.core.diskcache import DiskCache
 from repro.core.explore import _pool_mp_context
 from repro.core.fastsim import FrozenGraph, simulate_fast
-from repro.core.jaxsim import (MEGABATCH_CHUNK, STEP_IMPLS, _bucket,
-                               simulate_jax, simulate_jax_many)
+from repro.core.jaxsim import _bucket, simulate_jax_many
 from repro.core.replay import (BatchStats, JAX_RTOL, ReplayLibrary,
                                rankings_equivalent, sims_equivalent)
 from repro.core.taskgraph import Task, TaskGraph
@@ -75,16 +71,17 @@ def test_non_power_of_two_chunk_is_invariant():
     identical to any other chunking (the cap rounds down internally)."""
     fg, _ = frozen_for(synth_trace(20), smp=False)
     systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 13)]
-    base = simulate_jax(fg, systems, "availability", min_lockstep=2)
+    (base,) = simulate_jax_many([(fg, systems)], "availability",
+                                min_lockstep=2)
     for chunk in (5, 48, 100):
-        got = simulate_jax(fg, systems, "availability", min_lockstep=2,
-                           chunk=chunk)
+        (got,) = simulate_jax_many([(fg, systems)], "availability",
+                                   min_lockstep=2, chunk=chunk)
         assert [s.makespan for s in got] == [s.makespan for s in base]
         assert [s.placements for s in got] == [s.placements for s in base]
 
 
 # ---------------------------------------------------------------------------
-# megabatch vs per-graph: randomized tier equivalence
+# megabatch vs the exact engine: randomized equivalence
 # ---------------------------------------------------------------------------
 
 
@@ -129,51 +126,39 @@ def _mixed_families(seed):
                   st.sampled_from(["availability", "eft"]))
 @hypothesis.settings(deadline=None, max_examples=4)
 def test_megabatch_matches_per_graph_tier(seed, policy):
-    """One megabatch call over heterogeneous families is tier-equivalent
-    to per-family ``simulate_jax`` — which is itself pinned to
-    ``simulate_fast`` — across policies, conditional DMA on/off, and
-    heterogeneous pool templates/slot counts."""
+    """One megabatch call over heterogeneous families equals
+    ``simulate_fast`` bit for bit (tolerance 0, inside the jax tier)
+    across policies, conditional DMA on/off, and heterogeneous pool
+    templates/slot counts."""
     items = _mixed_families(seed)
     res = simulate_jax_many(items, policy, min_lockstep=2)
     for (fg, systems), sims in zip(items, res):
         assert len(sims) == len(systems)
-        per_graph = simulate_jax(fg, systems, policy, min_lockstep=2)
-        for system, sim, pg in zip(systems, sims, per_graph):
+        for system, sim in zip(systems, sims):
             ref = simulate_fast(fg, system, policy)
             assert sim.system == system.name and sim.schedule == []
-            assert sims_equivalent(sim, ref, JAX_RTOL), \
+            assert sims_equivalent(sim, ref, 0.0), \
                 (policy, system.name, sim.makespan, ref.makespan)
-            assert sims_equivalent(pg, ref, JAX_RTOL)
             assert sim.placements == ref.placements
 
 
-def _no_replay_launch(monkeypatch):
-    """Make a replay-scan (``jit_run``) launch fail the test."""
-    from repro.core import jaxsim
-
-    def refuse(*_a, **_k):
-        raise AssertionError("the megabatch launched the replay scan")
-    monkeypatch.setattr(jaxsim, "_load_runner", refuse)
-
-
-def test_megabatch_divergent_lanes_fall_back_exactly(monkeypatch):
+def test_megabatch_divergent_lanes_fall_back_exactly():
     """A slot-count ramp whose lanes diverge from any replayed order (the
-    per-graph path shows it) completes in the megabatch's heap scan, every
-    lane bit-identical to the batch engine, with no replay launch, no
-    discovery and no divergence; the per-lane accounting covers every lane
-    exactly once."""
+    batch engine's replay shows it) completes in the megabatch's heap
+    scan, every lane bit-identical to the batch engine, with no discovery
+    and no divergence; the per-lane accounting covers every lane exactly
+    once."""
     fg1, _ = frozen_for(synth_trace(40), smp=True)
     fg2, _ = frozen_for(synth_trace(24), smp=False)
     items = [(fg1, [zynq_system(f"a{n}", {"fpga:k": n})
                     for n in range(1, 25)]),
              (fg2, [zynq_system(f"b{n}", {"fpga:k": n})
                     for n in range(1, 13)])]
-    per_graph = BatchStats()
+    replayed = BatchStats()
     for fg, systems in items:
-        simulate_jax(fg, systems, "availability", min_lockstep=2,
-                     stats=per_graph)
-    assert per_graph.diverged_lanes > 0, "ramp should diverge on replay"
-    _no_replay_launch(monkeypatch)
+        simulate_batch(fg, systems, "availability", min_lockstep=2,
+                       stats=replayed)
+    assert replayed.diverged_lanes > 0, "ramp should diverge on replay"
     stats = BatchStats()
     res = simulate_jax_many(items, "availability", min_lockstep=2,
                             stats=stats)
@@ -189,20 +174,18 @@ def test_megabatch_divergent_lanes_fall_back_exactly(monkeypatch):
             assert sims_equivalent(sim, want, 0.0), system.name
 
 
-def test_megabatch_warm_library_routes_everything(monkeypatch):
-    """A library warmed by the per-graph jax path steers no megabatch
-    lane: the megabatch sweep on it routes every lane to the heap scan,
-    with no order hit, no discovery and no replay launch, leaves the
-    library as it found it, and ranks as the batch engine does."""
+def test_megabatch_warm_library_routes_everything():
+    """A library warmed by the batch engine steers no megabatch lane: the
+    megabatch sweep on it routes every lane to the heap scan, with no
+    order hit and no discovery, leaves the library as it found it, and
+    ranks as the batch engine does."""
     reports, rep = synth_reports(), synth_report()
     tr = synth_trace(24)
     cands = synth_candidates(range(1, 7), rep)
     lib = ReplayLibrary()
-    Explorer(tr, reports, engine="jax", jax_megabatch=False,
-             order_library=lib).explore(cands)
+    Explorer(tr, reports, engine="batch", order_library=lib).explore(cands)
     warmed = lib.counts()
     assert warmed["orders"] > 0
-    _no_replay_launch(monkeypatch)
     ex = Explorer(tr, reports, engine="jax", order_library=lib)
     got = ex.explore(cands)
     st = ex.batch_stats
@@ -222,72 +205,6 @@ def test_megabatch_rejects_bad_arguments():
         simulate_jax_many(items, "heft")
     with pytest.raises(ValueError, match="chunk"):
         simulate_jax_many(items, "availability", chunk=0)
-    with pytest.raises(ValueError, match="step_impl"):
-        simulate_jax(fg, [zynq_system("s", {"fpga:k": 1})],
-                     step_impl="nope")
-
-
-# ---------------------------------------------------------------------------
-# the pallas step body
-# ---------------------------------------------------------------------------
-
-
-def test_step_commit_kernel_matches_numpy_oracle():
-    """The fused commit kernel (interpret mode) against a direct numpy
-    transcription of the lax step tail — same slot argmin tie-break, same
-    clock/busy/seen updates, bit-for-bit in f64."""
-    from repro.kernels.lockstep_step import step_commit
-    import jax.numpy as jnp
-    import jax
-
-    rng = np.random.default_rng(7)
-    P, S, B = 3, 4, 16
-    clocks = np.where(rng.random((P, S, B)) < 0.3, np.inf,
-                      rng.random((P, S, B)) * 5)
-    clocks[:, 0, :] = rng.random((P, B))        # every pool has a free slot
-    busy = rng.random((P, B))
-    seen = rng.random((P, B)) < 0.5
-    p = rng.integers(0, P, B)
-    rt = rng.random(B) * 3
-    base = rng.random(B)
-    live = rng.random(B) < 0.8
-
-    with jax.enable_x64(True):
-        oclk, obusy, oseen, oend = step_commit(
-            jnp.asarray(clocks), jnp.asarray(busy), jnp.asarray(seen),
-            jnp.asarray(p), jnp.asarray(rt), jnp.asarray(base),
-            jnp.asarray(live), interpret=True)
-        oclk, obusy = np.asarray(oclk), np.asarray(obusy)
-        oseen, oend = np.asarray(oseen), np.asarray(oend)
-
-    for li in range(B):
-        cl = clocks[p[li], :, li]
-        s = int(np.argmin(cl))                  # first minimum
-        start = max(rt[li], cl[s])
-        end = start + base[li]
-        assert oend[li] == end
-        want_clk = clocks[:, :, li].copy()
-        want_busy = busy[:, li].copy()
-        want_seen = seen[:, li].copy()
-        if live[li]:
-            want_clk[p[li], s] = end
-            want_busy[p[li]] += end - start
-            want_seen[p[li]] = True
-        assert np.array_equal(oclk[:, :, li], want_clk)
-        assert np.array_equal(obusy[:, li], want_busy)
-        assert np.array_equal(oseen[:, li], want_seen)
-
-
-def test_scan_takes_no_kernel_step():
-    """The kernel is guarded on its own (the oracle above, the described-v5e
-    compile in test_tpu_compile.py); the scan only ever compiles the lax
-    step, and neither kernel name reaches it."""
-    fg, _ = frozen_for(synth_trace(8), smp=False)
-    systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 7)]
-    assert STEP_IMPLS == ("auto", "lax")
-    for name in ("pallas", "pallas-interpret"):
-        with pytest.raises(ValueError, match="step_impl"):
-            simulate_jax(fg, systems, "availability", step_impl=name)
 
 
 # ---------------------------------------------------------------------------
@@ -301,15 +218,51 @@ def test_compile_cache_memory_tier_dedups_repeat_shapes():
     fg, _ = frozen_for(synth_trace(10), smp=False)
     systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 9)]
     cc = CompileCache()                                     # mem-only
-    simulate_jax(fg, systems, "availability", min_lockstep=2,
-                 compile_cache=cc)
+    simulate_jax_many([(fg, systems)], "availability", min_lockstep=2,
+                      compile_cache=cc)
     compiles = cc.as_dict()["compiles"]
     assert compiles >= 1
-    simulate_jax(fg, systems, "availability", min_lockstep=2,
-                 compile_cache=cc)
+    simulate_jax_many([(fg, systems)], "availability", min_lockstep=2,
+                      compile_cache=cc)
     got = cc.as_dict()
     assert got["compiles"] == compiles, "repeat shapes must not recompile"
     assert got["mem_hits"] >= 1
+
+
+def _drift_sweeps(axis):
+    """Two sweeps whose heap-bound lanes (``axis="lanes"``: 7 then 12) or
+    cohorts (``axis="cohorts"``: 1 then 3 graphs) differ, while rows,
+    slots, lanes and cohorts stay inside one bucket each (under
+    ``HEAP_ROW_ALIGN`` rows, at most 8 slots, at most ``HEAP_MIN_LANES``
+    lanes, at most ``HEAP_MIN_COHORTS`` cohorts)."""
+    def ramp(tag, counts):
+        return [zynq_system(f"{tag}{i}-{n}", {"fpga:k": n})
+                for i, n in enumerate(counts)]
+    fgs = [frozen_for(synth_trace(n), smp=True)[0] for n in (12, 9, 15)]
+    if axis == "lanes":
+        return ([(fgs[0], ramp("a", range(1, 8)))],
+                [(fgs[0], ramp("b", [1 + i % 8 for i in range(12)]))])
+    return ([(fgs[0], ramp("a", range(1, 8)))],
+            [(fg, ramp(f"c{g}", range(1, 9))) for g, fg in enumerate(fgs)])
+
+
+@pytest.mark.parametrize("axis", ["lanes", "cohorts"])
+def test_compile_cache_serves_drift_inside_a_bucket(axis):
+    """A sweep whose count of heap lanes or cohorts drifts with its costs
+    meets the executable its warm-up compiled: one compile for both
+    sweeps, and both bit-identical to ``simulate_fast``."""
+    cc = CompileCache()                                     # mem-only
+    for items in _drift_sweeps(axis):
+        stats = BatchStats()
+        res = simulate_jax_many(items, "availability", compile_cache=cc,
+                                stats=stats)
+        assert stats.heap_lanes == sum(len(s) for _, s in items)
+        for (fg, systems), sims in zip(items, res):
+            for system, sim in zip(systems, sims):
+                ref = simulate_fast(fg, system, "availability")
+                assert sims_equivalent(sim, ref, 0.0), system.name
+    got = cc.as_dict()
+    assert got["compiles"] == 1 and got["mem_hits"] >= 1, got
 
 
 def test_compile_cache_rejects_corrupt_disk_payloads(tmp_path):
@@ -385,14 +338,13 @@ def _src_env():
 
 
 # ---------------------------------------------------------------------------
-# scan counters (BatchStats.scan_lane_steps / scan_kept_lane_steps /
-# h2d_bytes)
+# scan counters (BatchStats.heap_lane_steps / heap_h2d_bytes)
 # ---------------------------------------------------------------------------
 
 
 def _ramp_families():
-    """Two families whose slot-count ramps make some lanes diverge, so the
-    kept lanes are fewer than the launched ones."""
+    """Two families with slot-count ramps, whose lanes diverge from any
+    one replayed order."""
     fg1, _ = frozen_for(synth_trace(24), smp=True)
     fg2, _ = frozen_for(synth_trace(14), smp=False)
     return [(fg1, [zynq_system(f"a{n}", {"fpga:k": n})
@@ -403,22 +355,12 @@ def _ramp_families():
 
 def _record_scans(monkeypatch):
     """Record, beside the counters, what the scan really did: the
-    ``(T, B)`` step block of every launch, the bytes of every
-    ``jnp.asarray`` upload and of each launch's host-made arguments, and
-    each cohort's kept lanes × its own steps."""
+    ``(rows, lanes)`` of every launch, the bytes of every ``jnp.asarray``
+    upload and of each launch's host-made arguments."""
     import jax.numpy as jnp
     from repro.core import jaxsim
-    rec = {"launches": [], "uploads": [], "launch_args": [], "kept": [],
-           "heap_launches": [], "heap_args": []}
-    real_load, real_put = jaxsim._load_runner, jnp.asarray
-    real_scan = jaxsim._scan_cohorts
-    real_heap = jaxsim._load_heap_runner
-
-    def load(cc, args):
-        rec["launches"].append(args[0]["valid"].shape)
-        rec["launch_args"].append(np.asarray(args[1]).nbytes
-                                  + np.asarray(args[8]).nbytes)
-        return real_load(cc, args)
+    rec = {"uploads": [], "heap_launches": [], "heap_args": []}
+    real_put, real_heap = jnp.asarray, jaxsim._load_heap_runner
 
     def load_heap(cc, args):
         rec["heap_launches"].append((args[0]["npred"].shape[0],
@@ -430,34 +372,24 @@ def _record_scans(monkeypatch):
         rec["uploads"].append(np.asarray(a).nbytes)
         return real_put(a, *rest, **kw)
 
-    def scan(cohorts, *rest, **kw):
-        outs = real_scan(cohorts, *rest, **kw)
-        for (_fg, order, _lay, _cut), (done, _div, _ret) in zip(cohorts,
-                                                                outs):
-            rec["kept"].append(len(done) * len(order))
-        return outs
-
     monkeypatch.setattr(jaxsim, "_DEV_XS_CACHE",
                         collections.OrderedDict())
-    monkeypatch.setattr(jaxsim, "_load_runner", load)
     monkeypatch.setattr(jaxsim, "_load_heap_runner", load_heap)
-    monkeypatch.setattr(jaxsim, "_scan_cohorts", scan)
     monkeypatch.setattr(jnp, "asarray", put)
     return rec
 
 
 def test_scan_counters_match_the_launches_and_uploads(monkeypatch):
-    """A megabatch sweep makes no replay-scan launch, so
-    ``scan_lane_steps``, ``scan_kept_lane_steps`` and ``h2d_bytes`` stay
-    0; ``heap_lane_steps`` is Σ B × rows over the heap scan's launches and
-    ``heap_h2d_bytes`` the bytes actually handed to ``jnp.asarray``.  A
-    warm repeat (the stacked graph tables a device-cache hit) uploads only
-    each launch's own arguments, over the same launches."""
+    """Nothing writes ``scan_lane_steps``, ``scan_kept_lane_steps`` and
+    ``h2d_bytes``, so they stay 0; ``heap_lane_steps`` is Σ B × rows over
+    the heap scan's launches and ``heap_h2d_bytes`` the bytes actually
+    handed to ``jnp.asarray``.  A warm repeat (the stacked graph tables a
+    device-cache hit) uploads only each launch's own arguments, over the
+    same launches."""
     items = _ramp_families()
     rec = _record_scans(monkeypatch)
     cold = BatchStats()
     simulate_jax_many(items, "availability", min_lockstep=2, stats=cold)
-    assert rec["launches"] == [] and rec["kept"] == []
     assert (cold.scan_lane_steps, cold.scan_kept_lane_steps,
             cold.h2d_bytes) == (0, 0, 0)
     heap_launches = list(rec["heap_launches"])
@@ -498,19 +430,15 @@ print("SPANS-OK")
 
 
 def test_scan_counters_on_the_per_graph_path_and_the_batch_engine():
-    """The per-graph scan counts as the megabatch does; the numpy batch
-    engine launches no scan and leaves all three counters at 0."""
+    """The numpy batch engine launches no scan and leaves every scan
+    counter at 0."""
     fg, systems = _ramp_families()[1]
-    stats = BatchStats()
-    simulate_jax(fg, systems, "availability", min_lockstep=2, stats=stats)
-    assert 0 < stats.scan_kept_lane_steps <= stats.scan_lane_steps
-    assert stats.h2d_bytes > 0
-    from repro.core.batchsim import simulate_batch
     stats = BatchStats()
     simulate_batch(fg, systems, "availability", min_lockstep=2, stats=stats)
     assert stats.lockstep_lanes > 0
     assert (stats.scan_lane_steps, stats.scan_kept_lane_steps,
-            stats.h2d_bytes) == (0, 0, 0)
+            stats.h2d_bytes, stats.heap_lane_steps,
+            stats.heap_h2d_bytes) == (0, 0, 0, 0, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -519,15 +447,15 @@ def test_scan_counters_on_the_per_graph_path_and_the_batch_engine():
 
 
 def test_explorer_megabatch_matches_batch_rankings(tmp_path):
-    """`engine="jax"` defaults to the megabatch path; rankings must stay
+    """`engine="jax"` runs the megabatch path; rankings must stay
     equivalent to the exact batch engine under the documented tie-break,
-    with the compile cache wired through ``cache_dir``."""
+    with the compile cache wired through ``cache_dir`` (and none on the
+    batch engine)."""
     reports, rep = synth_reports(), synth_report()
     tr = synth_trace(24)
     cands = synth_candidates(range(1, 7), rep)
     ex = Explorer(tr, reports, engine="jax",
                   cache_dir=str(tmp_path / "store"))
-    assert ex.jax_megabatch is True
     jaxr = ex.explore(cands)
     assert ex.compile_cache is not None
     assert ex.compile_cache.as_dict()["compiles"] >= 1
@@ -536,20 +464,6 @@ def test_explorer_megabatch_matches_batch_rankings(tmp_path):
     assert rankings_equivalent([o.name for o in jaxr.ranked],
                                [o.name for o in batch.ranked], spans,
                                JAX_RTOL)
-    # megabatch off takes the per-graph path and must agree too
-    off = Explorer(tr, reports, engine="jax",
-                   jax_megabatch=False).explore(cands)
-    assert off.ranked, "per-graph path still evaluates"
-    assert rankings_equivalent([o.name for o in off.ranked],
-                               [o.name for o in batch.ranked], spans,
-                               JAX_RTOL)
-
-
-def test_jax_megabatch_knob_validation():
-    reports, tr = synth_reports(), synth_trace(4)
-    with pytest.raises(ValueError, match="jax_megabatch"):
-        Explorer(tr, reports, engine="batch", jax_megabatch=True)
-    assert Explorer(tr, reports, engine="batch").jax_megabatch is False
     assert Explorer(tr, reports, engine="batch").compile_cache is None
 
 

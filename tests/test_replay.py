@@ -1,12 +1,12 @@
 """The multi-order replay library: reuse, rescue, persistence, corruption.
 
 The contract under test: a :class:`repro.core.replay.ReplayLibrary` carries
-discovered dispatch orders across calls, engines, processes and runs —
+discovered dispatch orders across calls, Explorers, processes and runs —
 warm sweeps route every lane to its remembered order (no serial reference
 run, no diverge-detect-resimulate cycle, zero serial fallbacks) while every
 completion stays either a validated lockstep lane or an exact serial run,
-so batch results remain bit-identical to ``Simulator.run()`` and jax stays
-inside its rtol tier *with rescued lanes included*.  Library payloads are
+so batch results remain bit-identical to ``Simulator.run()`` *with rescued
+lanes included*.  Library payloads are
 corruption-checked like graph entries: a corrupted, stale or wrong-policy
 order entry degrades to rediscovery, never to a wrong replay.
 """
@@ -23,9 +23,7 @@ from repro.core.batchsim import BatchStats, simulate_batch
 from repro.core.diskcache import DiskCache
 from repro.core.explore import _process_eval_chunk
 from repro.core.fastsim import FrozenGraph, simulate_fast
-from repro.core.jaxsim import simulate_jax
-from repro.core.replay import (JAX_RTOL, ReplayLibrary, order_valid,
-                               sims_equivalent, simulate_grouped)
+from repro.core.replay import ReplayLibrary, order_valid, simulate_grouped
 from repro.core.trace import Trace, TraceEvent
 from repro.testing.synth import (frozen_for, synth_candidates, synth_report,
                                  synth_reports, synth_trace)
@@ -319,41 +317,6 @@ def test_batch_bit_identical_with_warm_library(tr, smp, policy, slot_counts):
         sims = simulate_batch(fg, systems, policy, min_lockstep=2,
                               rescue_min=2, library=lib)
         assert_bit_identical(fg, systems, policy, sims)
-
-
-@hypothesis.given(random_trace(), st.booleans(),
-                  st.lists(st.integers(1, 10), min_size=2, max_size=8))
-@hypothesis.settings(deadline=None, max_examples=6)
-def test_jax_tier_holds_with_warm_library(tr, smp, slot_counts):
-    fg, _ = frozen_for(tr, smp)
-    systems = [zynq_system(f"{n}acc{i}", {"fpga:k": n})
-               for i, n in enumerate(slot_counts)]
-    lib = ReplayLibrary()
-    for _ in range(2):
-        sims = simulate_jax(fg, systems, "availability", min_lockstep=2,
-                            rescue_min=2, library=lib)
-        for sim, system in zip(sims, systems):
-            ref = simulate_fast(fg, system, "availability")
-            assert sims_equivalent(sim, ref, JAX_RTOL), system.name
-            assert sim.placements == ref.placements
-
-
-def test_library_is_shared_across_engines():
-    """Orders are engine-agnostic: a batch-warmed library serves the jax
-    scan (and vice versa) — recorded by the exact path, re-validated per
-    lane per backend."""
-    fg, _ = frozen_for(synth_trace(30), smp=True)
-    systems = ramp(range(1, 17))
-    lib = ReplayLibrary()
-    simulate_batch(fg, systems, "availability", min_lockstep=2, library=lib)
-    jstats = BatchStats()
-    sims = simulate_jax(fg, systems, "availability", min_lockstep=2,
-                        library=lib, stats=jstats)
-    assert jstats.reference_lanes == 0, "batch-warmed orders serve the scan"
-    assert jstats.order_hits > 0
-    for sim, system in zip(sims, systems):
-        ref = simulate_fast(fg, system, "availability")
-        assert sims_equivalent(sim, ref, JAX_RTOL)
 
 
 # ---------------------------------------------------------------------------

@@ -6,38 +6,23 @@ chip.  The topology is described inside a module fixture — never while a
 module is imported — because only one process at a time may load the TPU
 library, and every test worker imports every test file.
 
-* The jax engine's scan runner (the ``lax`` step: times as int64 float64
-  bit patterns, under x64) compiles at full-size slice shapes: the
-  Cholesky ``n=2048, bs=128`` graph (4,912 task rows after DMA
-  augmentation, 5 pools), and a ``synth:1024`` sweep over 1-128
-  accelerators (5,120 task rows, 4 pools, slot axes of 32, 64 and 128).
-* The heap scan (``_heap_runner``), which runs every megabatch lane,
-  compiles at the launch shapes of the benchmark's three cells, with no
-  copy between HBM and vector memory inside its loop.
-* The pallas step-commit kernel compiles to a Mosaic custom call with
-  float32 state and x64 off.  No 64-bit operand passes into a Mosaic
-  kernel, which is why ``step_impl="auto"`` resolves to ``lax`` inside
-  the scan, and ``"pallas"`` raises.
+The heap scan (``_heap_runner``, the ``lax`` step: times as int64 float64
+bit patterns, under x64), which runs every jax lane on the device,
+compiles at the launch shapes of the benchmark's three cells, at the
+floor and the lane cap of a launch, and at the ``bs=64`` sizes of both
+applications, with its loop's state kept in vector memory wherever the
+compiler can hold it there.
 """
 import functools
+import re
 
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.devices import zynq_system
-from repro.core.jaxsim import (_heap_runner, _runner, resolve_step_impl,
-                               simulate_jax)
-from repro.kernels.lockstep_step import step_commit
-from repro.testing.synth import frozen_for, synth_trace
-
-#: Full-size scan slices: task rows (T = n), pools, slots, lanes, per-row
-#: device options, device kinds and predecessor-list width.
-SLICES = {"phaseA": (4912, 5, 8, 32, 2, 5, 10),
-          "phaseB-S32": (5120, 4, 32, 64, 2, 4, 4),
-          "phaseB-S64": (5120, 4, 64, 32, 2, 4, 4),
-          "phaseB-S128": (5120, 4, 128, 16, 2, 4, 4)}
+from repro.core.jaxsim import (HEAP_MIN_COHORTS, HEAP_MIN_LANES,
+                               HEAP_ROW_ALIGN, MEGABATCH_CHUNK, _heap_runner)
 
 
 @pytest.fixture(scope="module")
@@ -57,55 +42,44 @@ def _sd(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("shape", SLICES.values(), ids=SLICES.keys())
-def test_scan_runner_compiles_for_v5e(one_chip, shape):
-    T, P, S, B, K, NK, PC = shape
-    sd = functools.partial(_sd, one_chip)
-    t64 = jnp.int64                     # times travel as float64 bits
-    with jax.enable_x64(True):
-        xs = {"valid": sd((T, B), jnp.bool_),
-              "tb": sd((T, B), jnp.int64), "c": sd((T, B), jnp.int32),
-              "is_comp": sd((T, B), jnp.bool_),
-              "k_first": sd((T, B), jnp.int32),
-              "own_opts": sd((T, B, K), jnp.int32),
-              "own_cost": sd((T, B, NK), t64),
-              "par_opts": sd((T, B, K), jnp.int32),
-              "par_cost": sd((T, B, NK), t64),
-              "act": sd((T, B, NK), jnp.bool_),
-              "bad_row": sd((T, B), jnp.bool_),
-              "pred": sd((T, B, PC), jnp.int32),
-              "at_own": sd((T, B), jnp.int32),
-              "at_par": sd((T, B), jnp.int32)}
-        args = (xs, sd((P, S, B), t64),                  # clocks
-                sd((T + 1, B), t64),                     # ends
-                sd((2 * T + 1, B), jnp.int32),           # placed
-                sd((P, B), t64), sd((P, B), jnp.bool_),  # busy, seen
-                sd((B, NK), jnp.int32), sd((B,), jnp.int32),
-                sd((), jnp.bool_))
-        compiled = jax.jit(_runner()).lower(*args).compile()
-    mem = compiled.memory_analysis()
-    # the per-slice step inputs dominate: ~43 MB at 64 lanes, far inside
-    # the chip's 16 GB
-    assert 0 < mem.argument_size_in_bytes < 1 << 30
+#: Heap-scan launches: padded rows, cohorts, options, device kinds, pools,
+#: slots, lanes.  Each is read from ``_heap_cohorts``'s launch arguments in
+#: a CPU sweep of the configuration named: rows pad to a multiple of
+#: ``HEAP_ROW_ALIGN``, cohorts and lanes bucket to powers of two no
+#: smaller than ``HEAP_MIN_COHORTS`` and ``HEAP_MIN_LANES`` (lanes capped
+#: by ``MEGABATCH_CHUNK``), slots to the widest lane's clamped need.
+HEAP_LAUNCHES = {
+    # the benchmark's cells, where every lane of a group of at least
+    # MIN_LOCKSTEP goes to the heap scan
+    "matmul1024-ramp": (3584, 4, 2, 4, 4, 64, 128),
+    "chol2048-dgemm64": (4480, 4, 2, 4, 4, 64, 256),
+    "chol2048-fig9": (4992, 8, 2, 5, 5, 8, 64),
+    # the floor: six synth candidates (synth_trace(12), +SMP, 1-6
+    # accelerators) fill none of the floors
+    "floor": (HEAP_ROW_ALIGN, HEAP_MIN_COHORTS, 2, 4, 4, 8, HEAP_MIN_LANES),
+    # fig9's rows and cohorts at the lane cap
+    "chol2048-fig9-cap": (4992, 8, 2, 5, 5, 8, MEGABATCH_CHUNK),
+    # the two configurations at bs=64: matmul1024-ramp (4,096 tasks) and
+    # the Fig. 9 grid of chol2048-fig9 (5,984 kernel tasks)
+    "matmul1024-bs64": (28672, 4, 2, 4, 4, 64, 128),
+    "chol2048-bs64": (38784, 8, 2, 5, 5, 8, 64),
+}
+
+#: Launches whose ``[rows, lanes]`` state the compiler cannot hold in
+#: vector memory: their loop copies it in from HBM and back every step.
+STATE_COPIED = {"chol2048-bs64"}
+
+#: Argument bytes a launch stays under: 1 GiB, but for Cholesky at bs=64,
+#: whose successor bitsets ``[R, G, R / 32]`` alone are 1.50 GB.
+ARG_LIMIT = {"chol2048-bs64": 2 << 30}
 
 
-#: Heap-scan launches of the benchmark's cells, where every lane of a
-#: group of at least ``MIN_LOCKSTEP`` goes to the heap scan: padded rows,
-#: cohorts, options, device kinds, pools, slots, lanes (read from
-#: ``_heap_cohorts``'s launch arguments in a CPU sweep of each cell).
-HEAP_LAUNCHES = {"matmul1024-ramp": (3584, 4, 2, 4, 4, 64, 128),
-                 "chol2048-dgemm64": (4480, 4, 2, 4, 4, 64, 256),
-                 "chol2048-fig9": (4992, 8, 2, 5, 5, 8, 64)}
-
-
-@pytest.mark.parametrize("shape", HEAP_LAUNCHES.values(),
-                         ids=HEAP_LAUNCHES.keys())
-def test_heap_runner_compiles_for_v5e(one_chip, shape):
-    """The heap scan at the benchmark's launch shapes: it compiles, its
-    loop copies nothing between HBM and vector memory, and its step input
-    is stacked over the padded rows."""
-    import re
-    R, G, K, NK, P, S, B = shape
+@pytest.mark.parametrize("name", HEAP_LAUNCHES)
+def test_heap_runner_compiles_for_v5e(one_chip, name):
+    """The heap scan at a launch shape: it compiles, its loop copies
+    nothing between HBM and vector memory (but where its state cannot fit
+    there), and its step input is stacked over the padded rows."""
+    R, G, K, NK, P, S, B = HEAP_LAUNCHES[name]
     sd = functools.partial(_sd, one_chip)
     with jax.enable_x64(True):
         tabs = {"npred": sd((R, G), jnp.int32), "c": sd((R, G), jnp.int32),
@@ -125,34 +99,8 @@ def test_heap_runner_compiles_for_v5e(one_chip, shape):
                      r"body=%?([\w.\-]+)", hlo).group(1)
     start = hlo.index(f"%{body} ")
     loop = hlo[start:hlo.index("\n}\n", start)]
-    assert "copy-start" not in loop and "copy-done" not in loop
+    copies = "copy-start" in loop or "copy-done" in loop
+    assert copies == (name in STATE_COPIED)
     assert f"pred[{R},{B}]" in hlo
-    assert 0 < compiled.memory_analysis().argument_size_in_bytes < 1 << 30
-
-
-@pytest.mark.parametrize("P,S,B", [(4, 32, 64), (5, 8, 256)])
-def test_step_commit_kernel_compiles_for_v5e(one_chip, P, S, B):
-    sd = functools.partial(_sd, one_chip)
-    args = (sd((P, S, B), jnp.float32), sd((P, B), jnp.float32),
-            sd((P, B), jnp.bool_), sd((B,), jnp.int32),
-            sd((B,), jnp.float32), sd((B,), jnp.float32),
-            sd((B,), jnp.bool_))
-    with jax.enable_x64(False):
-        compiled = jax.jit(functools.partial(step_commit, interpret=False)) \
-            .lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_pallas_step_impl_raises_never_interprets(monkeypatch):
-    assert resolve_step_impl("auto") == "lax"
-    with pytest.raises(ValueError, match="backend is 'cpu'"):
-        resolve_step_impl("pallas")
-    fg, _ = frozen_for(synth_trace(8), smp=False)
-    systems = [zynq_system(f"{n}acc", {"fpga:k": n}) for n in range(1, 7)]
-    with pytest.raises(ValueError, match="step_impl='pallas'"):
-        simulate_jax(fg, systems, step_impl="pallas")
-    # on a TPU backend the scan's 64-bit state is the reason
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert resolve_step_impl("auto") == "lax"
-    with pytest.raises(ValueError, match="64-bit state"):
-        resolve_step_impl("pallas")
+    assert 0 < compiled.memory_analysis().argument_size_in_bytes \
+        < ARG_LIMIT.get(name, 1 << 30)
