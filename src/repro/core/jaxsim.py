@@ -234,21 +234,29 @@ def _choose(opts, cost, rt, minc, kind_pool, smp_kid, eft, aB):
     return best_k
 
 
-def _commit(clocks, busy, seen, p, rt, base, live, aB):
-    """Slot argmin + clock/busy/seen update — the step's dense tail."""
-    _, jnp = _jax()
-    cl = clocks[p, :, aB]                                       # [B, S]
-    s = jnp.argmin(cl, axis=1)                  # first-minimum, like ref
-    tmin = cl[aB, s]
+def _commit(clocks, busy, seen, p, rt, base, live):
+    """Slot argmin + clock/busy/seen update — the step's dense tail.
+
+    Each lane's pool ``p`` is a one-hot over the small pool axis, so its
+    reads are masked max-reductions (the bit patterns are non-negative,
+    so a fill of 0 never wins) and its writes are dense ``where``s over
+    ``[P, S, B]`` / ``[P, B]``: on a TPU a per-lane scatter serialises
+    over the lanes, while a dense pass over the pool state is no larger
+    than the step's own ``min(clocks, axis=1)``."""
+    jax, jnp = _jax()
+    P, S, B = clocks.shape
+    at_p = jax.lax.broadcasted_iota(jnp.int32, (P, B), 0) == p[None, :]
+    cl = jnp.max(jnp.where(at_p[:, None, :], clocks, 0), axis=0)  # [S, B]
+    s = jnp.argmin(cl, axis=0)                  # first-minimum, like ref
+    tmin = jnp.min(cl, axis=0)
     start = jnp.maximum(rt, tmin)
     end = _bits_add(start, base)
-    clocks = clocks.at[p, s, aB].set(
-        jnp.where(live, end, clocks[p, s, aB]))
-    b0 = busy[p, aB]
-    busy = busy.at[p, aB].set(
-        jnp.where(live, _bits_add(b0, _bits_sub(end, start)), b0))
-    seen = seen.at[p, aB].set(seen[p, aB] | live)
-    return clocks, busy, seen, end
+    own = at_p & live[None, :]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (S, B), 0) == s[None, :]
+    clocks = jnp.where(own[:, None, :] & slot[None, :, :], end, clocks)
+    b0 = jnp.max(jnp.where(own, busy, 0), axis=0)
+    busy = jnp.where(own, _bits_add(b0, _bits_sub(end, start)), busy)
+    return clocks, busy, seen | own, end
 
 
 #: ``cnt`` of a row a lane has popped: never 0 again, since a popped row
@@ -284,7 +292,8 @@ def _heap_runner():
     ``where`` over ``[R, B]`` (the popped row's one-hot, and its successor
     bitset row expanded to a mask): on a TPU a per-step scatter into
     row-indexed ``[R, B]`` state moves the whole array through vector
-    memory every step.
+    memory every step.  The pool state's writes are dense too
+    (:func:`_commit`), so the loop holds no scatter.
 
     Graph tables are per cohort, stacked row-major ``[R, G, ...]`` (so the
     loop's operands share the leading dimension ``R``, its trip count);
@@ -355,7 +364,7 @@ def _heap_runner():
                 kk = jnp.maximum(k, 0)
                 p = jnp.maximum(kind_pool[aB, kk], 0)
                 clocks, busy, seen, end = _commit(clocks, busy, seen, p, rt,
-                                                  cost[aB, kk], live, aB)
+                                                  cost[aB, kk], live)
                 end_eff = jnp.where(live, end, jnp.where(valid, rt, zero))
                 makespan = jnp.maximum(makespan, end_eff)
 

@@ -74,11 +74,27 @@ STATE_COPIED = {"chol2048-bs64"}
 ARG_LIMIT = {"chol2048-bs64": 2 << 30}
 
 
+def _called(hlo, root):
+    """The text of computation ``root`` of a compiled module and of every
+    computation it calls, fusions and reductions included."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)\n\}$",
+                            hlo, re.M | re.S))
+    todo, seen = [root], set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += re.findall(r"(?:calls|to_apply|body|condition)="
+                               r"%?([\w.\-]+)", comps[name])
+    return "\n".join(comps[name] for name in seen)
+
+
 @pytest.mark.parametrize("name", HEAP_LAUNCHES)
 def test_heap_runner_compiles_for_v5e(one_chip, name):
     """The heap scan at a launch shape: it compiles, its loop copies
     nothing between HBM and vector memory (but where its state cannot fit
-    there), and its step input is stacked over the padded rows."""
+    there) and holds no scatter (a TPU serialises one over its lanes), and
+    its step input is stacked over the padded rows."""
     R, G, K, NK, P, S, B = HEAP_LAUNCHES[name]
     sd = functools.partial(_sd, one_chip)
     with jax.enable_x64(True):
@@ -101,6 +117,7 @@ def test_heap_runner_compiles_for_v5e(one_chip, name):
     loop = hlo[start:hlo.index("\n}\n", start)]
     copies = "copy-start" in loop or "copy-done" in loop
     assert copies == (name in STATE_COPIED)
+    assert "scatter(" not in _called(hlo, body)
     assert f"pred[{R},{B}]" in hlo
     assert 0 < compiled.memory_analysis().argument_size_in_bytes \
         < ARG_LIMIT.get(name, 1 << 30)
